@@ -34,6 +34,8 @@ def main(argv=None) -> int:
     # configured in the environment (parallel/distributed.py)
     from .parallel import maybe_initialize_distributed
     maybe_initialize_distributed()
+    from .utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = load_config(args.config, tuple(args.overrides))
     if args.command in ("evaluate", "benchmark"):

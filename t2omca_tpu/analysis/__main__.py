@@ -43,9 +43,11 @@ def _pin_cpu_platform() -> None:
     need (the dp program's 2-device data mesh, and the sebulba
     actor_step/learner_step programs' 2+2-device split). The checked-in
     fingerprints/budgets are for exactly this platform — auditing on
-    whatever backend happens to be attached would produce fiction. A
-    no-op when jax is already imported (in-process callers — the tests
-    — own their platform)."""
+    whatever backend happens to be attached would produce fiction. The
+    ``*_pallas`` entries are the interpret lowering for the same reason,
+    so the pin also turns the kernels' interpreter mode on. A no-op
+    when jax is already imported (in-process callers — the tests — own
+    their platform and their interpret flag, tests/conftest.py)."""
     if "jax" in sys.modules:
         return
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -53,6 +55,8 @@ def _pin_cpu_platform() -> None:
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=4").strip()
+    from ..kernels import attention
+    attention.INTERPRET = True
 
 
 def _refuse_small_host(jax, registry, tool: str) -> int:

@@ -5,8 +5,8 @@ handed. The fused superstep (docs/SPEC.md §8) concentrates the whole
 rollout→insert→train pipeline into a handful of long-lived programs, so
 one silent regression — an undonated buffer, a weight baked in as a
 constant, a stray bf16→f32 upcast — doubles device memory or FLOPs with
-every unit test still green (the PR 2 ``NormState`` donate-twice bug and
-the 0.66 s-dispatch discovery both surfaced only by accident). Each
+every unit test still green (the PR 2 ``NormState`` donate-twice bug
+surfaced only by accident). Each
 registered program (``analysis/registry.py``) is traced, lowered and —
 for the donated hot programs — compiled, then checked at two levels:
 
@@ -83,7 +83,11 @@ CONST_BYTES_DEFAULT = 16_384
 DEFAULT_TOLERANCE = {"flops": 0.10, "bytes_accessed": 0.10,
                      "peak_bytes": 0.25}
 
-_DONATION_WARNING_RE = re.compile(r"donated buffers were not usable")
+#: jax's lowering warning: "Some donated buffers were not usable:
+#: float32[3], bfloat16[8,8].\nSee an explanation at ..." — group 1 is
+#: the comma-separated aval list
+_DONATION_WARNING_RE = re.compile(
+    r"donated buffers were not usable: (.*?)\.?\n")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,7 +137,7 @@ def _iter_closed_jaxprs(closed) -> Iterator[object]:
     block-spec tables as GP202 baked constants. A Pallas kernel is
     audited as one opaque device op, like any other XLA custom call;
     pinned by tests/test_graftprog.py."""
-    from jax.core import ClosedJaxpr
+    from jax.extend.core import ClosedJaxpr
     seen = set()
     stack = [closed]
     while stack:
@@ -217,15 +221,10 @@ def _callback_findings(closed) -> List[str]:
 # ----------------------------------------------------------------- metrics
 
 def _cost_dict(stage) -> Dict[str, float]:
-    """``cost_analysis()`` is a dict on some jaxlib versions and a
-    one-element list of dicts on others — normalize."""
-    try:
-        ca = stage.cost_analysis()
-    except Exception:  # noqa: BLE001 — backend may not implement it
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """``cost_analysis()`` of a lowered or compiled stage: a dict, or
+    ``None`` where the backend has no cost model for that stage (a
+    lowered stage on TPU)."""
+    return dict(stage.cost_analysis() or {})
 
 
 def fingerprint_text(text: str) -> str:
@@ -278,10 +277,10 @@ def audit_program(name: str, prog: AuditProgram, compute_dtype: str,
         if missing > 0:
             unaliased: List[str] = []
             for w in caught:
-                msg = str(w.message)
-                if _DONATION_WARNING_RE.search(msg):
+                m = _DONATION_WARNING_RE.search(str(w.message))
+                if m:
                     unaliased.extend(
-                        re.findall(r"ShapedArray\([^)]*\)", msg))
+                        re.findall(r"\w+\[[\d,]*\]", m.group(1)))
             if len(unaliased) == missing:
                 details["GP201"] = [
                     f"donated leaf {aval} has no input_output_alias — "

@@ -4,10 +4,10 @@ The static side (``graftlint``) catches hazards visible in the AST; this
 module catches the two failure modes that are only observable at run
 time and that PR 2's superstep made expensive:
 
-* **Silent retraces.** ``superstep_program`` amortizes ~0.66 s of
-  dispatch overhead over K iterations (BASELINE.md) — ONE compile, many
-  dispatches. A weak-typed scalar, a shape wobble, or a changed static
-  arg silently recompiles the whole fused program every iteration and
+* **Silent retraces.** ``superstep_program`` amortizes dispatch
+  overhead over K iterations — ONE compile, many dispatches. A
+  weak-typed scalar, a shape wobble, or a changed static arg silently
+  recompiles the whole fused program every iteration and
   erases the win (the exact bug class ``run._strong`` exists to stop).
   ``compile_budget(n)`` turns that into a hard test failure: it counts
   XLA compiles (via the ``jax.log_compiles`` log stream) inside the
@@ -36,7 +36,7 @@ import jax
 
 #: loggers that carry the per-compile "Compiling <fn> ..." records
 #: (jax._src.interpreters.pxla emits them for both the jit and the
-#: pjit/sharded paths on JAX 0.4.x; dispatch kept for fallback coverage)
+#: pjit/sharded paths; dispatch logs the per-primitive ones)
 _COMPILE_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")
 
 
@@ -123,8 +123,8 @@ def no_transfer(host_to_device: bool = True) -> Iterator[None]:
     the block. Explicit transfers — ``jax.device_put``,
     ``jax.device_get`` — stay allowed: the driver's cadence-boundary
     fetches are deliberate, it's the silent ones that stall the
-    pipeline (the PR 2 priority-feedback ``device_get`` cost ~0.66 s
-    per train iteration before it was made async)."""
+    pipeline (the PR 2 priority-feedback ``device_get`` blocked every
+    train iteration before it was made async)."""
     with contextlib.ExitStack() as stack:
         stack.enter_context(jax.transfer_guard_device_to_host("disallow"))
         if host_to_device:

@@ -132,7 +132,7 @@ AUDIT_SUPERSTEP_K = 2
 #: ``_rollout``/``_insert``/``_train_iter``/``_superstep``; the device
 #: tracks name the XLA module ``jit_<fn>`` while the host executor
 #: track (the only one a CPU trace has — verified against a real
-#: JAX 0.4.37 capture) names the call ``PjitFunction(<fn>)``. Both
+#: capture) names the call ``PjitFunction(<fn>)``. Both
 #: forms are listed; the parser attributes one track per program, so
 #: listing both never double-counts. Stable as long as the wrapper
 #: names are (renaming one breaks attribution AND the checked-in GP304

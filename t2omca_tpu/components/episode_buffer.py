@@ -89,8 +89,8 @@ class TimeMajorEpisodes:
     The fused superstep path (``run.Experiment.superstep_program``)
     scatters these straight into the replay ring
     (``ReplayBuffer.insert_time_major``) without ever materializing the
-    concatenated ``(B, T+1, ...)`` episode batch — the batch→copy HBM
-    round-trip BASELINE.md flags on the bandwidth-bound path. The
+    concatenated ``(B, T+1, ...)`` episode batch (a batch→copy HBM
+    round-trip). The
     classic path assembles the same values into an ``EpisodeBatch`` via
     ``to_batch()`` (bit-identical contents either way)."""
 

@@ -80,7 +80,7 @@ def welford_update_batch(state: NormState, xs: jnp.ndarray) -> NormState:
 
     This replaces an ``A``-step sequential scan of tiny updates on the env
     hot path with one batched op (the scan was the env-step serialization
-    bottleneck at 64 agents — VERDICT r2 Weak #1)."""
+    bottleneck at 64 agents)."""
     a = xs.shape[0]
     bmean = xs.mean(axis=0)
     bs = ((xs - bmean) ** 2).sum(axis=0)

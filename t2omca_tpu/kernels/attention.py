@@ -43,15 +43,18 @@ Numerics contract (pinned by ``tests/test_kernels.py``):
   Residual cost: O(B·H·Q) f32 per forward — two rows of statistics vs
   the O(B·H·Q·K) P tensor the einsum VJP keeps alive.
 
-``interpret=None`` (the default) auto-selects interpreter mode off-TPU,
-which is what makes the kernel testable in the CPU tier-1 gate and
-auditable by graftprog (the registered ``attn_pallas``/
-``attn_pallas_bwd`` programs lower the interpret form on the gate's
-pinned CPU platform). Interpret mode also skips the TPU sublane/lane
-tile quanta (token counts pad only to the clamped block sizes, head dim
-not at all) — the kernel *body* is the one that lowers to Mosaic, but
-off-TPU there is no hardware tiling to satisfy and the padding would
-only inflate the audit's cost model with work the chip never does.
+Compiled (Mosaic) is the default. Interpreter mode is opt-in through
+the module attribute :data:`INTERPRET` — the CPU tests
+(``tests/conftest.py``), the CPU-pinned audit CLI
+(``analysis/__main__.py``) and ``bench.py --smoke`` set it; nothing
+else does, so a ``kernels.attention: pallas`` run that finds no chip
+fails in the lowering instead of quietly interpreting. Interpret mode
+also skips the TPU sublane/lane tile quanta (token counts pad only to
+the clamped block sizes, head dim not at all): the kernel *body* is the
+one that lowers to Mosaic, but the padding would only inflate the
+audit's cost model with work the chip never does. The compiled geometry
+is covered by ``tests/test_mosaic_compile.py`` (described-device
+compiles) and ``chip_smoke.py`` (on the chip).
 """
 
 from __future__ import annotations
@@ -63,11 +66,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover - import surface depends on the jaxlib build
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 # the ONE reference masked_fill value — imported, not redefined, so the
 # kernel's replacement bias can never drift from the einsum path's
 # (models/transformer.py only imports this module lazily inside
@@ -78,6 +76,10 @@ from ..models.transformer import NEG_MASK_VALUE  # noqa: E402
 #: all-masked-row case where m == NEG_MASK_VALUE (the einsum path's
 #: uniform-over-real-keys degenerate behavior is preserved)
 _PAD_VALUE = -1e30
+
+#: interpreter mode for calls that pass ``interpret=None``. False = the
+#: kernels lower to Mosaic; only CPU rehearsal harnesses flip it.
+INTERPRET = False
 
 #: default VMEM tile sizes (clamped to the padded token counts); 128
 #: matches the MXU/VPU lane width
@@ -112,6 +114,28 @@ def _tile_geometry(t_q: int, t_k: int, d: int, block_q: int, block_k: int,
     return bq, bk, t_q_pad, t_k_pad, d_pad
 
 
+def _eye(n: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def _col_to_row(x):
+    """``(n, 1)`` per-row statistic → ``(1, n)``, exactly. The kernels
+    compute row statistics as columns (q rows on sublanes) but HBM
+    keeps them lane-major — Mosaic tiles the last two dims to (8, 128),
+    so a column-shaped array would pad 128x. A select against the
+    identity and a sublane reduce is a relayout Mosaic always lowers
+    (adding zeros is exact, so ``-1e30`` pad values survive)."""
+    return jnp.sum(jnp.where(_eye(x.shape[0]), x, 0.0), axis=0,
+                   keepdims=True)
+
+
+def _row_to_col(x):
+    """Inverse of :func:`_col_to_row`: ``(1, n)`` → ``(n, 1)``."""
+    return jnp.sum(jnp.where(_eye(x.shape[1]), x, 0.0), axis=1,
+                   keepdims=True)
+
+
 def _flash_attention_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
                             has_bias: bool, save_res: bool, t_k: int,
                             t_k_pad: int, block_q: int, block_k: int):
@@ -125,7 +149,7 @@ def _flash_attention_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
     bias_ref = rest.pop(0) if has_bias else None
     o_ref = rest.pop(0)
     if save_res:
-        m_ref, l_ref = rest
+        (res_ref,) = rest
     q = q_ref[0, 0].astype(jnp.float32)                    # (bq, d)
     d = q.shape[-1]
     q_row0 = pl.program_id(2) * block_q
@@ -175,8 +199,8 @@ def _flash_attention_kernel(q_ref, k_ref, v_ref, *rest, causal: bool,
         # m is −1e9 and f32 addition swallows log l entirely, which
         # would turn the backward's recomputed P into exp(0) = 1
         # instead of the uniform 1/t_k the forward produced
-        m_ref[0, 0] = m[:, 0]
-        l_ref[0, 0] = l[:, 0]
+        res_ref[0, 0, 0:1, :] = _col_to_row(m)
+        res_ref[0, 0, 1:2, :] = _col_to_row(l)
 
 
 def _recompute_p(q, kb, bias_blk, m, l, row0, col0, causal: bool,
@@ -217,12 +241,11 @@ def _flash_attention_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest,
     positions. Neither the logits nor P ever reach HBM."""
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
-    g_ref, m_ref, l_ref, delta_ref, dq_ref = rest
+    g_ref, stats_ref, dq_ref = rest
     q = q_ref[0, 0].astype(jnp.float32)                    # (bq, d)
     g = g_ref[0, 0].astype(jnp.float32)                    # (bq, d)
-    m = m_ref[0, 0][:, None]                               # (bq, 1) f32
-    l = l_ref[0, 0][:, None]
-    delta = delta_ref[0, 0][:, None]
+    m, l, delta = (_row_to_col(stats_ref[0, 0, r:r + 1, :])
+                   for r in range(3))                      # (bq, 1) f32
     row0 = pl.program_id(2) * block_q
 
     def body(j, acc):
@@ -261,7 +284,7 @@ def _flash_attention_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest,
     ``dK = Σ_q dSᵀ · Q``."""
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
-    g_ref, m_ref, l_ref, delta_ref, dk_ref, dv_ref = rest
+    g_ref, stats_ref, dk_ref, dv_ref = rest
     kb = k_ref[0, 0].astype(jnp.float32)                   # (bk, d)
     vb = v_ref[0, 0].astype(jnp.float32)
     col0 = pl.program_id(2) * block_k
@@ -273,9 +296,9 @@ def _flash_attention_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest,
             jnp.float32)
         gb = g_ref[0, 0, pl.ds(i * block_q, block_q), :].astype(
             jnp.float32)
-        mb = m_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        lb = l_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        db = delta_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
+        rows = pl.ds(i * block_q, block_q)
+        mb, lb, db = (_row_to_col(stats_ref[0, 0, r:r + 1, rows])
+                      for r in range(3))
         bias_blk = None
         if has_bias:
             bias_blk = bias_ref[0, 0, pl.ds(i * block_q, block_q),
@@ -377,18 +400,20 @@ def _build(causal: bool, block_q: int, block_k: int, interpret: bool,
         unpad = (lambda o: o if (t_q_pad, d_pad) == (t_q, d)
                  else o[:, :, :t_q, :d])
         if save_res:
-            res_spec = pl.BlockSpec((1, 1, bq),
-                                    lambda b_, h_, i: (b_, h_, i))
-            res_shape = jax.ShapeDtypeStruct((b, h, t_q_pad), jnp.float32)
-            out, m, l = pl.pallas_call(
+            # (m, l) stacked on the sublane axis, q rows lane-major: a
+            # (1, 1, 2, bq) block meets Mosaic's (8, 128) block rule —
+            # 2 is the full dim, bq is t_q_pad or a multiple of 128
+            out, res = pl.pallas_call(
                 kernel,
                 grid=(b, h, t_q_pad // bq),
                 in_specs=in_specs,
-                out_specs=(out_specs, res_spec, res_spec),
-                out_shape=(out_shape, res_shape, res_shape),
+                out_specs=(out_specs, pl.BlockSpec(
+                    (1, 1, 2, bq), lambda b_, h_, i: (b_, h_, 0, i))),
+                out_shape=(out_shape, jax.ShapeDtypeStruct(
+                    (b, h, 2, t_q_pad), jnp.float32)),
                 interpret=interpret,
             )(*args)
-            return unpad(out), m, l
+            return unpad(out), res
         out = pl.pallas_call(
             kernel,
             grid=(b, h, t_q_pad // bq),
@@ -399,7 +424,7 @@ def _build(causal: bool, block_q: int, block_k: int, interpret: bool,
         )(*args)
         return unpad(out)
 
-    def backward(q, k, v, bias, o, m, l, g):
+    def backward(q, k, v, bias, o, res, g):
         """Flash backward: ``Δ = rowsum(dO ∘ O)`` (one elementwise pass,
         no score-shaped tensor), then two pallas programs — dQ gridded
         over q-blocks, dK/dV over k-blocks — each recomputing P tiles in
@@ -416,17 +441,19 @@ def _build(causal: bool, block_q: int, block_k: int, interpret: bool,
         if (t_q_pad, d_pad) != (t_q, d):
             gp = jnp.pad(g, ((0, 0), (0, 0), (0, t_q_pad - t_q),
                              (0, d_pad - d)))
-        dp_ = delta
         if t_q_pad != t_q:
-            dp_ = jnp.pad(delta, ((0, 0), (0, 0), (0, t_q_pad - t_q)))
-        # m/l come back from the forward already t_q_pad-long
+            delta = jnp.pad(delta, ((0, 0), (0, 0), (0, t_q_pad - t_q)))
+        # the forward's (m, l) rows are already t_q_pad-long; delta
+        # joins them as a third row, same lane-major layout
+        stats = jnp.concatenate([res, delta[:, :, None, :]], axis=2)
         qd_spec = pl.BlockSpec((1, 1, bq, d_pad),
                                lambda b_, h_, i: (b_, h_, i, 0))
-        qrow_spec = pl.BlockSpec((1, 1, bq), lambda b_, h_, i: (b_, h_, i))
+        qrow_spec = pl.BlockSpec((1, 1, 3, bq),
+                                 lambda b_, h_, i: (b_, h_, 0, i))
         qfull_spec = pl.BlockSpec((1, 1, t_q_pad, d_pad),
                                   lambda b_, h_, j: (b_, h_, 0, 0))
-        qfullrow_spec = pl.BlockSpec((1, 1, t_q_pad),
-                                     lambda b_, h_, j: (b_, h_, 0))
+        qfullrow_spec = pl.BlockSpec((1, 1, 3, t_q_pad),
+                                     lambda b_, h_, j: (b_, h_, 0, 0))
         kd_spec = pl.BlockSpec((1, 1, bk, d_pad),
                                lambda b_, h_, j: (b_, h_, j, 0))
 
@@ -437,13 +464,12 @@ def _build(causal: bool, block_q: int, block_k: int, interpret: bool,
         dq = pl.pallas_call(
             dq_kernel,
             grid=(b, h, t_q_pad // bq),
-            in_specs=in_specs + [qd_spec, qrow_spec, qrow_spec,
-                                 qrow_spec],
+            in_specs=in_specs + [qd_spec, qrow_spec],
             out_specs=qd_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, t_q_pad, d_pad),
                                            q.dtype),
             interpret=interpret,
-        )(*args, gp, m, l, dp_)
+        )(*args, gp, stats)
 
         # dK/dV grid over key blocks: Q/dO/residuals arrive whole, the
         # key/value/bias specs re-map onto the k-block axis
@@ -464,15 +490,14 @@ def _build(causal: bool, block_q: int, block_k: int, interpret: bool,
         dk, dv = pl.pallas_call(
             dkv_kernel,
             grid=(b, h, t_k_pad // bk),
-            in_specs=in_specs_kv + [qfull_spec, qfullrow_spec,
-                                    qfullrow_spec, qfullrow_spec],
+            in_specs=in_specs_kv + [qfull_spec, qfullrow_spec],
             out_specs=(kd_spec, kd_spec),
             out_shape=(jax.ShapeDtypeStruct((b, h, t_k_pad, d_pad),
                                             k.dtype),
                        jax.ShapeDtypeStruct((b, h, t_k_pad, d_pad),
                                             v.dtype)),
             interpret=interpret,
-        )(*args, gp, m, l, dp_)
+        )(*args, gp, stats)
         unpad_q = (lambda x: x if (t_q_pad, d_pad) == (t_q, d)
                    else x[:, :, :t_q, :d])
         unpad_k = (lambda x: x if (t_k_pad, d_pad) == (t_k, d)
@@ -484,12 +509,12 @@ def _build(causal: bool, block_q: int, block_k: int, interpret: bool,
         return forward(q, k, v, bias, save_res=False)
 
     def attn_fwd(q, k, v, bias):
-        o, m, l = forward(q, k, v, bias, save_res=True)
-        return o, (q, k, v, bias, o, m, l)
+        o, res = forward(q, k, v, bias, save_res=True)
+        return o, (q, k, v, bias, o, res)
 
-    def attn_bwd(res, g):
-        q, k, v, bias, o, m, l = res
-        dq, dk, dv = backward(q, k, v, bias, o, m, l, g)
+    def attn_bwd(saved, g):
+        q, k, v, bias, o, res = saved
+        dq, dk, dv = backward(q, k, v, bias, o, res, g)
         # the bias plane encodes the (non-differentiable) mask; its
         # cotangent is structurally zero, as on the einsum path where
         # the mask feeds only `where` predicates
@@ -512,13 +537,13 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     the einsum path).
 
     ``mask``: optional ``(B, 1|H, T_q, T_k)``; zero entries are
-    suppressed (module semantics). ``interpret=None`` auto-selects the
-    Pallas interpreter off-TPU (CPU tier-1 gate); pass an explicit bool
-    to force either mode. Differentiating through the call runs the
-    flash backward kernels (P recomputed in VMEM from per-row
-    residuals — no logits/P tensor in HBM either direction)."""
+    suppressed (module semantics). ``interpret=None`` takes the module's
+    :data:`INTERPRET` (compiled unless a CPU harness set it); pass an
+    explicit bool to force either mode. Differentiating through the
+    call runs the flash backward kernels (P recomputed in VMEM from
+    per-row residuals — no logits/P tensor in HBM either direction)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = INTERPRET
     bias = None
     if mask is not None:
         if mask.ndim != 4:
@@ -542,9 +567,9 @@ def register_audit_programs(ctx):
     hot program. ``attn_pallas_bwd`` additionally lowers the GRADIENT
     of the pallas module (value_and_grad over q/k inputs), pinning the
     flash backward kernels — the train-path lowering PR 13 added — the
-    same way. The pallas variants lower the interpret form (the gate is
-    pinned to CPU); on-TPU they lower to Mosaic custom calls with the
-    same kernel bodies."""
+    same way. The pallas variants lower the interpret form (the audit
+    CLI pins CPU and sets ``INTERPRET``); on-TPU they lower to Mosaic
+    custom calls with the same kernel bodies."""
     from ..analysis.registry import AuditProgram
     from ..models.transformer import MultiHeadAttention
 

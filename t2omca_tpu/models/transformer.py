@@ -9,7 +9,7 @@ are load-bearing for loss-curve parity (SURVEY.md §7.5):
   ``transformer.py:34-36,52-59``), and attention logits are scaled by dividing
   both queries and keys by ``emb ** (1/4)`` (``transformer.py:62-63``).
   ``standard_heads=True`` switches to conventional ``emb//heads`` heads for the
-  performance configs (measured separately; BASELINE.md).
+  performance configs.
 * **Q2 — post-LN residuals**, residual adds the *query* input, dropout after
   each sub-layer: ``x = norm1(attended + q); x = do(x); x = norm2(ff(x) + x);
   x = do(x)`` (``transformer.py:120-140``).

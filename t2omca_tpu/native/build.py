@@ -1,8 +1,10 @@
 """Build + load the native sum-tree via ctypes.
 
 No pybind11 in the image (environment constraint), so the C++ side is a
-plain ``extern "C"`` shared object compiled with g++ on first use and cached
-next to the source keyed by source mtime. Callers should catch
+plain ``extern "C"`` shared object compiled with g++ on first use into
+``<checkout>/.native_build/`` (git-ignored), named by the hash of the
+source it was built from — a library from another checkout or an older
+source can never be picked up. Callers should catch
 ``NativeBuildError`` and fall back to the pure-NumPy sum-tree
 (``components/host_replay.PySumTree``) when no toolchain is present.
 """
@@ -10,11 +12,14 @@ next to the source keyed by source mtime. Callers should catch
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
-import tempfile
 
 _SRC = os.path.join(os.path.dirname(__file__), "sumtree.cpp")
+_BUILD_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".native_build")
 _LIB_CACHE = {}
 
 
@@ -23,19 +28,20 @@ class NativeBuildError(RuntimeError):
 
 
 def _build_lib() -> str:
-    cache_dir = os.path.join(tempfile.gettempdir(), "t2omca_native")
-    os.makedirs(cache_dir, exist_ok=True)
-    so_path = os.path.join(cache_dir, "libsumtree.so")
-    if (os.path.exists(so_path)
-            and os.path.getmtime(so_path) >= os.path.getmtime(_SRC)):
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so_path = os.path.join(_BUILD_DIR, f"libsumtree-{digest}.so")
+    if os.path.exists(so_path):
         return so_path
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-o", so_path + ".tmp", _SRC]
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{so_path}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
     except (subprocess.CalledProcessError, FileNotFoundError) as e:
         detail = getattr(e, "stderr", str(e))
         raise NativeBuildError(f"g++ build failed: {detail}") from e
-    os.replace(so_path + ".tmp", so_path)
+    os.replace(tmp, so_path)
     return so_path
 
 

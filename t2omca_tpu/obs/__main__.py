@@ -13,9 +13,9 @@ Subcommands:
     reports from the flight tail.
 
 ``timeline [BENCH_r*.json ...] [--runs <run_dir> ...]``
-    The longitudinal perf-trajectory table over the repo's BENCH_r*
-    records (all historical shapes) and recorded runs' metrics.jsonl,
-    distinguishing measured numbers from wedged partials
+    The longitudinal perf-trajectory table over bench records (every
+    shape) and recorded runs' metrics.jsonl, distinguishing measured
+    numbers from failed partials
     (docs/OBSERVABILITY.md §pulse).
 
 ``learning <run_dir>``

@@ -2,8 +2,6 @@
 
 Everything graftscope (``spans.py``) records is post-mortem — spans,
 flight rings and stall diagnoses are only readable after the run dies.
-ROADMAP open item 1's hardest blocker is exactly that shape: five
-consecutive TPU benches wedged at backend init with nobody watching.
 Podracer-style decoupled layouts (PAPERS.md, arXiv 2104.06272) live or
 die on actor/learner *utilization you can see while it runs*, and the
 fleet-scale serving story (EnvPool's share-nothing engines) needs a

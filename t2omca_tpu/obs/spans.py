@@ -1,9 +1,8 @@
 """graftscope span tracing: the host-side runtime telemetry recorder.
 
-ROADMAP open item 1 needs to know where a dispatch's wall-clock goes,
-and BENCH_r03–r05 died at backend init leaving no trail — nothing in
-the repo could say *which phase* a wedged run was in, or how long the
-phases before it took. Podracer (arxiv 2104.06272) attributes its TPU
+The driver needs to know where a dispatch's wall-clock goes, and which
+phase a stalled or crashed run was in and how long the phases before it
+took. Podracer (arxiv 2104.06272) attributes its TPU
 utilization wins to exactly this per-phase accounting. This module is
 the host half of that story (device-time attribution lives in
 ``obs/device_time.py``):
@@ -94,13 +93,8 @@ KNOWN_PHASES = frozenset({
     # when a peer died mid-preemption)
     "checkpoint.save", "collective.gather", "backend.init",
     "checkpoint.elastic", "preempt.barrier", "checkpoint.shard_save",
-    # bench.py phases (bench harness spans; embedded in BENCH_r*.json).
-    # bench.probe is the RETRYABLE backend-init phase (per-attempt
-    # budget split + backoff ladder); bench.probe.fallback is the
-    # JAX_PLATFORMS='' auto-fallback probe that runs after it fails —
-    # its outcome lands in the failure record's `fallback` block
-    "bench.probe", "bench.probe.fallback", "bench.build",
-    "bench.compile", "bench.warm", "bench.measure",
+    # bench.py phases (bench harness spans; embedded in every record)
+    "bench.build", "bench.compile", "bench.warm", "bench.measure",
     # graftserve boundaries (serve/export.py, serve/frontend.py): the
     # exporter's lower/compile/export pass, artifact load, and the
     # three per-request front-end stages — `obs report` reads a
@@ -118,11 +112,8 @@ KNOWN_PHASES = frozenset({
     "fleet.refresh", "bench.chaos",
     # graftpulse live telemetry plane (obs/pulse.py, obs/memwatch.py):
     # one /metrics-endpoint scrape, one per-device HBM snapshot, the
-    # PULSE_TRACE-file / /trace-endpoint arming of a live trace window,
-    # and the bench daemon's two orchestration boundaries (the backoff-
-    # laddered backend-init probe and one A/B matrix leg subprocess)
+    # PULSE_TRACE-file / /trace-endpoint arming of a live trace window
     "pulse.scrape", "memwatch.snapshot", "trace.trigger",
-    "bench.daemon.probe", "bench.daemon.leg",
     # graftsight (obs/sight.py): the host-side RL-health detector pass
     # over the log-cadence fetched train info — host-only (no device
     # traffic), spanned so a slow sink/detector shows up in the phase

@@ -1,13 +1,10 @@
 """graftpulse timeline: the longitudinal perf-trajectory table.
 
-The repo carries seven BENCH_r*.json records spanning every perf PR,
-in three historical shapes (bare ``{metric, value, unit,
-vs_baseline}`` lines in r01/r02, error-only partials in r03, schema'd
-partials with span summaries in r06/r07), plus per-run
-``metrics.jsonl`` streams — and nothing that reads them TOGETHER. The
-question ROADMAP open item 1 keeps asking ("what is the trajectory,
-and which rounds are real numbers vs wedged partials?") has been
-answered by hand every round. This CLI answers it mechanically:
+Bench records come in several shapes (bare ``{metric, value, unit,
+vs_baseline}`` lines, error-only partials, schema'd partials with span
+summaries), next to per-run ``metrics.jsonl`` streams. This CLI reads
+them TOGETHER — what is the trajectory, and which records are real
+numbers and which are failed partials:
 
     python -m t2omca_tpu.obs timeline [BENCH_r*.json ...] \
         [--runs <run_dir> ...] [--json]
@@ -15,13 +12,13 @@ answered by hand every round. This CLI answers it mechanically:
 One row per BENCH record (wrapper ``{n, cmd, rc, tail, parsed}`` or a
 bare record line — every historical shape tolerated), one row per run
 directory (newest ``env_steps_per_sec`` from its ``metrics.jsonl``),
-rendered measured-vs-wedged so a partial can never masquerade as a
+rendered measured-vs-failed so a partial can never masquerade as a
 number. Torn final JSONL lines (the artifact a killed run leaves) are
 skipped with a warning, never raised on.
 
 Deliberately **jax-free** (pinned by a subprocess test, like the
 report CLI): the trajectory question gets asked from hosts that cannot
-initialize a backend — that is what most of the table's rows died of.
+initialize a backend.
 """
 
 from __future__ import annotations
@@ -103,9 +100,9 @@ def bench_row(path: str) -> Dict[str, Any]:
     row["schema"] = rec.get("schema")
     row["platform"] = rec.get("platform") or rec.get("backend")
     if row["value"] is None:
-        # the wedged-partial class (r03–r07): value never landed — the
-        # note says which phase died, which is the record's whole point
-        row["status"] = "wedged"
+        # a failed partial: the value never landed — the note says
+        # which phase died, which is the record's whole point
+        row["status"] = "failed"
         note = []
         if rec.get("phase"):
             note.append(f"phase={rec['phase']}")
@@ -186,21 +183,18 @@ def render(rows: List[Dict[str, Any]]) -> str:
             f"{_fmt(r['vs_baseline'], 3):>9}  "
             f"{(r['platform'] or '-'):<9}{r['note']}")
     measured = sum(1 for r in rows if r["status"] == "measured")
-    wedged = sum(1 for r in rows if r["status"] == "wedged")
+    failed = sum(1 for r in rows if r["status"] == "failed")
     bench_n = sum(1 for r in rows if r["kind"] == "bench")
     lines.append("")
     lines.append(f"{measured}/{bench_n} bench records carry a measured "
-                 f"value; {wedged} wedged partial(s)"
-                 + (" — the r03+ backend-init class, ROADMAP open "
-                    "item 1 (bench.py --daemon waits those out)"
-                    if wedged else ""))
+                 f"value; {failed} failed partial(s)")
     return "\n".join(lines)
 
 
 def timeline_main(paths: List[str], runs: List[str],
                   as_json: bool = False) -> int:
     """The ``timeline`` subcommand body. Exit 0 = table printed
-    (wedged rows are CONTENT, not errors), 2 = nothing to read."""
+    (failed rows are CONTENT, not errors), 2 = nothing to read."""
     if not paths and not runs:
         # bare invocation: the repo-root default. With --runs alone the
         # caller asked about runs, not the cwd's records

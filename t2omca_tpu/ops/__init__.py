@@ -4,8 +4,7 @@ The Pallas fused-block kernel that used to live here
 (``transformer_block.py`` + ``fast_agent.py``) was deleted in round 5:
 it computed the FULL dense forward for every token, which the
 query-slice reduction (token-0-only, K/V contracted away) and the
-entity-table acting path strictly dominate on FLOPs — see BASELINE.md
-round-5 notes for the decision record.
+entity-table acting path strictly dominate on FLOPs.
 """
 
 from .query_slice import (agent_forward_qslice, agent_forward_qslice_entity,

@@ -93,7 +93,7 @@ def maybe_initialize_distributed(
     attempt_box = [0]
 
     def _reset_partial_init() -> None:
-        # jax 0.4.37 assigns global_state.service/.client BEFORE
+        # jax assigns global_state.service/.client BEFORE
         # client.connect() (jax/_src/distributed.py), so a failed
         # rendezvous leaves the runtime half-initialized and a bare
         # retry dies on the double-init RuntimeError instead of
@@ -124,7 +124,7 @@ def maybe_initialize_distributed(
         except Exception as e:
             # idempotency via the runtime's own double-init error (there
             # is no public already-initialized predicate to query; jax
-            # 0.4.37 phrases it "should only be called once") — but only
+            # phrases it "should only be called once") — but only
             # on the FIRST attempt, where it can only mean a previous
             # successful call. On a retry the same message means THIS
             # call's failed attempt left the runtime half-initialized

@@ -28,10 +28,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.mixer import TransformerMixer
-from .compat import shard_map
 from .ring_attention import ring_attention
 
 LN_EPS = 1e-6   # flax nn.LayerNorm default, matches models/transformer.py

@@ -6,7 +6,7 @@ Subcommands::
     python -m t2omca_tpu.serve export results/models/<token> \
         --config configs/serve_smoke.yaml --out /path/to/artifact \
         [--buckets 1,2,4,8] [--dtypes float32,bfloat16] [--load-step N] \
-        [--no-blobs] [--no-compile-cache] [key=value overrides ...]
+        [--no-blobs] [key=value overrides ...]
 
     # inspect an artifact
     python -m t2omca_tpu.serve info /path/to/artifact
@@ -67,8 +67,6 @@ def main(argv=None) -> int:
     exp.add_argument("--no-blobs", action="store_true",
                      help="skip the per-bucket jax.export program blobs "
                           "(the front-end then rebuilds from the config)")
-    exp.add_argument("--no-compile-cache", action="store_true",
-                     help="skip the persistent compile cache warm-up")
 
     info = sub.add_parser("info", help="print an artifact's meta summary")
     info.add_argument("artifact_dir")
@@ -123,6 +121,9 @@ def main(argv=None) -> int:
               f"jax={prov.get('jax')} backend={prov.get('backend')}")
         return 0
 
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.command == "refresh":
         if not os.path.isfile(os.path.join(args.artifact_dir,
                                            "meta.json")):
@@ -157,7 +158,6 @@ def main(argv=None) -> int:
             dtypes=tuple(d for d in args.dtypes.split(",") if d)
             or PARAM_DTYPES,
             load_step=args.load_step,
-            compile_cache=not args.no_compile_cache,
             export_blobs=not args.no_blobs)
     except (FileNotFoundError, ValueError) as e:
         print(f"serve: error: {e}", file=sys.stderr)

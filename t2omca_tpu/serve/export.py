@@ -16,10 +16,9 @@ load:
   ``serve_step`` AOT-lowered per batch bucket and serialized with
   ``jax.export`` (StableHLO): a portable, version-checked program the
   front-end deserializes instead of re-tracing Python. Each bucket is
-  also compiled at export time — both a validation pass and the write
-  that warms the artifact's persistent compile cache.
-* ``compile_cache/`` — a ``jax_compilation_cache_dir`` populated by the
-  export-time compiles, so a fresh serving process warm-starts instead
+  also compiled at export time — a validation pass, and (where the
+  process has a persistent compile cache, ``utils/compile_cache.py``)
+  the write a later serving process on the same checkout hits instead
   of paying cold XLA compiles in front of traffic.
 * ``meta.json`` — format version, bucket list, param digests, the full
   train config (the front-end rebuilds the exact MAC from it), and
@@ -69,28 +68,6 @@ DEFAULT_BUCKETS: Tuple[int, ...] = (1, 2, 4, 8, 16, 32, 64)
 
 #: the serialized-param variants an artifact ships
 PARAM_DTYPES: Tuple[str, ...] = ("float32", "bfloat16")
-
-
-def enable_compile_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir`` (with
-    the size/time floors dropped so the small serve programs qualify).
-    Process-global jax config — callers opt in (``compile_cache=True``
-    on export/load). Best-effort: an older jaxlib without the knobs
-    just skips the warm-start."""
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # the cache singleton latches its directory at the process's
-        # FIRST compile (proven on jax 0.4.37): a process that already
-        # compiled anything would silently ignore the new dir — reset
-        # so the next compile re-reads the config
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-        return True
-    except Exception as e:  # noqa: BLE001 — cache is an optimization
-        logger.warning("persistent compile cache unavailable: %r", e)
-        return False
 
 
 def _git_commit() -> Optional[str]:
@@ -174,8 +151,8 @@ def _cast_variant(tree, dtype_name: str):
 def export_artifact(cfg: TrainConfig, ckpt_dir: str, out_dir: str,
                     buckets: Sequence[int] = DEFAULT_BUCKETS,
                     dtypes: Sequence[str] = PARAM_DTYPES,
-                    load_step: int = 0, compile_cache: bool = True,
-                    export_blobs: bool = True, rec=NULL_RECORDER) -> dict:
+                    load_step: int = 0, export_blobs: bool = True,
+                    rec=NULL_RECORDER) -> dict:
     """Write the serving artifact for ``cfg``'s newest (or
     ``load_step``-nearest) checkpoint under ``ckpt_dir`` into
     ``out_dir``; → the ``meta.json`` dict. See the module docstring for
@@ -186,15 +163,13 @@ def export_artifact(cfg: TrainConfig, ckpt_dir: str, out_dir: str,
         raise ValueError(f"buckets must be positive ints, got {buckets}")
     for d in dtypes:
         jnp.dtype(d)                 # fail fast on a typo'd dtype
-    # resolve + restore the checkpoint BEFORE any filesystem or
-    # process-global (compile cache) side effect: a missing/mismatched
-    # checkpoint must be a clean error, not a half-written artifact
+    # resolve + restore the checkpoint BEFORE any filesystem side
+    # effect: a missing/mismatched checkpoint must be a clean error,
+    # not a half-written artifact
     with rec.span("serve.export", phase_detail="load"):
         acting, mac, env_info, ckpt_info = load_acting_params(
             cfg, ckpt_dir, load_step)
     os.makedirs(out_dir, exist_ok=True)
-    if compile_cache:
-        enable_compile_cache(os.path.join(out_dir, "compile_cache"))
     step = build_serve_step(mac)
     obs_dim, n_actions = env_info["obs_shape"], env_info["n_actions"]
 
@@ -225,12 +200,8 @@ def export_artifact(cfg: TrainConfig, ckpt_dir: str, out_dir: str,
                           dtype=dtype_name, bucket=b):
                 lowered = step.trace(variant, obs, avail, hidden).lower()
                 fp = fingerprint_text(lowered.as_text())
-                try:
-                    cost = lowered.cost_analysis()
-                    if isinstance(cost, (list, tuple)):
-                        cost = cost[0] if cost else {}
-                except Exception:  # noqa: BLE001 — backend-dependent
-                    cost = {}
+                # None on TPU: no cost model before compilation there
+                cost = lowered.cost_analysis() or {}
             entry = {"fingerprint": fp,
                      "flops": cost.get("flops"),
                      "bytes_accessed": cost.get("bytes accessed")}
@@ -280,7 +251,6 @@ def export_artifact(cfg: TrainConfig, ckpt_dir: str, out_dir: str,
         "buckets": buckets,
         "params": params_meta,
         "programs": programs_meta,
-        "compile_cache": bool(compile_cache),
     }
     write_json_atomic(os.path.join(out_dir, "meta.json"), meta)
     logger.info("serve artifact written to %s (checkpoint t_env=%d)",

@@ -343,8 +343,7 @@ class ServeFleet:
                  cfg: Optional[FleetConfig] = None,
                  rec=NULL_RECORDER, hub=None,
                  frontend_factory: Optional[Callable] = None,
-                 use_exported: bool = True,
-                 compile_cache: bool = True) -> None:
+                 use_exported: bool = True) -> None:
         if n_engines < 1:
             raise ValueError(f"n_engines must be >= 1, got {n_engines}")
         self.artifact_dir = artifact_dir
@@ -354,7 +353,6 @@ class ServeFleet:
         self._rec = rec
         self._hub = hub
         self._use_exported = use_exported
-        self._compile_cache = compile_cache
         self._factory = frontend_factory or self._load_frontend
         self.meta: Optional[dict] = None
         self.engines = [_Engine(i) for i in range(self.n_engines)]
@@ -486,8 +484,7 @@ class ServeFleet:
     def _load_frontend(self, dtype: str):
         fe = ServeFrontend.load(
             self.artifact_dir, dtype=dtype,
-            use_exported=self._use_exported,
-            compile_cache=self._compile_cache, rec=self._rec,
+            use_exported=self._use_exported, rec=self._rec,
             hub=self._hub)
         live = self._live_params
         if live is not None and dtype == self.dtype:

@@ -24,8 +24,9 @@ TF-Agents host-side batching pattern (PAPERS.md, arXiv 2206.10558):
 The dispatched program is the export's own ``jax.export`` blob
 (deserialized StableHLO — no Python re-trace), falling back to
 rebuilding ``build_serve_step`` from the artifact's train config when a
-blob is absent; either way the artifact's ``compile_cache/`` makes the
-first dispatch a persistent-cache hit instead of a cold XLA compile.
+blob is absent. The persistent compile cache is the process's
+(``utils/compile_cache.py``), never the artifact's: a first dispatch is
+a cache hit where the export ran against the same cache directory.
 """
 
 from __future__ import annotations
@@ -39,7 +40,7 @@ from typing import Dict, List, Sequence, Tuple
 import numpy as np
 
 from ..obs.spans import NULL_RECORDER
-from .export import ARTIFACT_FORMAT, enable_compile_cache
+from .export import ARTIFACT_FORMAT
 from .program import build_serve_step
 
 logger = logging.getLogger(__name__)
@@ -121,12 +122,10 @@ class ServeFrontend:
 
     @classmethod
     def load(cls, artifact_dir: str, dtype: str = "float32",
-             use_exported: bool = True, compile_cache: bool = True,
+             use_exported: bool = True,
              rec=NULL_RECORDER, hub=None) -> "ServeFrontend":
         """Load an exported artifact (``serve/export.py`` layout).
-        ``dtype`` picks the param variant; ``compile_cache`` points the
-        persistent compile cache at the artifact's warm entries
-        (process-global jax config — the serving process owns it)."""
+        ``dtype`` picks the param variant."""
         import jax
         from flax import serialization
 
@@ -144,11 +143,6 @@ class ServeFrontend:
                 raise ValueError(
                     f"artifact {artifact_dir} ships no {dtype!r} param "
                     f"variant (has: {sorted(meta.get('params', {}))})")
-            cache_dir = os.path.join(artifact_dir, "compile_cache")
-            if compile_cache and meta.get("compile_cache") \
-                    and os.path.isdir(cache_dir):
-                enable_compile_cache(cache_dir)
-
             with open(os.path.join(artifact_dir, entry["file"]), "rb") as f:
                 blob = f.read()
             import hashlib
@@ -283,7 +277,7 @@ class ServeFrontend:
     def warmup(self) -> None:
         """Dispatch one padded batch per bucket so every compiled
         program exists before traffic (persistent-cache hits when the
-        artifact's ``compile_cache/`` is warm)."""
+        process's compile cache saw the export)."""
         for b in self.buckets:
             obs = np.zeros((b, self.n_agents, self.obs_dim), np.float32)
             avail = np.ones((b, self.n_agents, self.n_actions), np.bool_)

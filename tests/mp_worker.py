@@ -180,17 +180,10 @@ def main() -> int:
 if __name__ == "__main__":
     import jax
 
+    # CPU-only by construction: these children never need a chip, so a
+    # parent that holds one can start them
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 4)
-    except AttributeError:
-        # older JAX (0.4.x): same lazy-backend fallback as tests/conftest.py
-        # — but REPLACE any inherited count (the parent pytest process
-        # exports 8; each of the 2 workers must present 4 local devices)
-        flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-                 if "xla_force_host_platform_device_count" not in f]
-        flags.append("--xla_force_host_platform_device_count=4")
-        os.environ["XLA_FLAGS"] = " ".join(flags)
+    jax.config.update("jax_num_cpu_devices", 4)
     # CPU cross-process collectives backend (jaxlib ships gloo); a TPU pod
     # uses the ICI/DCN fabric instead, so this stays test-side
     jax.config.update("jax_cpu_collectives_implementation", "gloo")

@@ -311,10 +311,9 @@ def test_compact_obs_reconstructs_full_obs():
 
 
 def test_default_config_resolves_to_full_fast_stack():
-    """TrainConfig() defaults land on the documented production path
-    (BASELINE.md / docs/ROUND3.md "default on"): entity-table acting +
-    compact entity storage, with fast_norm gating satisfied (VERDICT r3
-    Weak #3 — config, docs, and this pin must agree)."""
+    """TrainConfig() defaults land on the documented production path:
+    entity-table acting + compact entity storage, with fast_norm gating
+    satisfied (config, docs, and this pin must agree)."""
     from t2omca_tpu.config import TrainConfig, sanity_check
     from t2omca_tpu.ops.query_slice import (agent_qslice_eligible,
                                             entity_store_eligible,

@@ -200,7 +200,7 @@ def test_flash_backward_is_pallas_not_einsum_recompute():
     NOT the pre-PR-13 einsum-reference recompute, whose jaxpr had ONE
     pallas_call and a (B, H, Q, K)-shaped softmax chain in the host
     program."""
-    from jax.core import ClosedJaxpr
+    from jax.extend.core import ClosedJaxpr
     from t2omca_tpu.kernels.attention import flash_attention
 
     x = jnp.zeros((2, 2, 24, 8), jnp.float32)

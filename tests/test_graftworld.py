@@ -44,15 +44,18 @@ def tiny_env(**kw):
 
 # ------------------------------------------------------- default parity
 
-#: golden digests captured from the PRE-graftworld env/runner on this
-#: box (jax 0.4.37, CPU, f32): the default EnvParams must reproduce the
-#: fixed scenario BIT-identically — acceptance criterion of ISSUE 11.
+#: golden digests of the default scenario: the default EnvParams must
+#: reproduce the fixed scenario BIT-identically — acceptance criterion
+#: of ISSUE 11, first captured from the PRE-graftworld env/runner. The
+#: bits belong to one installation (XLA's CPU lowering and threefry
+#: move them): these are that same code's digests on JAX 0.9.0, CPU,
+#: f32, recaptured in PR 21 when the tree moved to this installation.
 #: If a deliberate env-semantics change moves these, recapture via the
 #: recipe in docs/ENVS.md §parity.
-ENV_GOLDEN = "b517edfaa286d819"
-ENV_STATE_GOLDEN = "60b154d8b4a185c8"
-RUNNER_GOLDEN = "30d99a1c21118889"
-RUNNER_STATS_GOLDEN = "91066c60eb50c847"
+ENV_GOLDEN = "41a9d523980e32d0"
+ENV_STATE_GOLDEN = "483e160628b138f6"
+RUNNER_GOLDEN = "38c3ba539357dc5c"
+RUNNER_STATS_GOLDEN = "6deb80f7a3966290"
 
 
 def _env_rollout_digests(params_b=None):

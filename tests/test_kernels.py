@@ -3,9 +3,10 @@ docs/PERF.md): the Pallas fused attention kernel vs the einsum path, the
 single-scatter time-major ring insert, and the bf16 acting-dtype mode —
 the PR-9 parity contracts the CPU tier-1 gate pins.
 
-The pallas kernel runs in interpreter mode here (interpret auto-selects
-off-TPU), so every assertion below holds for the exact kernel body that
-lowers to Mosaic on a real chip."""
+The pallas kernel runs in interpreter mode here (tests/conftest.py sets
+it), so every assertion below holds for the exact kernel body that
+lowers to Mosaic on a real chip (tests/test_mosaic_compile.py compiles
+it for one)."""
 
 import dataclasses
 

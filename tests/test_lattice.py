@@ -24,7 +24,6 @@ the blocking mechanism and the nearest legal alternative) live in
 tests/test_population.py::test_sanity_lattice_legal_and_gated_combos.
 """
 
-import argparse
 import json
 import os
 import subprocess
@@ -287,21 +286,6 @@ def test_population_sebulba_lockstep_matches_population_classic(tmp_path):
 
 
 # ------------------------------------------------------------- bench legs
-
-def test_daemon_matrix_has_lattice_leg():
-    """--daemon's A/B matrix gained the lattice leg, and --legs
-    validates it by name."""
-    import bench
-    ns = argparse.Namespace(smoke=True, iters=1, artifact=None,
-                            legs=None)
-    legs = dict(bench._daemon_legs(ns))
-    assert legs["lattice"] == ["--lattice", "--smoke", "--iters", "1"]
-    ns.legs = "lattice"
-    assert [n for n, _ in bench._daemon_legs(ns)] == ["lattice"]
-    ns.legs = "nope"
-    with pytest.raises(SystemExit, match="lattice"):
-        bench._daemon_legs(ns)
-
 
 def test_bench_population_rejects_ab_kernels_with_alternative():
     """--population --kernels ab is rejected NAMING the legal

@@ -1,0 +1,144 @@
+"""Compile-only guards against the chip's own compiler, at no chip time.
+
+The TPU compiler is installed in the sandbox and compiles for a device
+that is *described*, not attached (guide ``on-chip-measurement`` §2.3):
+Mosaic refuses here what it would refuse on the chip — a block shape off
+the (8, 128) tiling, a kernel that wants too much VMEM, a program that
+does not fit HBM. Interpret mode (every other test in this directory)
+shows none of that: the flash backward passed all of them for eight PRs
+while no gradient had ever lowered.
+
+Nothing runs, so nothing here says anything about results or times.
+The compiles happen in THIS process, one at a time: two at once in
+separate processes abort on libtpu's lock file (/tmp/libtpu_lockfile).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from t2omca_tpu.kernels.attention import flash_attention
+
+REPO = os.path.join(os.path.dirname(__file__), "..")
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e device, with the persistent compile cache off
+    around the module: a described-device executable is written to the
+    cache but cannot be read back without a chip, so the next compile
+    would warn and compile again."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (B, H, Tq, Tk, D) as configs/config3_tpu_northstar.yaml traces them
+# (batch 32 x 64 AGVs, 65 agent / 131 mixer tokens, emb 256, 4 heads):
+AGENT_QSLICE = (2048, 1, 4, 65, 256)     # learner unroll, sliced rows
+MIXER_QSLICE = (32, 1, 268, 131, 256)    # learner unroll; 3 q-blocks
+AGENT_DENSE = (2048, 4, 65, 65, 64)      # use_qslice=false learner
+MIXER_DENSE = (32, 4, 131, 131, 64)
+ACTING_TQ1 = (1024, 4, 1, 65, 64)        # one query row per env lane
+
+# (shape, dtype, mask heads or None, causal)
+CASES = {
+    "agent-qslice-bf16": (AGENT_QSLICE, "bfloat16", None, False),
+    "agent-qslice-f32": (AGENT_QSLICE, "float32", None, False),
+    "mixer-qslice-bf16": (MIXER_QSLICE, "bfloat16", None, False),
+    "mixer-qslice-f32": (MIXER_QSLICE, "float32", None, False),
+    "agent-dense-bf16": (AGENT_DENSE, "bfloat16", None, False),
+    "agent-dense-bf16-masked": (AGENT_DENSE, "bfloat16", 1, False),
+    "agent-dense-f32-headmask": (AGENT_DENSE, "float32", 4, False),
+    "mixer-dense-bf16": (MIXER_DENSE, "bfloat16", None, False),
+    "mixer-dense-f32-masked": (MIXER_DENSE, "float32", 1, False),
+    "mixer-dense-bf16-causal": (MIXER_DENSE, "bfloat16", None, True),
+    "acting-tq1-bf16": (ACTING_TQ1, "bfloat16", None, False),
+    "acting-tq1-f32-masked": (ACTING_TQ1, "float32", 1, False),
+}
+
+
+@pytest.mark.parametrize("shape,dtype,mask_heads,causal", CASES.values(),
+                         ids=CASES.keys())
+def test_flash_attention_lowers_to_mosaic(v5e, shape, dtype, mask_heads,
+                                          causal):
+    """Forward AND gradient compile for the chip as Mosaic kernels
+    (``tpu_custom_call`` in the compiled text) — compiled geometry
+    (16-row sublane quantum, 128-lane head pad), not the interpret
+    one."""
+    b, h, t_q, t_k, d = shape
+
+    def aval(*s, dt=dtype):
+        return jax.ShapeDtypeStruct(s, jnp.dtype(dt), sharding=v5e)
+
+    q, k, v = aval(b, h, t_q, d), aval(b, h, t_k, d), aval(b, h, t_k, d)
+    mask = (None if mask_heads is None
+            else aval(b, mask_heads, t_q, t_k, dt="float32"))
+
+    def fwd(q, k, v, mask):
+        return flash_attention(q, k, v, mask, causal, interpret=False)
+
+    def loss(q, k, v, mask):
+        return (fwd(q, k, v, mask).astype(jnp.float32) ** 2).sum()
+
+    for fn in (fwd, jax.grad(loss, argnums=(0, 1, 2))):
+        text = jax.jit(fn).lower(q, k, v, mask).compile().as_text()
+        assert "tpu_custom_call" in text
+
+
+@pytest.mark.slow   # ~1 min: the whole fused program through the TPU compiler
+def test_config3_programs_fit_one_v5e_chip(v5e):
+    """The committed north-star file's training programs, at full
+    width, compile for one 16 GB v5e chip from shapes alone — the
+    compiler raises RESOURCE_EXHAUSTED for a program that does not fit
+    (it did, "Used 28.54G of 15.75G hbm", before ``model.remat``)."""
+    from t2omca_tpu.config import load_config
+    from t2omca_tpu.run import Experiment, superstep_eligible
+
+    cfg = load_config(os.path.join(REPO, "configs",
+                                   "config3_tpu_northstar.yaml"))
+    exp = Experiment.build(cfg)
+
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e), tree)
+
+    ts = place(jax.eval_shape(lambda: exp.init_train_state(0)))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    t_env = place(jax.ShapeDtypeStruct((), jnp.int32))
+    rollout, insert, train_iter = exp.jitted_programs(donate=True)
+    agent = ts.learner.params["agent"]
+    lowered = [rollout.lower(agent, ts.runner, test_mode=True)]
+    if superstep_eligible(cfg):
+        k = cfg.superstep
+        keys = place(jax.ShapeDtypeStruct((k,) + key.shape, key.dtype))
+        lowered.append(exp.superstep_program(k, donate=True).lower(
+            ts, keys, t_env))
+    else:
+        batch = place(jax.eval_shape(
+            lambda p, r: rollout(p, r, test_mode=False), agent,
+            ts.runner)[1])
+        lowered += [rollout.lower(agent, ts.runner, test_mode=False),
+                    insert.lower(ts.buffer, batch),
+                    train_iter.lower(ts, place(key), t_env)]
+    hbm = 15.75 * 2 ** 30               # what the compiler itself allows
+    for low in lowered:
+        m = low.compile().memory_analysis()
+        live = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert live < hbm, (live, m)

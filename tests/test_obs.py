@@ -209,8 +209,7 @@ def test_every_driver_phase_is_registered():
     assert driver, "driver phase scan found nothing — scan broken?"
     assert driver <= KNOWN_PHASES, driver - KNOWN_PHASES
     bench = _literal_phases(os.path.join(REPO, "bench.py"))
-    assert {"bench.probe", "bench.build", "bench.compile",
-            "bench.measure"} <= bench
+    assert {"bench.build", "bench.compile", "bench.measure"} <= bench
     assert bench <= KNOWN_PHASES, bench - KNOWN_PHASES
     # the resilience hook table and the span registry stay aligned for
     # the dispatch/fetch boundaries both name
@@ -485,8 +484,7 @@ def test_stall_diagnosis_carries_flight_tail(tmp_path, _no_fault_leaks):
     ``stall_diagnosis.json`` containing the flight-recorder tail with
     the hanging span LAST (open, wall-so-far >= the watchdog timeout).
     The diagnosis is written by the watchdog thread WHILE the main
-    thread is still wedged — the post-mortem trail a wedged BENCH run
-    never used to leave."""
+    thread is still blocked — the post-mortem trail of a hung run."""
     import jax  # noqa: F401 — ensures backend up before timing
     from t2omca_tpu.run import run
     from t2omca_tpu.utils import resilience

@@ -5,8 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from t2omca_tpu.parallel.compat import shard_map
 
 from t2omca_tpu.parallel import make_mesh
 from t2omca_tpu.parallel.ring_attention import (ring_attention,

@@ -103,10 +103,10 @@ def test_watchdog_fires_once_per_stamp():
 
 
 def test_watchdog_wedged_on_stall_does_not_blind_monitor():
-    """on_stall runs on its own thread: a callback wedged inside the
-    stalled backend (the emergency save blocking on a dead tunnel) must
+    """on_stall runs on its own thread: a callback stuck inside the
+    stalled backend (the emergency save blocking on a dead device) must
     not stop the monitor from firing for LATER stalls — otherwise the
-    first wedge permanently disables the hang detection the watchdog
+    first one permanently disables the hang detection the watchdog
     exists to provide."""
     fired = []
     release = threading.Event()
@@ -238,7 +238,7 @@ def test_watchdog_first_occurrence_is_compile_exempt():
 
 def test_watchdog_first_timeout_bounds_cold_phases():
     """resilience.first_dispatch_timeout: an explicit bound on the cold
-    occurrence (the wedged-tunnel-at-startup shape) — the diagnosis must
+    occurrence (a hang at start-up) — the diagnosis must
     carry the limit that actually fired."""
     wd = watchdog.Watchdog(10.0, poll_s=0.01, first_timeout_s=0.05)
     with wd:
