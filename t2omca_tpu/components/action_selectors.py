@@ -61,12 +61,13 @@ class EpsilonGreedySelector:
                t_env: jnp.ndarray, test_mode: bool = False,
                eps_scale=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """q, avail: ``(..., n_actions)`` → (actions ``(...)``, epsilon)."""
-        eps = self.epsilon(t_env, test_mode, eps_scale)
-        k_coin, k_rand = jax.random.split(key)
-        explore = jax.random.uniform(k_coin, q.shape[:-1]) < eps
-        actions = jnp.where(explore, random_avail(k_rand, avail),
-                            masked_argmax(q, avail))
-        return actions, eps
+        with jax.named_scope("act.select"):
+            eps = self.epsilon(t_env, test_mode, eps_scale)
+            k_coin, k_rand = jax.random.split(key)
+            explore = jax.random.uniform(k_coin, q.shape[:-1]) < eps
+            actions = jnp.where(explore, random_avail(k_rand, avail),
+                                masked_argmax(q, avail))
+            return actions, eps
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +86,8 @@ class NoisySelector:
         # NoisyNet exploration lives in the q-head, so the population
         # eps knob has nothing to scale here
         del key, eps_scale
-        return masked_argmax(q, avail), jnp.zeros(())
+        with jax.named_scope("act.select"):
+            return masked_argmax(q, avail), jnp.zeros(())
 
 
 SELECTOR_REGISTRY = {

@@ -250,14 +250,15 @@ class ReplayBuffer:
                              ) -> BufferState:
         """Ring-insert ``B`` episodes; overwrites oldest when full (the
         reference's EpisodeBatch ring semantics)."""
-        b = batch.batch_size
-        idx = self._ring_slots(state, b)
-        # cast to the ring's storage dtypes (int32-avail producers stay
-        # legal; scatter dtype mismatches become hard errors in newer JAX)
-        storage = jax.tree.map(
-            lambda s, x: s.at[idx].set(x.astype(s.dtype)), state.storage,
-            batch)
-        return self._ring_advance(state, storage, idx, b, alpha)
+        with jax.named_scope("replay.insert"):
+            b = batch.batch_size
+            idx = self._ring_slots(state, b)
+            # cast to the ring's storage dtypes (int32-avail producers stay
+            # legal; scatter dtype mismatches become hard errors in newer JAX)
+            storage = jax.tree.map(
+                lambda s, x: s.at[idx].set(x.astype(s.dtype)), state.storage,
+                batch)
+            return self._ring_advance(state, storage, idx, b, alpha)
 
     def insert_time_major(self, state: BufferState,
                           tm: TimeMajorEpisodes,
@@ -279,38 +280,39 @@ class ReplayBuffer:
         program. Contents are bit-identical to
         ``insert_episode_batch(state, tm.to_batch())`` — the fused
         superstep relies on that for K=1 parity."""
-        b = tm.batch_size
-        idx = self._ring_slots(state, b)
-        t1 = self.episode_limit + 1
-        # combined index map shared by every (T+1)-leaf scatter: update
-        # row (t, b) lands at ring element (slots[b], t)
-        t_grid = jnp.broadcast_to(jnp.arange(t1)[:, None], (t1, b))
-        s_grid = jnp.broadcast_to(idx[None, :], (t1, b))
+        with jax.named_scope("replay.insert"):
+            b = tm.batch_size
+            idx = self._ring_slots(state, b)
+            t1 = self.episode_limit + 1
+            # combined index map shared by every (T+1)-leaf scatter: update
+            # row (t, b) lands at ring element (slots[b], t)
+            t_grid = jnp.broadcast_to(jnp.arange(t1)[:, None], (t1, b))
+            s_grid = jnp.broadcast_to(idx[None, :], (t1, b))
 
-        def put_tp1(s, seq, last):
-            """(cap, T+1, ...) leaf ← one scatter of the time-major
-            (T+1, B, ...) updates (scan stack ++ bootstrap step)."""
-            upd = jnp.concatenate([seq, last[None]], axis=0)
-            return s.at[s_grid, t_grid].set(upd.astype(s.dtype))
+            def put_tp1(s, seq, last):
+                """(cap, T+1, ...) leaf ← one scatter of the time-major
+                (T+1, B, ...) updates (scan stack ++ bootstrap step)."""
+                upd = jnp.concatenate([seq, last[None]], axis=0)
+                return s.at[s_grid, t_grid].set(upd.astype(s.dtype))
 
-        def put_t(s, seq):
-            """(cap, T, ...) leaf ← one scatter of the time-major
-            (T, B, ...) scan stack (same combined index map, first T
-            rows — no transpose here either)."""
-            return s.at[s_grid[:-1], t_grid[:-1]].set(seq.astype(s.dtype))
+            def put_t(s, seq):
+                """(cap, T, ...) leaf ← one scatter of the time-major
+                (T, B, ...) scan stack (same combined index map, first T
+                rows — no transpose here either)."""
+                return s.at[s_grid[:-1], t_grid[:-1]].set(seq.astype(s.dtype))
 
-        st = state.storage
-        storage = st.replace(
-            obs=jax.tree.map(put_tp1, st.obs, tm.obs, tm.last_obs),
-            state=put_tp1(st.state, tm.state, tm.last_state),
-            avail_actions=put_tp1(st.avail_actions, tm.avail_actions,
-                                  tm.last_avail),
-            actions=put_t(st.actions, tm.actions),
-            reward=put_t(st.reward, tm.reward),
-            terminated=put_t(st.terminated, tm.terminated),
-            filled=st.filled.at[idx].set(True),
-        )
-        return self._ring_advance(state, storage, idx, b, alpha)
+            st = state.storage
+            storage = st.replace(
+                obs=jax.tree.map(put_tp1, st.obs, tm.obs, tm.last_obs),
+                state=put_tp1(st.state, tm.state, tm.last_state),
+                avail_actions=put_tp1(st.avail_actions, tm.avail_actions,
+                                      tm.last_avail),
+                actions=put_t(st.actions, tm.actions),
+                reward=put_t(st.reward, tm.reward),
+                terminated=put_t(st.terminated, tm.terminated),
+                filled=st.filled.at[idx].set(True),
+            )
+            return self._ring_advance(state, storage, idx, b, alpha)
 
     def can_sample(self, state: BufferState, batch_size: int) -> jnp.ndarray:
         return state.episodes_in_buffer >= batch_size
@@ -324,14 +326,17 @@ class ReplayBuffer:
         """→ (batch, idx, weights). Uniform without replacement (weights = 1),
         same return signature as PER so the driver is agnostic
         (``per_run.py:224``)."""
-        del t_env
-        n = state.episodes_in_buffer
-        # top-batch_size of random scores over valid slots ≡ sampling without
-        # replacement with static shapes (caller gates on can_sample)
-        scores = jax.random.uniform(key, (self.capacity,))
-        scores = jnp.where(jnp.arange(self.capacity) < n, scores, -jnp.inf)
-        _, idx = jax.lax.top_k(scores, batch_size)
-        return self._gather(state, idx), idx, jnp.ones((batch_size,))
+        with jax.named_scope("replay.sample"):
+            del t_env
+            n = state.episodes_in_buffer
+            # top-batch_size of random scores over valid slots ≡ sampling
+            # without replacement with static shapes (caller gates on
+            # can_sample)
+            scores = jax.random.uniform(key, (self.capacity,))
+            scores = jnp.where(jnp.arange(self.capacity) < n, scores,
+                               -jnp.inf)
+            _, idx = jax.lax.top_k(scores, batch_size)
+            return self._gather(state, idx), idx, jnp.ones((batch_size,))
 
     def update_priorities(self, state: BufferState, idx: jnp.ndarray,
                           priorities: jnp.ndarray,
@@ -375,20 +380,21 @@ class PrioritizedReplayBuffer(ReplayBuffer):
     def sample(self, state: BufferState, key: jax.Array, batch_size: int,
                t_env: jnp.ndarray = 0
                ) -> Tuple[EpisodeBatch, jnp.ndarray, jnp.ndarray]:
-        probs = self._probs(state)
-        cdf = jnp.cumsum(probs)
-        # stratified inverse-CDF: one uniform per equal-mass stratum
-        u = (jnp.arange(batch_size)
-             + jax.random.uniform(key, (batch_size,))) / batch_size
-        idx = jnp.searchsorted(cdf, u * cdf[-1], side="left")
-        idx = jnp.clip(idx, 0, self.capacity - 1)
+        with jax.named_scope("replay.sample"):
+            probs = self._probs(state)
+            cdf = jnp.cumsum(probs)
+            # stratified inverse-CDF: one uniform per equal-mass stratum
+            u = (jnp.arange(batch_size)
+                 + jax.random.uniform(key, (batch_size,))) / batch_size
+            idx = jnp.searchsorted(cdf, u * cdf[-1], side="left")
+            idx = jnp.clip(idx, 0, self.capacity - 1)
 
-        beta = self.beta0 + (1.0 - self.beta0) * jnp.clip(
-            jnp.asarray(t_env, jnp.float32) / self.t_max, 0.0, 1.0)
-        n = jnp.maximum(state.episodes_in_buffer, 1).astype(jnp.float32)
-        w = (n * jnp.maximum(probs[idx], 1e-12)) ** (-beta)
-        w = w / jnp.maximum(w.max(), 1e-12)
-        return self._gather(state, idx), idx, w
+            beta = self.beta0 + (1.0 - self.beta0) * jnp.clip(
+                jnp.asarray(t_env, jnp.float32) / self.t_max, 0.0, 1.0)
+            n = jnp.maximum(state.episodes_in_buffer, 1).astype(jnp.float32)
+            w = (n * jnp.maximum(probs[idx], 1e-12)) ** (-beta)
+            w = w / jnp.maximum(w.max(), 1e-12)
+            return self._gather(state, idx), idx, w
 
     def update_priorities(self, state: BufferState, idx: jnp.ndarray,
                           priorities: jnp.ndarray,
@@ -413,12 +419,13 @@ class PrioritizedReplayBuffer(ReplayBuffer):
         exponent — the graftpop per-member PER-alpha seam (each vmapped
         member's ring then stores ``p^alpha_i`` consistently across
         insert-stamp, feedback and sample-normalize)."""
-        pa = priorities ** (self.alpha if alpha is None else alpha)
-        new_max = jnp.maximum(state.max_priority, priorities.max())
-        if valid is not None:
-            pa = jnp.where(valid, pa, state.priorities[idx])
-            new_max = jnp.where(valid, new_max, state.max_priority)
-        return state.replace(
-            priorities=state.priorities.at[idx].set(pa),
-            max_priority=new_max,
-        )
+        with jax.named_scope("replay.priority"):
+            pa = priorities ** (self.alpha if alpha is None else alpha)
+            new_max = jnp.maximum(state.max_priority, priorities.max())
+            if valid is not None:
+                pa = jnp.where(valid, pa, state.priorities[idx])
+                new_max = jnp.where(valid, new_max, state.max_priority)
+            return state.replace(
+                priorities=state.priorities.at[idx].set(pa),
+                max_priority=new_max,
+            )

@@ -204,15 +204,16 @@ class BasicMAC:
         act_dtype-free — serving's dtype story is the per-variant cast,
         not the training run's rollout knob)."""
         ad = jnp.dtype(dtype) if dtype is not None else self._acting_dtype
-        if not self.use_qslice:
-            return self._cast_acting(params, ad)
-        from ..ops.query_slice import fold_agent_params
-        a = self.agent
-        folded = fold_agent_params(params, emb=a.emb, heads=a.heads,
-                                   depth=a.depth,
-                                   standard_heads=a.standard_heads,
-                                   dtype=ad)
-        return self._cast_acting(folded, ad)
+        with jax.named_scope("act.forward"):
+            if not self.use_qslice:
+                return self._cast_acting(params, ad)
+            from ..ops.query_slice import fold_agent_params
+            a = self.agent
+            folded = fold_agent_params(params, emb=a.emb, heads=a.heads,
+                                       depth=a.depth,
+                                       standard_heads=a.standard_heads,
+                                       dtype=ad)
+            return self._cast_acting(folded, ad)
 
     def _cast_acting(self, tree, ad):
         """Pre-cast f32 param leaves to the acting dtype — only in the
@@ -239,19 +240,21 @@ class BasicMAC:
         ``eps_scale`` (optional traced scalar) is the graftpop
         per-member epsilon multiplier, forwarded to the selector."""
         k_noise, k_sel = jax.random.split(key)
-        if self.use_entity_tables and compact is not None:
-            q, hidden = self.forward_entity(params, compact, hidden,
-                                            key=k_noise,
-                                            deterministic=test_mode,
-                                            acting=True)
-        elif self.use_qslice:
-            q, hidden = self.forward_qslice(params, obs, hidden,
-                                            key=k_noise,
-                                            deterministic=test_mode,
-                                            acting=True)
-        else:
-            q, hidden = self.forward(params, obs, hidden, key=k_noise,
-                                     deterministic=test_mode, acting=True)
+        with jax.named_scope("act.forward"):
+            if self.use_entity_tables and compact is not None:
+                q, hidden = self.forward_entity(params, compact, hidden,
+                                                key=k_noise,
+                                                deterministic=test_mode,
+                                                acting=True)
+            elif self.use_qslice:
+                q, hidden = self.forward_qslice(params, obs, hidden,
+                                                key=k_noise,
+                                                deterministic=test_mode,
+                                                acting=True)
+            else:
+                q, hidden = self.forward(params, obs, hidden, key=k_noise,
+                                         deterministic=test_mode,
+                                         acting=True)
         actions, eps = self.selector.select(k_sel, q, avail, t_env,
                                             test_mode=test_mode,
                                             eps_scale=eps_scale)

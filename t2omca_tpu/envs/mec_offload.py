@@ -374,26 +374,34 @@ class MultiAgvOffloadingEnv:
             # materialized raw matrix, but when no consumer reads it (the
             # entity-table acting + compact-storage stack) XLA dead-code
             # eliminates the whole O(A²) materialization from the rollout
-            rows, same_mec = self._entity_parts(state, params)
-            norm = select_update(
-                state.norm,
-                welford_update_batch_factored(state.norm, rows, same_mec),
-                update_norm)
-            obs = apply_norm(norm, self._raw_obs(state, params))
+            with jax.named_scope("env.normalizer"):
+                rows, same_mec = self._entity_parts(state, params)
+                norm = select_update(
+                    state.norm,
+                    welford_update_batch_factored(state.norm, rows,
+                                                  same_mec),
+                    update_norm)
+            with jax.named_scope("env.obs"):
+                obs = apply_norm(norm, self._raw_obs(state, params))
             return state.replace(norm=norm), obs
 
-        raw = self._raw_obs(state, params)
+        with jax.named_scope("env.obs"):
+            raw = self._raw_obs(state, params)
 
-        if self.cfg.fast_norm:
-            norm, obs = normalize_batch(state.norm, raw, update=update_norm)
+        # the dense normalizers update and apply in one call: both under
+        # the normalizer's name
+        with jax.named_scope("env.normalizer"):
+            if self.cfg.fast_norm:
+                norm, obs = normalize_batch(state.norm, raw,
+                                            update=update_norm)
+                return state.replace(norm=norm), obs
+
+            def body(carry: NormState, x):
+                carry, y = normalize(carry, x, update=update_norm)
+                return carry, y
+
+            norm, obs = jax.lax.scan(body, state.norm, raw)
             return state.replace(norm=norm), obs
-
-        def body(carry: NormState, x):
-            carry, y = normalize(carry, x, update=update_norm)
-            return carry, y
-
-        norm, obs = jax.lax.scan(body, state.norm, raw)
-        return state.replace(norm=norm), obs
 
     def compact_obs(self, state: EnvState,
                     params: "EnvParams | None" = None

@@ -322,36 +322,37 @@ class QMixLearner:
         # forward instead of reconstructing the flat obs (the mixer never
         # reads obs in state_entity_mode, which the storage gate requires).
         from ..components.episode_buffer import CompactEntityObs
-        if isinstance(batch.obs, CompactEntityObs):
-            co = batch.obs
-            mec = jnp.swapaxes(co.mec_index, 0, 1)
-            compact_tm = (
-                jnp.swapaxes(co.rows, 0, 1).astype(jnp.float32),
-                mec[..., :, None] == mec[..., None, :],
-                jnp.swapaxes(co.mean, 0, 1),
-                jnp.swapaxes(co.std, 0, 1),
-            )
-            obs = None
-        else:
-            compact_tm = None
-            obs = jnp.swapaxes(batch.obs, 0, 1).astype(jnp.float32)
-        state = jnp.swapaxes(batch.state, 0, 1).astype(jnp.float32)
-        avail = jnp.swapaxes(batch.avail_actions, 0, 1)   # (T+1, B, A, n)
-        actions = jnp.swapaxes(batch.actions, 0, 1)       # (T, B, A)
-        reward = jnp.swapaxes(batch.reward, 0, 1)         # (T, B)
-        term = jnp.swapaxes(batch.terminated, 0, 1).astype(jnp.float32)
-        mask = jnp.swapaxes(batch.filled, 0, 1).astype(jnp.float32)
+        with jax.named_scope("learner.loss"):
+            if isinstance(batch.obs, CompactEntityObs):
+                co = batch.obs
+                mec = jnp.swapaxes(co.mec_index, 0, 1)
+                compact_tm = (
+                    jnp.swapaxes(co.rows, 0, 1).astype(jnp.float32),
+                    mec[..., :, None] == mec[..., None, :],
+                    jnp.swapaxes(co.mean, 0, 1),
+                    jnp.swapaxes(co.std, 0, 1),
+                )
+                obs = None
+            else:
+                compact_tm = None
+                obs = jnp.swapaxes(batch.obs, 0, 1).astype(jnp.float32)
+            state = jnp.swapaxes(batch.state, 0, 1).astype(jnp.float32)
+            avail = jnp.swapaxes(batch.avail_actions, 0, 1)   # (T+1, B, A, n)
+            actions = jnp.swapaxes(batch.actions, 0, 1)       # (T, B, A)
+            reward = jnp.swapaxes(batch.reward, 0, 1)         # (T, B)
+            term = jnp.swapaxes(batch.terminated, 0, 1).astype(jnp.float32)
+            mask = jnp.swapaxes(batch.filled, 0, 1).astype(jnp.float32)
 
-        if key is not None:
-            k_ag, k_tag, k_mx, k_tmx = jax.random.split(key, 4)
-            if cfg.model.dropout == 0.0:
-                # noisy-only configs: the mixer has no noise source
-                # (NoisyLinear lives in the agent q-head only), so its
-                # unroll stays on the deterministic fast path — passing
-                # keys here forced the dense flax mixer scan for nothing
-                k_mx = k_tmx = None
-        else:
-            k_ag = k_tag = k_mx = k_tmx = None
+            if key is not None:
+                k_ag, k_tag, k_mx, k_tmx = jax.random.split(key, 4)
+                if cfg.model.dropout == 0.0:
+                    # noisy-only configs: the mixer has no noise source
+                    # (NoisyLinear lives in the agent q-head only), so its
+                    # unroll stays on the deterministic fast path — passing
+                    # keys here forced the dense flax mixer scan for nothing
+                    k_mx = k_tmx = None
+            else:
+                k_ag = k_tag = k_mx = k_tmx = None
 
         # the two unrolls stay SEPARATE deliberately: the target unroll
         # feeds only stop_gradient-terminated consumers, so partial eval
@@ -359,10 +360,12 @@ class QMixLearner:
         # both into one stacked scan would re-attach the target lane to the
         # VJP (zero cotangents still cost full backward matmuls + 2x scan
         # residual memory), trading a halved forward for a heavier backward
-        qs, hs = self._unroll_agent(params["agent"], obs, k_ag,
-                                    compact_tm=compact_tm)
-        target_qs, target_hs = self._unroll_agent(
-            target_params["agent"], obs, k_tag, compact_tm=compact_tm)
+        with jax.named_scope("learner.agent"):
+            qs, hs = self._unroll_agent(params["agent"], obs, k_ag,
+                                        compact_tm=compact_tm)
+        with jax.named_scope("learner.target"):
+            target_qs, target_hs = self._unroll_agent(
+                target_params["agent"], obs, k_tag, compact_tm=compact_tm)
 
         # mixer-side padding mask (graftworld fleet-size randomization,
         # ROADMAP item 3's open remainder): padded agents are
@@ -388,92 +391,97 @@ class QMixLearner:
         # tests/test_population.py). The gate is config-STATIC
         # (_mask_padded): non-padding configs never compile any of
         # this.
-        if self._mask_padded:
-            saw_job = (avail[..., 1:] > 0).any(axis=(0, -1))  # (B, A)
-            # active = suffix-any of saw_job: agent i is masked only
-            # when agents i..A-1 ALL never saw a job (the padded tail)
-            act_m = jnp.flip(jax.lax.cummax(
-                jnp.flip(saw_job.astype(jnp.int32), -1), axis=1),
-                -1) > 0
+        with jax.named_scope("learner.loss"):
+            if self._mask_padded:
+                saw_job = (avail[..., 1:] > 0).any(axis=(0, -1))  # (B, A)
+                # active = suffix-any of saw_job: agent i is masked only
+                # when agents i..A-1 ALL never saw a job (the padded tail)
+                act_m = jnp.flip(jax.lax.cummax(
+                    jnp.flip(saw_job.astype(jnp.int32), -1), axis=1),
+                    -1) > 0
 
-            def _padmask(x):
-                # zero padded agents along the trailing agent axis
-                # (x: (T?, B, A) or (T?, B, A, F))
-                m = act_m.astype(x.dtype)
-                return x * (m[None] if x.ndim == 3 else m[None, ..., None])
+                def _padmask(x):
+                    # zero padded agents along the trailing agent axis
+                    # (x: (T?, B, A) or (T?, B, A, F))
+                    m = act_m.astype(x.dtype)
+                    return x * (m[None] if x.ndim == 3 else m[None, ..., None])
 
-            hs, target_hs = _padmask(hs), _padmask(target_hs)
-            if obs is not None:
-                # Q12 fallback path: the mixer tokenizes all agents'
-                # obs — padded rows go in as zeros too
-                obs = _padmask(obs)
-        else:
-            _padmask = lambda x: x  # noqa: E731 — static no-op branch
+                hs, target_hs = _padmask(hs), _padmask(target_hs)
+                if obs is not None:
+                    # Q12 fallback path: the mixer tokenizes all agents'
+                    # obs — padded rows go in as zeros too
+                    obs = _padmask(obs)
+            else:
+                _padmask = lambda x: x  # noqa: E731 — static no-op branch
 
-        chosen = _padmask(jnp.take_along_axis(
-            qs[:-1], actions[..., None], axis=-1)[..., 0])  # (T, B, A)
+            chosen = _padmask(jnp.take_along_axis(
+                qs[:-1], actions[..., None], axis=-1)[..., 0])  # (T, B, A)
 
-        # illegal actions suppressed in targets (MAC masking contract);
-        # computed over ALL T+1 steps so the target mixer can unroll its
-        # hyper-token recurrence from t=0 with the same history depth as
-        # the online mixer (the targets themselves use steps [1:])
-        masked_all = jnp.where(avail > 0, qs, -jnp.inf)
-        if cfg.double_q:
-            best = jnp.argmax(masked_all, axis=-1)         # online argmax
-            target_max = jnp.take_along_axis(
-                target_qs, best[..., None], axis=-1)[..., 0]
-        else:
-            target_max = jnp.where(
-                avail > 0, target_qs, -jnp.inf).max(axis=-1)
-        target_max = _padmask(target_max)
+            # illegal actions suppressed in targets (MAC masking contract);
+            # computed over ALL T+1 steps so the target mixer can unroll its
+            # hyper-token recurrence from t=0 with the same history depth as
+            # the online mixer (the targets themselves use steps [1:])
+            masked_all = jnp.where(avail > 0, qs, -jnp.inf)
+            if cfg.double_q:
+                best = jnp.argmax(masked_all, axis=-1)         # online argmax
+                target_max = jnp.take_along_axis(
+                    target_qs, best[..., None], axis=-1)[..., 0]
+            else:
+                target_max = jnp.where(
+                    avail > 0, target_qs, -jnp.inf).max(axis=-1)
+            target_max = _padmask(target_max)
 
-        obs_m = None if obs is None else obs[:-1]
-        q_tot = self._unroll_mixer(
-            params["mixer"], chosen, hs[:-1], state[:-1], obs_m, k_mx)
+            obs_m = None if obs is None else obs[:-1]
+        with jax.named_scope("learner.mixer"):
+            q_tot = self._unroll_mixer(
+                params["mixer"], chosen, hs[:-1], state[:-1], obs_m, k_mx)
         # target unroll spans t=0..T (recurrence semantics of
         # /root/reference/n_transf_mixer.py:55,91: both nets start their
         # hyper recurrence at the episode start); outputs [1:] are the
         # bootstrap values
-        target_q_tot = self._unroll_mixer(
-            target_params["mixer"], target_max, target_hs, state,
-            obs, k_tmx)[1:]   # obs may be None (compact storage: the
-        # state-entity mixer never reads it)
+        with jax.named_scope("learner.target"):
+            target_q_tot = self._unroll_mixer(
+                target_params["mixer"], target_max, target_hs, state,
+                obs, k_tmx)[1:]   # obs may be None (compact storage: the
+            # state-entity mixer never reads it)
 
-        # reward_unit: static train-time unit normalization (the value
-        # function is learned in reward/reward_unit units; logged returns
-        # stay raw — see config.py loss-scale levers). 1.0 = off, exact.
-        if cfg.reward_unit != 1.0:
-            reward = reward / cfg.reward_unit
-        targets = reward + cfg.gamma * (1.0 - term) * target_q_tot
-        td = (q_tot - jax.lax.stop_gradient(targets)) * mask
+        with jax.named_scope("learner.loss"):
+            # reward_unit: static train-time unit normalization (the value
+            # function is learned in reward/reward_unit units; logged returns
+            # stay raw — see config.py loss-scale levers). 1.0 = off, exact.
+            if cfg.reward_unit != 1.0:
+                reward = reward / cfg.reward_unit
+            targets = reward + cfg.gamma * (1.0 - term) * target_q_tot
+            td = (q_tot - jax.lax.stop_gradient(targets)) * mask
 
-        denom = jnp.maximum(mask.sum(), 1.0)
-        if cfg.td_loss == "huber":
-            # 2x-scaled Huber: td^2 inside |td|<=delta (matches the MSE
-            # branch exactly), linear with slope 2*delta outside — bounds
-            # each element's dLoss/dq_tot at 2*delta (config.py rationale).
-            # Deliberately NOT optax.huber_loss: its min()-based form
-            # accumulates backward cotangents as q + delta - delta, which
-            # cancels catastrophically in f32 once delta >> |td| (grads of
-            # small TDs round to 0 at delta=1e9, breaking the delta->inf
-            # == MSE identity the tests pin); branch selection via where
-            # keeps each cotangent path exact at any delta.
-            d = cfg.huber_delta
-            abs_td = jnp.abs(td)
-            elem = jnp.where(abs_td <= d, td ** 2, 2.0 * d * abs_td - d * d)
-        else:
-            elem = td ** 2
-        loss = (weights[None, :] * elem).sum() / denom
+            denom = jnp.maximum(mask.sum(), 1.0)
+            if cfg.td_loss == "huber":
+                # 2x-scaled Huber: td^2 inside |td|<=delta (matches the MSE
+                # branch exactly), linear with slope 2*delta outside — bounds
+                # each element's dLoss/dq_tot at 2*delta (config.py rationale).
+                # Deliberately NOT optax.huber_loss: its min()-based form
+                # accumulates backward cotangents as q + delta - delta, which
+                # cancels catastrophically in f32 once delta >> |td| (grads of
+                # small TDs round to 0 at delta=1e9, breaking the delta->inf
+                # == MSE identity the tests pin); branch selection via where
+                # keeps each cotangent path exact at any delta.
+                d = cfg.huber_delta
+                abs_td = jnp.abs(td)
+                elem = jnp.where(abs_td <= d, td ** 2,
+                                 2.0 * d * abs_td - d * d)
+            else:
+                elem = td ** 2
+            loss = (weights[None, :] * elem).sum() / denom
 
-        ep_mask = jnp.maximum(mask.sum(axis=0), 1.0)
-        info = {
-            "loss": loss,
-            "td_error_abs": jnp.abs(td).sum() / denom,
-            "q_taken_mean": (chosen.mean(axis=-1) * mask).sum() / denom,
-            "target_mean": (targets * mask).sum() / denom,
-            # per-episode priorities (Q9): masked mean |TD| per sample
-            "td_errors_abs": jnp.abs(td).sum(axis=0) / ep_mask,   # (B,)
-        }
+            ep_mask = jnp.maximum(mask.sum(axis=0), 1.0)
+            info = {
+                "loss": loss,
+                "td_error_abs": jnp.abs(td).sum() / denom,
+                "q_taken_mean": (chosen.mean(axis=-1) * mask).sum() / denom,
+                "target_mean": (targets * mask).sum() / denom,
+                # per-episode priorities (Q9): masked mean |TD| per sample
+                "td_errors_abs": jnp.abs(td).sum(axis=0) / ep_mask,   # (B,)
+            }
         if cfg.obs.sight.enabled:
             # graftsight in-graph diagnostics (docs/OBSERVABILITY.md §6):
             # value-scale histograms + one-timestep attention-entropy
@@ -574,25 +582,27 @@ class QMixLearner:
             return loss, info
 
         grads, info = jax.grad(loss_fn, has_aux=True)(ls.params)
-        info["grad_norm"] = optax.global_norm(grads)
-        all_finite = (jnp.isfinite(info["loss"])
-                      & jnp.isfinite(info["grad_norm"]))
-        info["all_finite"] = all_finite
-        updates, opt_state = opt.update(grads, ls.opt_state, ls.params)
-        if spec is not None:
-            # graftpop per-member lr: scale the update tree (exact — see
-            # the docstring; opt_state is lr-independent by construction)
-            updates = jax.tree.map(
-                lambda u: u * spec.lr_scale.astype(u.dtype), updates)
-        params = optax.apply_updates(ls.params, updates)
-        # guard rail: a tripped step is a no-op on params AND opt state
-        # (a NaN grad corrupts Adam's mu/nu permanently, so opt_state must
-        # pass through too, not just params)
-        params = jax.tree.map(
-            lambda n, o: jnp.where(all_finite, n, o), params, ls.params)
-        opt_state = jax.tree.map(
-            lambda n, o: jnp.where(all_finite, n, o), opt_state,
-            ls.opt_state)
+        with jax.named_scope("learner.optimizer"):
+            info["grad_norm"] = optax.global_norm(grads)
+            all_finite = (jnp.isfinite(info["loss"])
+                          & jnp.isfinite(info["grad_norm"]))
+            info["all_finite"] = all_finite
+            updates, opt_state = opt.update(grads, ls.opt_state, ls.params)
+            if spec is not None:
+                # graftpop per-member lr: scale the update tree (exact —
+                # see the docstring; opt_state is lr-independent by
+                # construction)
+                updates = jax.tree.map(
+                    lambda u: u * spec.lr_scale.astype(u.dtype), updates)
+            params = optax.apply_updates(ls.params, updates)
+            # guard rail: a tripped step is a no-op on params AND opt
+            # state (a NaN grad corrupts Adam's mu/nu permanently, so
+            # opt_state must pass through too, not just params)
+            params = jax.tree.map(
+                lambda n, o: jnp.where(all_finite, n, o), params, ls.params)
+            opt_state = jax.tree.map(
+                lambda n, o: jnp.where(all_finite, n, o), opt_state,
+                ls.opt_state)
         if self.cfg.obs.sight.enabled:
             # graftsight learner-tail block: per-module grad/update
             # norms, importance-weight ESS, target drift — computed
@@ -603,19 +613,21 @@ class QMixLearner:
                 self.cfg, grads, updates, params, ls.target_params,
                 weights))
 
-        episode = jnp.asarray(episode, jnp.int32)
-        sync = (episode - ls.last_target_update
-                ) >= self.cfg.target_update_interval
-        target_params = jax.tree.map(
-            lambda p, tp: jnp.where(sync, p, tp), params, ls.target_params)
-        return LearnerState(
-            params=params,
-            target_params=target_params,
-            opt_state=opt_state,
-            train_steps=ls.train_steps + 1,
-            last_target_update=jnp.where(sync, episode,
-                                         ls.last_target_update),
-        ), info
+        with jax.named_scope("learner.optimizer"):
+            episode = jnp.asarray(episode, jnp.int32)
+            sync = (episode - ls.last_target_update
+                    ) >= self.cfg.target_update_interval
+            target_params = jax.tree.map(
+                lambda p, tp: jnp.where(sync, p, tp), params,
+                ls.target_params)
+            return LearnerState(
+                params=params,
+                target_params=target_params,
+                opt_state=opt_state,
+                train_steps=ls.train_steps + 1,
+                last_target_update=jnp.where(sync, episode,
+                                             ls.last_target_update),
+            ), info
 
 
 LEARNER_REGISTRY = {"qmix_learner": QMixLearner}

@@ -44,14 +44,16 @@ class TransformerAgent(nn.Module):
     def __call__(self, inputs: jax.Array, hidden_state: jax.Array,
                  deterministic: bool = True) -> Tuple[jax.Array, jax.Array]:
         b, a, _ = inputs.shape
-        x = inputs.reshape(b * a, self.n_entities, self.feat_dim)
-        h = hidden_state.reshape(b * a, 1, self.emb).astype(self.dtype)
+        with jax.named_scope("agent.embed"):
+            x = inputs.reshape(b * a, self.n_entities, self.feat_dim)
+            h = hidden_state.reshape(b * a, 1, self.emb).astype(self.dtype)
 
-        embs = nn.Dense(self.emb, name="feat_embedding", dtype=self.dtype,
-                        kernel_init=orthogonal_or_default(self.use_orthogonal))(x)
+            embs = nn.Dense(
+                self.emb, name="feat_embedding", dtype=self.dtype,
+                kernel_init=orthogonal_or_default(self.use_orthogonal))(x)
 
-        # hidden token prepended at position 0 (transf_agent.py:65)
-        tokens = jnp.concatenate([h, embs], axis=1)
+            # hidden token prepended at position 0 (transf_agent.py:65)
+            tokens = jnp.concatenate([h, embs], axis=1)
 
         out = Transformer(
             emb=self.emb, heads=self.heads, depth=self.depth,
@@ -61,19 +63,23 @@ class TransformerAgent(nn.Module):
             attn_impl=self.attn_impl,
             name="transformer")(tokens, tokens, deterministic=deterministic)
 
-        h_new = out[:, 0:1, :].astype(jnp.float32)  # token 0 = new hidden (:71)
+        with jax.named_scope("agent.head"):
+            # token 0 = new hidden (:71)
+            h_new = out[:, 0:1, :].astype(jnp.float32)
 
-        if self.noisy:
-            q = NoisyLinear(self.n_actions, name="q_basic")(
-                h_new, deterministic=deterministic)
-        else:
-            q = nn.Dense(self.n_actions, name="q_basic",
-                         kernel_init=orthogonal_or_default(self.use_orthogonal))(h_new)
+            if self.noisy:
+                q = NoisyLinear(self.n_actions, name="q_basic")(
+                    h_new, deterministic=deterministic)
+            else:
+                init = orthogonal_or_default(self.use_orthogonal)
+                q = nn.Dense(self.n_actions, name="q_basic",
+                             kernel_init=init)(h_new)
 
-        # Q-values and the carried hidden token stay f32 regardless of the
-        # compute dtype (selector argmax + TD math need full precision)
-        return (q.astype(jnp.float32).reshape(b, a, self.n_actions),
-                h_new.reshape(b, a, self.emb))
+            # Q-values and the carried hidden token stay f32 regardless of
+            # the compute dtype (selector argmax + TD math need full
+            # precision)
+            return (q.astype(jnp.float32).reshape(b, a, self.n_actions),
+                    h_new.reshape(b, a, self.emb))
 
     def initial_hidden(self, batch_size: int) -> jax.Array:
         """Zeros ``(batch, n_agents, emb)`` (reference ``init_hidden`` zeros

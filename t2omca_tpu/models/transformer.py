@@ -151,26 +151,30 @@ class TransformerBlock(nn.Module):
     def __call__(self, q: jax.Array, k: jax.Array,
                  mask: Optional[jax.Array] = None,
                  deterministic: bool = True) -> jax.Array:
-        attended = MultiHeadAttention(
-            emb=self.emb, heads=self.heads, causal=self.causal,
-            standard_heads=self.standard_heads,
-            use_orthogonal=self.use_orthogonal, dtype=self.dtype,
-            attn_impl=self.attn_impl,
-            name="attention")(q, k, mask)
+        with jax.named_scope("agent.attention"):
+            attended = MultiHeadAttention(
+                emb=self.emb, heads=self.heads, causal=self.causal,
+                standard_heads=self.standard_heads,
+                use_orthogonal=self.use_orthogonal, dtype=self.dtype,
+                attn_impl=self.attn_impl,
+                name="attention")(q, k, mask)
 
-        x = nn.LayerNorm(name="norm1", dtype=self.dtype)(attended + q)
-        x = nn.Dropout(self.dropout, deterministic=deterministic)(x)
+        # the block's tail (post-LN residuals + FFN) is one scope, as in
+        # ops/query_slice._block_tail
+        with jax.named_scope("agent.ff"):
+            x = nn.LayerNorm(name="norm1", dtype=self.dtype)(attended + q)
+            x = nn.Dropout(self.dropout, deterministic=deterministic)(x)
 
-        init = orthogonal_or_default(self.use_orthogonal)
-        ff = nn.Dense(self.ff_hidden_mult * self.emb, name="ff1",
-                      dtype=self.dtype, kernel_init=init)(x)
-        ff = nn.relu(ff)
-        ff = nn.Dense(self.emb, name="ff2", dtype=self.dtype,
-                      kernel_init=init)(ff)
+            init = orthogonal_or_default(self.use_orthogonal)
+            ff = nn.Dense(self.ff_hidden_mult * self.emb, name="ff1",
+                          dtype=self.dtype, kernel_init=init)(x)
+            ff = nn.relu(ff)
+            ff = nn.Dense(self.emb, name="ff2", dtype=self.dtype,
+                          kernel_init=init)(ff)
 
-        x = nn.LayerNorm(name="norm2", dtype=self.dtype)(ff + x)
-        x = nn.Dropout(self.dropout, deterministic=deterministic)(x)
-        return x
+            x = nn.LayerNorm(name="norm2", dtype=self.dtype)(ff + x)
+            x = nn.Dropout(self.dropout, deterministic=deterministic)(x)
+            return x
 
 
 class Transformer(nn.Module):

@@ -128,22 +128,24 @@ def learner_train_info(cfg, grads, updates, params, target_params,
     per-module gradient and update norms, importance-weight effective
     sample size (fraction of batch), and target-network drift
     (relative param distance to the target copy)."""
+    import jax
     import jax.numpy as jnp
     info = {}
-    for name, leaves in module_groups(cfg, grads).items():
-        info[f"sight_grad_norm_{name}"] = _global_norm(leaves)
-    for name, leaves in module_groups(cfg, updates).items():
-        info[f"sight_update_norm_{name}"] = _global_norm(leaves)
-    w = jnp.asarray(weights, jnp.float32)
-    s1, s2 = w.sum(), (w * w).sum()
-    info["sight_per_ess"] = (s1 * s1) / (w.shape[0]
-                                         * jnp.maximum(s2, _EPS))
-    import jax
-    diff = jax.tree.map(lambda p, t: p.astype(jnp.float32)
-                        - t.astype(jnp.float32), params, target_params)
-    info["sight_target_drift"] = (
-        _global_norm(jax.tree.leaves(diff))
-        / jnp.maximum(_global_norm(jax.tree.leaves(target_params)), _EPS))
+    with jax.named_scope("sight"):
+        for name, leaves in module_groups(cfg, grads).items():
+            info[f"sight_grad_norm_{name}"] = _global_norm(leaves)
+        for name, leaves in module_groups(cfg, updates).items():
+            info[f"sight_update_norm_{name}"] = _global_norm(leaves)
+        w = jnp.asarray(weights, jnp.float32)
+        s1, s2 = w.sum(), (w * w).sum()
+        info["sight_per_ess"] = (s1 * s1) / (w.shape[0]
+                                             * jnp.maximum(s2, _EPS))
+        diff = jax.tree.map(lambda p, t: p.astype(jnp.float32)
+                            - t.astype(jnp.float32), params, target_params)
+        info["sight_target_drift"] = (
+            _global_norm(jax.tree.leaves(diff))
+            / jnp.maximum(_global_norm(jax.tree.leaves(target_params)),
+                          _EPS))
     return info
 
 
@@ -154,14 +156,16 @@ def loss_sight_info(sight_cfg, td, chosen, targets, mask) -> dict:
     dead-value collapse shows up in first. All inputs pre-detached by
     the caller (``stop_gradient``) so the probe never touches the
     backward pass."""
+    import jax
     b, q = float(sight_cfg.td_range), float(sight_cfg.q_range)
     n = int(sight_cfg.bins)
-    return {
-        "sight_td_hist": masked_histogram(td, mask, -b, b, n),
-        "sight_q_taken_hist": masked_histogram(
-            chosen, mask[..., None], -q, q, n),
-        "sight_target_hist": masked_histogram(targets, mask, -q, q, n),
-    }
+    with jax.named_scope("sight"):
+        return {
+            "sight_td_hist": masked_histogram(td, mask, -b, b, n),
+            "sight_q_taken_hist": masked_histogram(
+                chosen, mask[..., None], -q, q, n),
+            "sight_target_hist": masked_histogram(targets, mask, -q, q, n),
+        }
 
 
 def attention_entropies(folded_tf: dict, k0, x0, *, emb: int, heads: int,
@@ -217,48 +221,50 @@ def agent_attention_entropy(learner, agent_params, obs_t0, compact_t0):
     import jax.numpy as jnp
 
     from ..ops.query_slice import fold_agent_params
-    a = learner.mac.agent
-    f = fold_agent_params(jax.lax.stop_gradient(agent_params),
-                          emb=a.emb, heads=a.heads, depth=a.depth,
-                          standard_heads=a.standard_heads, dtype=a.dtype)
-    if compact_t0 is not None:
-        rows, same_mec, mean, std = [jax.lax.stop_gradient(x)
-                                     for x in compact_t0]
-        b, n_ag, _ = rows.shape
-        denom = std.astype(jnp.float32) + 1e-8
-        rows9 = jnp.concatenate(
-            [rows.astype(jnp.float32), jnp.zeros((b, n_ag, 1))], axis=-1)
-        we = f["fe"]["kernel"].astype(a.dtype)
-        be = f["fe"]["bias"].astype(jnp.float32)
-        e_vis = (jnp.dot(((rows9 - mean) / denom).astype(a.dtype), we,
-                         preferred_element_type=jnp.float32) + be)
-        e_hid = (jnp.dot(((-mean) / denom).astype(a.dtype), we,
-                         preferred_element_type=jnp.float32) + be)
-        self_corr = (we[8][None, None, :].astype(jnp.float32)
-                     / denom[..., 8:9])
-        # observer i's entity token j: visible ? e_vis[j] : e_hid[j],
-        # plus the is-self correction on the diagonal (j == i)
-        vis = same_mec[:, :, :, None]                    # (B, A_i, A_j, 1)
-        ent_tok = jnp.where(vis, e_vis[:, None, :, :], e_hid[:, None, :, :])
-        eye = jnp.eye(n_ag, dtype=jnp.float32)[None, :, :, None]
-        ent_tok = ent_tok + eye * self_corr[:, None, :, :]
-        h0 = learner.mac.init_hidden(b).astype(jnp.float32)  # (B, A, E)
-        k0 = jnp.concatenate([h0[:, :, None, :], ent_tok], axis=2)
-        k0 = k0.reshape(b * n_ag, n_ag + 1, a.emb).astype(a.dtype)
-    else:
-        obs_t0 = jax.lax.stop_gradient(obs_t0)
-        b, n_ag, _ = obs_t0.shape
-        s = b * n_ag
-        x = obs_t0.reshape(s, a.n_entities, a.feat_dim).astype(a.dtype)
-        fe = f["fe"]
-        embs = (jnp.dot(x, fe["kernel"].astype(a.dtype),
-                        preferred_element_type=jnp.float32)
-                + fe["bias"].astype(jnp.float32)).astype(a.dtype)
-        h0 = learner.mac.init_hidden(b).reshape(s, a.emb).astype(a.dtype)
-        k0 = jnp.concatenate([h0[:, None, :], embs], axis=1)
-    x0 = k0[:, :1, :]                                    # the hidden row
-    return attention_entropies(f["tf"], k0, x0, emb=a.emb, heads=a.heads,
-                               depth=a.depth, dtype=a.dtype)
+    with jax.named_scope("sight"):
+        a = learner.mac.agent
+        f = fold_agent_params(jax.lax.stop_gradient(agent_params),
+                              emb=a.emb, heads=a.heads, depth=a.depth,
+                              standard_heads=a.standard_heads, dtype=a.dtype)
+        if compact_t0 is not None:
+            rows, same_mec, mean, std = [jax.lax.stop_gradient(x)
+                                         for x in compact_t0]
+            b, n_ag, _ = rows.shape
+            denom = std.astype(jnp.float32) + 1e-8
+            rows9 = jnp.concatenate(
+                [rows.astype(jnp.float32), jnp.zeros((b, n_ag, 1))], axis=-1)
+            we = f["fe"]["kernel"].astype(a.dtype)
+            be = f["fe"]["bias"].astype(jnp.float32)
+            e_vis = (jnp.dot(((rows9 - mean) / denom).astype(a.dtype), we,
+                             preferred_element_type=jnp.float32) + be)
+            e_hid = (jnp.dot(((-mean) / denom).astype(a.dtype), we,
+                             preferred_element_type=jnp.float32) + be)
+            self_corr = (we[8][None, None, :].astype(jnp.float32)
+                         / denom[..., 8:9])
+            # observer i's entity token j: visible ? e_vis[j] : e_hid[j],
+            # plus the is-self correction on the diagonal (j == i)
+            vis = same_mec[:, :, :, None]                    # (B, A_i, A_j, 1)
+            ent_tok = jnp.where(vis, e_vis[:, None, :, :],
+                                e_hid[:, None, :, :])
+            eye = jnp.eye(n_ag, dtype=jnp.float32)[None, :, :, None]
+            ent_tok = ent_tok + eye * self_corr[:, None, :, :]
+            h0 = learner.mac.init_hidden(b).astype(jnp.float32)  # (B, A, E)
+            k0 = jnp.concatenate([h0[:, :, None, :], ent_tok], axis=2)
+            k0 = k0.reshape(b * n_ag, n_ag + 1, a.emb).astype(a.dtype)
+        else:
+            obs_t0 = jax.lax.stop_gradient(obs_t0)
+            b, n_ag, _ = obs_t0.shape
+            s = b * n_ag
+            x = obs_t0.reshape(s, a.n_entities, a.feat_dim).astype(a.dtype)
+            fe = f["fe"]
+            embs = (jnp.dot(x, fe["kernel"].astype(a.dtype),
+                            preferred_element_type=jnp.float32)
+                    + fe["bias"].astype(jnp.float32)).astype(a.dtype)
+            h0 = learner.mac.init_hidden(b).reshape(s, a.emb).astype(a.dtype)
+            k0 = jnp.concatenate([h0[:, None, :], embs], axis=1)
+        x0 = k0[:, :1, :]                                    # the hidden row
+        return attention_entropies(f["tf"], k0, x0, emb=a.emb, heads=a.heads,
+                                   depth=a.depth, dtype=a.dtype)
 
 
 def mixer_attention_entropy(learner, mixer_params, state_t0, obs_t0,
@@ -271,28 +277,29 @@ def mixer_attention_entropy(learner, mixer_params, state_t0, obs_t0,
     import jax.numpy as jnp
 
     from ..ops.query_slice import fold_mixer_params
-    mx = learner.mixer
-    f = fold_mixer_params(jax.lax.stop_gradient(mixer_params),
-                          emb=mx.emb, heads=mx.heads, depth=mx.depth,
-                          standard_heads=mx.standard_heads, dtype=mx.dtype)
-    b = hid_t0.shape[0]
-    if mx.state_entity_mode:
-        inputs = state_t0.reshape(b, mx.n_entities, mx.feat_dim)
-    else:                       # Q12: all agents' obs entities
-        inputs = obs_t0.reshape(b, mx.n_agents * mx.n_entities,
-                                mx.feat_dim)
-    inputs = jax.lax.stop_gradient(inputs).astype(mx.dtype)
-    fe = f["fe"]
-    embs = (jnp.dot(inputs, fe["kernel"].astype(mx.dtype),
-                    preferred_element_type=jnp.float32)
-            + fe["bias"].astype(jnp.float32)).astype(mx.dtype)
-    k0 = jnp.concatenate(
-        [embs, jax.lax.stop_gradient(hid_t0).astype(mx.dtype),
-         mx.initial_hyper(b).astype(mx.dtype)], axis=1)
-    r = mx.n_agents + 3
-    return attention_entropies(f["tf"], k0, k0[:, -r:, :], emb=mx.emb,
-                               heads=mx.heads, depth=mx.depth,
-                               dtype=mx.dtype)
+    with jax.named_scope("sight"):
+        mx = learner.mixer
+        f = fold_mixer_params(jax.lax.stop_gradient(mixer_params),
+                              emb=mx.emb, heads=mx.heads, depth=mx.depth,
+                              standard_heads=mx.standard_heads, dtype=mx.dtype)
+        b = hid_t0.shape[0]
+        if mx.state_entity_mode:
+            inputs = state_t0.reshape(b, mx.n_entities, mx.feat_dim)
+        else:                       # Q12: all agents' obs entities
+            inputs = obs_t0.reshape(b, mx.n_agents * mx.n_entities,
+                                    mx.feat_dim)
+        inputs = jax.lax.stop_gradient(inputs).astype(mx.dtype)
+        fe = f["fe"]
+        embs = (jnp.dot(inputs, fe["kernel"].astype(mx.dtype),
+                        preferred_element_type=jnp.float32)
+                + fe["bias"].astype(jnp.float32)).astype(mx.dtype)
+        k0 = jnp.concatenate(
+            [embs, jax.lax.stop_gradient(hid_t0).astype(mx.dtype),
+             mx.initial_hyper(b).astype(mx.dtype)], axis=1)
+        r = mx.n_agents + 3
+        return attention_entropies(f["tf"], k0, k0[:, -r:, :], emb=mx.emb,
+                                   heads=mx.heads, depth=mx.depth,
+                                   dtype=mx.dtype)
 
 
 def buffer_sight_info(priorities, episodes_in_buffer) -> dict:
@@ -302,16 +309,18 @@ def buffer_sight_info(priorities, episodes_in_buffer) -> dict:
     collapse (a handful of episodes soaking all sampling mass) reads
     as norm → 0. In-graph: one masked reduce over the ``(capacity,)``
     vector inside the already-dispatched train program."""
+    import jax
     import jax.numpy as jnp
-    pri = jnp.asarray(priorities, jnp.float32)
-    n = jnp.asarray(episodes_in_buffer, jnp.int32)
-    valid = jnp.arange(pri.shape[0]) < n
-    p = jnp.where(valid, pri, 0.0)
-    probs = p / jnp.maximum(p.sum(), _EPS)
-    ent = -(probs * jnp.log(probs + _EPS)).sum()
-    norm = ent / jnp.log(jnp.maximum(n, 2).astype(jnp.float32))
-    return {"sight_priority_entropy": ent,
-            "sight_priority_entropy_norm": norm}
+    with jax.named_scope("sight"):
+        pri = jnp.asarray(priorities, jnp.float32)
+        n = jnp.asarray(episodes_in_buffer, jnp.int32)
+        valid = jnp.arange(pri.shape[0]) < n
+        p = jnp.where(valid, pri, 0.0)
+        probs = p / jnp.maximum(p.sum(), _EPS)
+        ent = -(probs * jnp.log(probs + _EPS)).sum()
+        norm = ent / jnp.log(jnp.maximum(n, 2).astype(jnp.float32))
+        return {"sight_priority_entropy": ent,
+                "sight_priority_entropy_norm": norm}
 
 
 def maybe_buffer_info(cfg, info: dict, buf) -> dict:

@@ -57,7 +57,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from ..utils.ioutil import write_json_atomic
 
@@ -75,6 +75,13 @@ KNOWN_PHASES = frozenset({
     # driver sync/fetch boundaries (run.py _sync_point via _watched)
     "dispatch.wait", "fetch.train_infos", "fetch.train_stats",
     "fetch.test_stats",
+    # the driver loop's own host work (run.run_sequential), so that no
+    # stretch of an iteration is in no span: before the dispatch (gate
+    # mirror, key splits), after it (mirror commit, info-row slicing),
+    # and the log cadence after its fetch. WORKING spans: nothing in
+    # them blocks on the device — the blocked ones are fetch.*,
+    # dispatch.wait and checkpoint.save
+    "driver.prepare", "driver.account", "driver.log",
     # sebulba decoupled-loop boundaries (run.run_sebulba,
     # parallel/sebulba.py): actor-mesh rollout dispatch, the trajectory
     # queue's two ends (put = actor-side d2d copy + slot scatter, its
@@ -119,6 +126,35 @@ KNOWN_PHASES = frozenset({
     # traffic), spanned so a slow sink/detector shows up in the phase
     # tables instead of silently inflating the log cadence
     "sight.detect",
+})
+
+#: The device-side vocabulary: every literal the package passes to
+#: ``jax.named_scope`` (tests/test_scopes.py scans the sources both ways).
+#: A scope is opened where the work is written, so every program that
+#: runs the work (classic, fused, population, data-parallel) carries the
+#: same names. JAX wraps them in the operation's name stack
+#: (``vmap(env.step)``, ``transpose(jvp(learner.agent))``,
+#: ``checkpoint``/``rematted_computation``): a reader matches a scope as
+#: a token inside those wrappers, takes the OUTERMOST token for a
+#: layer's time and the innermost for a finer split (the ``agent.*``
+#: children are the same under ``act.forward`` and ``learner.*``). The
+#: benchmark's reader is ``benchmark/scopes.py``; docs/OBSERVABILITY.md
+#: §8 has the table of where each is opened.
+KNOWN_SCOPES = frozenset({
+    # rollout (runners/parallel_runner.py, envs/, controllers/,
+    # components/action_selectors.py)
+    "rollout.reset", "env.obs", "env.step", "env.normalizer",
+    "act.forward", "act.select", "rollout.store",
+    # the model (models/, ops/query_slice.py), under act.forward and
+    # under learner.agent / learner.mixer / learner.target alike
+    "agent.embed", "agent.attention", "agent.ff", "agent.head",
+    # replay ring (components/episode_buffer.py)
+    "replay.insert", "replay.sample", "replay.priority",
+    # learner (learners/qmix_learner.py)
+    "learner.agent", "learner.mixer", "learner.target", "learner.loss",
+    "learner.optimizer",
+    # in-graph telemetry reduces (obs/sight.py)
+    "sight",
 })
 
 _NOOP = contextlib.nullcontext()
@@ -185,10 +221,19 @@ class SpanRecorder:
 
     def __init__(self, ring_size: int = 256,
                  jsonl_path: Optional[str] = None,
-                 flush_every: int = 32) -> None:
+                 flush_every: int = 32,
+                 annotate: Optional[Callable[[str], Any]] = None) -> None:
         self.ring_size = max(int(ring_size), 1)
         self.jsonl_path = jsonl_path
         self.flush_every = max(int(flush_every), 1)
+        # ``annotate(phase)`` → a context manager entered and exited with
+        # every span (LIFO, on the span's own thread). The driver injects
+        # ``jax.profiler.TraceAnnotation`` so each span is also a host
+        # event of the same name in the profiler's trace, on the
+        # profiler's clock; injected, never imported: this module stays
+        # stdlib-only
+        self._annotate = annotate
+        self._open_ann: Dict[int, Any] = {}  # seq -> entered annotation
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=self.ring_size)
         self._open: Dict[int, Dict[str, Any]] = {}   # seq -> open span event
@@ -227,11 +272,18 @@ class SpanRecorder:
         d = getattr(self._depth, "n", 0)
         self._depth.n = d + 1
         ev["depth"] = d
-        ev["t0"] = round(time.time(), 3)
+        ann = None
+        if self._annotate is not None:
+            # outermost: the annotation covers the bookkeeping too
+            ann = self._annotate(ev["phase"])
+            ann.__enter__()
+        ev["t0"] = time.time()
         with self._lock:
             self._seq += 1
             ev["seq"] = self._seq
             self._open[ev["seq"]] = ev
+            if ann is not None:
+                self._open_ann[ev["seq"]] = ann
             pc0 = time.perf_counter()
             self._open_pc[ev["seq"]] = pc0
         return pc0
@@ -250,6 +302,7 @@ class SpanRecorder:
                              else f"error:{exc_type.__name__}")
             self._open.pop(ev["seq"], None)
             self._open_pc.pop(ev["seq"], None)
+            ann = self._open_ann.pop(ev["seq"], None)
             a = self._agg.get(phase)
             if a is None:
                 a = self._agg[phase] = {"n": 0, "total_ms": 0.0,
@@ -266,6 +319,8 @@ class SpanRecorder:
             if ev.pop("_ring", True):
                 self._ring.append(ev)
             self._sink(ev)
+        if ann is not None:
+            ann.__exit__(exc_type, None, None)
 
     def mark(self, kind: str, **meta) -> None:
         """Record one point event (run header, ladder action, ...)."""
@@ -418,13 +473,15 @@ class NullRecorder:
 NULL_RECORDER = NullRecorder()
 
 
-def make_recorder(obs_cfg, run_dir: Optional[str] = None):
+def make_recorder(obs_cfg, run_dir: Optional[str] = None,
+                  annotate: Optional[Callable[[str], Any]] = None):
     """Recorder for a run: :data:`NULL_RECORDER` unless
     ``obs_cfg.enabled``; the JSONL sink lands in
-    ``<run_dir>/spans.jsonl`` when a run directory is given."""
+    ``<run_dir>/spans.jsonl`` when a run directory is given.
+    ``annotate`` as in :class:`SpanRecorder`."""
     if obs_cfg is None or not getattr(obs_cfg, "enabled", False):
         return NULL_RECORDER
     path = (os.path.join(run_dir, "spans.jsonl")
             if run_dir else None)
     return SpanRecorder(ring_size=obs_cfg.ring_size, jsonl_path=path,
-                        flush_every=obs_cfg.flush_every)
+                        flush_every=obs_cfg.flush_every, annotate=annotate)

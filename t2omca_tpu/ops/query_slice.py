@@ -156,7 +156,8 @@ def fold_transformer(tf_params: dict, *, emb: int, heads: int,
     blocks = []
     for i in range(depth):
         bp = tf_params[f"block_{i}"]
-        wqk, wvu = _fold_block(bp, emb, heads, head_dim, dtype)
+        with jax.named_scope("agent.attention"):
+            wqk, wvu = _fold_block(bp, emb, heads, head_dim, dtype)
         blocks.append({"wqk": wqk, "wvu": wvu,
                        "u_bias": bp["attention"]["unifyheads"]["bias"],
                        "n1": bp["norm1"], "n2": bp["norm2"],
@@ -191,36 +192,37 @@ def transformer_rows(tf_folded: dict, k0: jnp.ndarray, x0: jnp.ndarray, *,
     for i in range(depth):
         bp = tf_folded["blocks"][i]
         wqk, wvu = bp["wqk"], bp["wvu"]
-        # logits over all T keys for each head, keys never materialized
-        qp = jnp.dot(x0.reshape(s * r, emb), wqk,
-                     preferred_element_type=jnp.float32)
-        qp = qp.astype(dtype).reshape(s, r * heads, emb)
-        if attn_impl == "pallas":
-            # fused flash kernel over the R·H sliced rows: the folded
-            # wqk already carries the d**-0.5 logit scaling, k0 doubles
-            # as keys AND values (the qslice identity: ctx = attn·k0,
-            # wvu applies after), no mask/causal structure
-            from ..kernels.attention import flash_attention
-            ctx = flash_attention(qp[:, None], k0[:, None],
-                                  k0[:, None])[:, 0]        # (S, R·H, E)
-            ctx = ctx.astype(dtype).reshape(s * r, heads * emb)
-        else:
-            logits = jax.lax.dot_general(
-                qp, k0, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)             # (S, R·H, T)
-            # parity mode keeps f32 softmax; bf16 perf mode stays in bf16
-            # (mirrors models/transformer.py:101-105)
-            if dtype == jnp.float32:
-                attn = jax.nn.softmax(logits, axis=-1)
+        with jax.named_scope("agent.attention"):
+            # logits over all T keys for each head, keys never materialized
+            qp = jnp.dot(x0.reshape(s * r, emb), wqk,
+                         preferred_element_type=jnp.float32)
+            qp = qp.astype(dtype).reshape(s, r * heads, emb)
+            if attn_impl == "pallas":
+                # fused flash kernel over the R·H sliced rows: the folded
+                # wqk already carries the d**-0.5 logit scaling, k0 doubles
+                # as keys AND values (the qslice identity: ctx = attn·k0,
+                # wvu applies after), no mask/causal structure
+                from ..kernels.attention import flash_attention
+                ctx = flash_attention(qp[:, None], k0[:, None],
+                                      k0[:, None])[:, 0]        # (S, R·H, E)
+                ctx = ctx.astype(dtype).reshape(s * r, heads * emb)
             else:
-                attn = jax.nn.softmax(logits.astype(dtype), axis=-1)
-            attn = attn.astype(dtype)
-            ctx = jax.lax.dot_general(
-                attn, k0, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)             # (S, R·H, E)
-            ctx = ctx.astype(dtype).reshape(s * r, heads * emb)
-        attended = (jnp.dot(ctx, wvu, preferred_element_type=jnp.float32)
-                    + bp["u_bias"].astype(jnp.float32))         # (S·R, E) f32
+                logits = jax.lax.dot_general(
+                    qp, k0, (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)  # (S, R·H, T)
+                # parity mode keeps f32 softmax; bf16 perf mode stays in bf16
+                # (mirrors models/transformer.py:101-105)
+                if dtype == jnp.float32:
+                    attn = jax.nn.softmax(logits, axis=-1)
+                else:
+                    attn = jax.nn.softmax(logits.astype(dtype), axis=-1)
+                attn = attn.astype(dtype)
+                ctx = jax.lax.dot_general(
+                    attn, k0, (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)  # (S, R·H, E)
+                ctx = ctx.astype(dtype).reshape(s * r, heads * emb)
+            attended = (jnp.dot(ctx, wvu, preferred_element_type=jnp.float32)
+                        + bp["u_bias"].astype(jnp.float32))  # (S·R, E) f32
         x0 = _block_tail(bp, attended,
                          x0.reshape(s * r, emb), dtype).reshape(s, r, emb)
 
@@ -232,19 +234,20 @@ def _block_tail(bp: dict, attended: jnp.ndarray, x0_flat: jnp.ndarray,
     """Post-attention block tail shared by both query-slice forwards:
     Q2 post-LN residuals + FFN, f32 statistics.
     ``attended (N, E)`` f32, ``x0_flat (N, E)`` in compute dtype."""
-    x1 = _ln(attended + x0_flat.astype(jnp.float32),
-             bp["n1"]["scale"].astype(jnp.float32),
-             bp["n1"]["bias"].astype(jnp.float32))
-    hid = jnp.dot(x1.astype(dtype), bp["ff1"]["kernel"].astype(dtype),
-                  preferred_element_type=jnp.float32)
-    hid = jnp.maximum(hid + bp["ff1"]["bias"].astype(jnp.float32), 0.0)
-    y = jnp.dot(hid.astype(dtype), bp["ff2"]["kernel"].astype(dtype),
-                preferred_element_type=jnp.float32)
-    y = y + bp["ff2"]["bias"].astype(jnp.float32)
-    x2 = _ln(y + x1,
-             bp["n2"]["scale"].astype(jnp.float32),
-             bp["n2"]["bias"].astype(jnp.float32))
-    return x2.astype(dtype)
+    with jax.named_scope("agent.ff"):
+        x1 = _ln(attended + x0_flat.astype(jnp.float32),
+                 bp["n1"]["scale"].astype(jnp.float32),
+                 bp["n1"]["bias"].astype(jnp.float32))
+        hid = jnp.dot(x1.astype(dtype), bp["ff1"]["kernel"].astype(dtype),
+                      preferred_element_type=jnp.float32)
+        hid = jnp.maximum(hid + bp["ff1"]["bias"].astype(jnp.float32), 0.0)
+        y = jnp.dot(hid.astype(dtype), bp["ff2"]["kernel"].astype(dtype),
+                    preferred_element_type=jnp.float32)
+        y = y + bp["ff2"]["bias"].astype(jnp.float32)
+        x2 = _ln(y + x1,
+                 bp["n2"]["scale"].astype(jnp.float32),
+                 bp["n2"]["bias"].astype(jnp.float32))
+        return x2.astype(dtype)
 
 
 def _q_head(qb: dict, h_new: jnp.ndarray,
@@ -263,17 +266,18 @@ def _q_head(qb: dict, h_new: jnp.ndarray,
     ``make_rng``, so the NOISE STREAM differs from the flax module's for
     the same key — identical distribution, different sample; documented
     in docs/SPEC.md §7 (use_qslice row)."""
-    if "kernel" in qb:
-        return (jnp.dot(h_new, qb["kernel"].astype(jnp.float32))
-                + qb["bias"].astype(jnp.float32))
-    w = qb["w_mu"].astype(jnp.float32)
-    b = qb["b_mu"].astype(jnp.float32)
-    if noise_key is not None:
-        from ..models.noisy import noisy_weights
-        w, b = noisy_weights(w, qb["w_sigma"].astype(jnp.float32),
-                             b, qb["b_sigma"].astype(jnp.float32),
-                             noise_key)
-    return jnp.dot(h_new, w) + b
+    with jax.named_scope("agent.head"):
+        if "kernel" in qb:
+            return (jnp.dot(h_new, qb["kernel"].astype(jnp.float32))
+                    + qb["bias"].astype(jnp.float32))
+        w = qb["w_mu"].astype(jnp.float32)
+        b = qb["b_mu"].astype(jnp.float32)
+        if noise_key is not None:
+            from ..models.noisy import noisy_weights
+            w, b = noisy_weights(w, qb["w_sigma"].astype(jnp.float32),
+                                 b, qb["b_sigma"].astype(jnp.float32),
+                                 noise_key)
+        return jnp.dot(h_new, w) + b
 
 
 def fold_agent_params(variables: dict, *, emb: int, heads: int, depth: int,
@@ -315,15 +319,16 @@ def agent_forward_qslice(variables: dict, inputs: jnp.ndarray,
     b, a, _ = inputs.shape
     s = b * a
 
-    x = inputs.reshape(s, n_entities, feat_dim).astype(dtype)
-    h0 = hidden_state.reshape(s, emb).astype(dtype)
+    with jax.named_scope("agent.embed"):
+        x = inputs.reshape(s, n_entities, feat_dim).astype(dtype)
+        h0 = hidden_state.reshape(s, emb).astype(dtype)
 
-    fe = f["fe"]
-    embs = (jnp.dot(x, fe["kernel"].astype(dtype),
-                    preferred_element_type=jnp.float32)
-            + fe["bias"].astype(jnp.float32)).astype(dtype)     # (S, N, E)
-    # layer-0 key tokens: hidden token prepended at position 0
-    k0 = jnp.concatenate([h0[:, None, :], embs], axis=1)        # (S, T, E)
+        fe = f["fe"]
+        embs = (jnp.dot(x, fe["kernel"].astype(dtype),
+                        preferred_element_type=jnp.float32)
+                + fe["bias"].astype(jnp.float32)).astype(dtype)  # (S, N, E)
+        # layer-0 key tokens: hidden token prepended at position 0
+        k0 = jnp.concatenate([h0[:, None, :], embs], axis=1)     # (S, T, E)
 
     out = transformer_rows(f["tf"], k0, h0[:, None, :],
                            emb=emb, heads=heads, depth=depth,
@@ -391,63 +396,67 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
     b, a, _ = rows.shape
     s = b * a
 
-    # ---- per-env embedding tables (feat 8 = is_self; _raw_obs layout)
-    denom = std.astype(jnp.float32) + 1e-8                    # (B, A, 9)
-    rows9 = jnp.concatenate(
-        [rows.astype(jnp.float32), jnp.zeros((b, a, 1))], axis=-1)
-    nv = ((rows9 - mean) / denom).astype(dtype)               # visible row
-    nh = ((-mean) / denom).astype(dtype)                      # masked row
-    we = f["fe"]["kernel"].astype(dtype)                      # (9, E)
-    be = f["fe"]["bias"].astype(jnp.float32)
-    e_vis = (jnp.dot(nv, we, preferred_element_type=jnp.float32)
-             + be).astype(dtype)                              # (B, A, E)
-    e_hid = (jnp.dot(nh, we, preferred_element_type=jnp.float32)
-             + be).astype(dtype)
-    self_corr = (we[8][None, None, :].astype(jnp.float32)
-                 / denom[..., 8:9]).astype(dtype)             # (B, A, E)
+    with jax.named_scope("agent.embed"):
+        # ---- per-env embedding tables (feat 8 = is_self; _raw_obs layout)
+        denom = std.astype(jnp.float32) + 1e-8                    # (B, A, 9)
+        rows9 = jnp.concatenate(
+            [rows.astype(jnp.float32), jnp.zeros((b, a, 1))], axis=-1)
+        nv = ((rows9 - mean) / denom).astype(dtype)               # visible row
+        nh = ((-mean) / denom).astype(dtype)                      # masked row
+        we = f["fe"]["kernel"].astype(dtype)                      # (9, E)
+        be = f["fe"]["bias"].astype(jnp.float32)
+        e_vis = (jnp.dot(nv, we, preferred_element_type=jnp.float32)
+                 + be).astype(dtype)                              # (B, A, E)
+        e_hid = (jnp.dot(nh, we, preferred_element_type=jnp.float32)
+                 + be).astype(dtype)
+        self_corr = (we[8][None, None, :].astype(jnp.float32)
+                     / denom[..., 8:9]).astype(dtype)             # (B, A, E)
 
-    h_tok = hidden_state.astype(dtype)                        # (B, A, E)
-    vis = same_mec[:, :, None, :]                             # (B, A, 1, A)
-    eye = jnp.eye(a, dtype=dtype)[None, :, None, :]           # (1, A, 1, A)
-    idx_diag = jnp.arange(a)[None, :, None, None]
+        h_tok = hidden_state.astype(dtype)                        # (B, A, E)
+        vis = same_mec[:, :, None, :]  # (B, A, 1, A)
+        eye = jnp.eye(a, dtype=dtype)[None, :, None, :]  # (1, A, 1, A)
+        idx_diag = jnp.arange(a)[None, :, None, None]
 
     x0 = h_tok
     for i in range(depth):
         bp = f["tf"]["blocks"][i]
-        qp = jnp.dot(x0.reshape(s, emb), bp["wqk"],
-                     preferred_element_type=jnp.float32)
-        qp = qp.astype(dtype).reshape(b, a, heads, emb)
-        # logits against key 0 (own hidden token) and the entity tables
-        l0 = jnp.einsum("bahe,bae->bah", qp, h_tok,
-                        preferred_element_type=jnp.float32)
-        lv = jnp.einsum("bahe,bje->bahj", qp, e_vis,
-                        preferred_element_type=jnp.float32)
-        lh = jnp.einsum("bahe,bje->bahj", qp, e_hid,
-                        preferred_element_type=jnp.float32)
-        ls = jnp.einsum("bahe,bae->bah", qp, self_corr,
-                        preferred_element_type=jnp.float32)
-        lent = jnp.where(vis, lv, lh) + eye.astype(jnp.float32) \
-            * ls[..., None]
-        logits = jnp.concatenate([l0[..., None], lent], axis=-1)
-        if dtype == jnp.float32:
-            attn = jax.nn.softmax(logits, axis=-1)
-        else:
-            attn = jax.nn.softmax(logits.astype(dtype), axis=-1)
-        attn = attn.astype(dtype)
-        a0, ae = attn[..., 0], attn[..., 1:]                  # (B,A,H[,A])
-        av = ae * vis.astype(dtype)
-        ah = ae - av                                          # masked branch
-        diag = jnp.take_along_axis(ae, idx_diag, axis=-1)[..., 0]
-        ctx = (a0[..., None] * h_tok[:, :, None, :]
-               + jnp.einsum("bahj,bje->bahe", av, e_vis,
-                            preferred_element_type=jnp.float32).astype(dtype)
-               + jnp.einsum("bahj,bje->bahe", ah, e_hid,
-                            preferred_element_type=jnp.float32).astype(dtype)
-               + diag[..., None] * self_corr[:, :, None, :])
-        ctx = ctx.astype(dtype).reshape(s, heads * emb)
-        attended = (jnp.dot(ctx, bp["wvu"],
+        with jax.named_scope("agent.attention"):
+            qp = jnp.dot(x0.reshape(s, emb), bp["wqk"],
+                         preferred_element_type=jnp.float32)
+            qp = qp.astype(dtype).reshape(b, a, heads, emb)
+            # logits against key 0 (own hidden token) and the entity tables
+            l0 = jnp.einsum("bahe,bae->bah", qp, h_tok,
                             preferred_element_type=jnp.float32)
-                    + bp["u_bias"].astype(jnp.float32))
+            lv = jnp.einsum("bahe,bje->bahj", qp, e_vis,
+                            preferred_element_type=jnp.float32)
+            lh = jnp.einsum("bahe,bje->bahj", qp, e_hid,
+                            preferred_element_type=jnp.float32)
+            ls = jnp.einsum("bahe,bae->bah", qp, self_corr,
+                            preferred_element_type=jnp.float32)
+            lent = jnp.where(vis, lv, lh) + eye.astype(jnp.float32) \
+                * ls[..., None]
+            logits = jnp.concatenate([l0[..., None], lent], axis=-1)
+            if dtype == jnp.float32:
+                attn = jax.nn.softmax(logits, axis=-1)
+            else:
+                attn = jax.nn.softmax(logits.astype(dtype), axis=-1)
+            attn = attn.astype(dtype)
+            a0, ae = attn[..., 0], attn[..., 1:]                  # (B,A,H[,A])
+            av = ae * vis.astype(dtype)
+            ah = ae - av  # masked branch
+            diag = jnp.take_along_axis(ae, idx_diag, axis=-1)[..., 0]
+            ctx = (a0[..., None] * h_tok[:, :, None, :]
+                   + jnp.einsum("bahj,bje->bahe", av, e_vis,
+                                preferred_element_type=jnp.float32
+                                ).astype(dtype)
+                   + jnp.einsum("bahj,bje->bahe", ah, e_hid,
+                                preferred_element_type=jnp.float32
+                                ).astype(dtype)
+                   + diag[..., None] * self_corr[:, :, None, :])
+            ctx = ctx.astype(dtype).reshape(s, heads * emb)
+            attended = (jnp.dot(ctx, bp["wvu"],
+                                preferred_element_type=jnp.float32)
+                        + bp["u_bias"].astype(jnp.float32))
         x0 = _block_tail(bp, attended, x0.reshape(s, emb), dtype) \
             .reshape(b, a, emb)
 
@@ -501,38 +510,41 @@ def mixer_forward_qslice(variables: dict, qvals: jnp.ndarray,
                           standard_heads=standard_heads, dtype=dtype)
     b = qvals.shape[0]
 
-    if state_entity_mode:
-        inputs = states.reshape(b, n_entities, feat_dim).astype(dtype)
-    else:  # Q12: all agents' obs entities
-        inputs = obs.reshape(b, n_agents * n_entities, feat_dim).astype(dtype)
+    with jax.named_scope("agent.embed"):
+        if state_entity_mode:
+            inputs = states.reshape(b, n_entities, feat_dim).astype(dtype)
+        else:  # Q12: all agents' obs entities
+            inputs = obs.reshape(b, n_agents * n_entities,
+                                 feat_dim).astype(dtype)
 
-    fe = f["fe"]
-    embs = (jnp.dot(inputs, fe["kernel"].astype(dtype),
-                    preferred_element_type=jnp.float32)
-            + fe["bias"].astype(jnp.float32)).astype(dtype)
+        fe = f["fe"]
+        embs = (jnp.dot(inputs, fe["kernel"].astype(dtype),
+                        preferred_element_type=jnp.float32)
+                + fe["bias"].astype(jnp.float32)).astype(dtype)
 
-    k0 = jnp.concatenate(
-        [embs, hidden_states.astype(dtype), hyper_weights.astype(dtype)],
-        axis=1)                                                 # (b, T, E)
+        k0 = jnp.concatenate(
+            [embs, hidden_states.astype(dtype), hyper_weights.astype(dtype)],
+            axis=1)                                             # (b, T, E)
 
     r = n_agents + 3
     out = transformer_rows(f["tf"], k0, k0[:, -r:, :],
                            emb=emb, heads=heads, depth=depth,
                            dtype=dtype, attn_impl=attn_impl)    # (b, A+3, E)
 
-    w1 = out[:, :n_agents, :]                                   # (b, A, emb)
-    b1 = out[:, -3, :].reshape(b, 1, emb)
-    w2 = out[:, -2, :].reshape(b, emb, 1)
-    hb = f["hb"]
-    b2 = jax.nn.relu(
-        jnp.dot(out[:, -1, :], hb["kernel"].astype(jnp.float32))
-        + hb["bias"].astype(jnp.float32)).reshape(b, 1, 1)
+    with jax.named_scope("agent.head"):
+        w1 = out[:, :n_agents, :]  # (b, A, emb)
+        b1 = out[:, -3, :].reshape(b, 1, emb)
+        w2 = out[:, -2, :].reshape(b, emb, 1)
+        hb = f["hb"]
+        b2 = jax.nn.relu(
+            jnp.dot(out[:, -1, :], hb["kernel"].astype(jnp.float32))
+            + hb["bias"].astype(jnp.float32)).reshape(b, 1, 1)
 
-    w1 = qmix_pos_func(w1, pos_func, pos_func_beta)
-    w2 = qmix_pos_func(w2, pos_func, pos_func_beta)
+        w1 = qmix_pos_func(w1, pos_func, pos_func_beta)
+        w2 = qmix_pos_func(w2, pos_func, pos_func_beta)
 
-    hidden = jax.nn.elu(jnp.matmul(qvals.astype(jnp.float32), w1) + b1)
-    y = jnp.matmul(hidden, w2) + b2                             # (b, 1, 1)
-    if "og" in f:              # zero_init_gate configs (models/mixer.py)
-        y = y * f["og"].astype(jnp.float32)
-    return y, out[:, -3:, :]
+        hidden = jax.nn.elu(jnp.matmul(qvals.astype(jnp.float32), w1) + b1)
+        y = jnp.matmul(hidden, w2) + b2                             # (b, 1, 1)
+        if "og" in f:              # zero_init_gate configs (models/mixer.py)
+            y = y * f["og"].astype(jnp.float32)
+        return y, out[:, -3:, :]

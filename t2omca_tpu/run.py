@@ -809,7 +809,7 @@ class _DriverKit:
                                  f"{type(e).__name__}: {e}")
                 time.sleep(delay)
 
-    def sync_point(self, phase, fn, state):
+    def sync_point(self, phase, fn, state, **context):
         """One blocking sync/fetch boundary (run-ahead wait, cadence stat
         fetch): watchdog stamp + fault-injection hook + transient
         classification in one place. On the production path these host
@@ -821,10 +821,11 @@ class _DriverKit:
         outputs are suspect), so a transient failure raises
         ``DispatchFailed`` for the caller to route to the ladder with
         ``can_degrade=False`` — restore is the only rung that can
-        stand; deterministic errors propagate unwrapped."""
+        stand; deterministic errors propagate unwrapped. ``context`` is
+        handed to the fault-injection hook only."""
         try:
             with self.watched(phase, state):
-                resilience.fire(phase, t_env=self.t_env_fn())
+                resilience.fire(phase, t_env=self.t_env_fn(), **context)
                 return fn()
         except Exception as e:  # noqa: BLE001 — classified below
             if not watchdog.is_transient(e):
@@ -936,7 +937,11 @@ def run(cfg: TrainConfig, logger: Optional[Logger] = None) -> TrainState:
     # without the obs layer. The Logger history cap applies regardless
     # (the unbounded self.stats growth was a bug, not a behavior).
     logger.max_history = cfg.obs.stats_history
-    rec = obs_spans.make_recorder(cfg.obs, results_dir)
+    # every span is also a host event of the same name in the
+    # profiler's trace, on the profiler's clock (a flag test while no
+    # profiler session is running)
+    rec = obs_spans.make_recorder(cfg.obs, results_dir,
+                                  annotate=jax.profiler.TraceAnnotation)
     # the first jax computation in the build triggers backend init
     with rec.span("backend.init"):
         exp = Experiment.build(cfg)
@@ -957,7 +962,8 @@ def run_sequential(exp: Experiment, logger: Logger,
     # graftscope span recorder (``run`` passes its own; direct callers —
     # tests, evaluate harnesses — get one from the config here)
     if rec is None:
-        rec = obs_spans.make_recorder(cfg.obs, results_dir)
+        rec = obs_spans.make_recorder(
+            cfg.obs, results_dir, annotate=jax.profiler.TraceAnnotation)
     if sebulba_eligible(cfg):
         # Sebulba decoupled actor/learner loop (docs/PERF.md): disjoint
         # device meshes + device-resident trajectory queue; its own loop
@@ -1452,14 +1458,14 @@ def run_sequential(exp: Experiment, logger: Logger,
                "; no checkpoints exist to restore (save_model off)")
             + f" — last failure: {df}") from df
 
-    def _sync_point(phase, fn):
+    def _sync_point(phase, fn, **context):
         """One blocking sync/fetch boundary (run-ahead wait, cadence
         stat fetch) — shared body in ``_DriverKit.sync_point``. Stays a
         local def (not a bare bound method) so the stamp always carries
         the loop's CURRENT ``ts``: the state local is rebound across
         restores and donated dispatches, and an early capture would
         stamp deleted buffers."""
-        return kit.sync_point(phase, fn, ts)
+        return kit.sync_point(phase, fn, ts, **context)
 
     # signal handlers are process-global state: restore them on
     # EVERY exit (normal, preemption, divergence abort)
@@ -1473,7 +1479,8 @@ def run_sequential(exp: Experiment, logger: Logger,
             # every cadence, and every checkpoint land between fused
             # dispatches, so a preemption loses at most K iterations and a
             # restored checkpoint always resumes at a K-aligned t_env
-            resilience.fire("driver.iteration", t_env=t_env, guard=guard)
+            resilience.fire("driver.iteration", t_env=t_env, guard=guard,
+                            ts=ts, key=key, train_infos=train_infos)
             # coordinated preemption (docs/RESILIENCE.md §6): propagate a
             # PEER's announced shutdown into the local guard, then
             # negotiate the one cut step all hosts share. Hosts behind
@@ -1516,31 +1523,34 @@ def run_sequential(exp: Experiment, logger: Logger,
                 # member's stream splits exactly like the classic
                 # loop's single one — member 0's consumed stream IS the
                 # solo run's, the bit-parity contract.
-                ep2, fill2 = episode, buffer_filled
-                key2 = list(key) if P else key
-                key_rows, gated = [], []
-                for _ in range(K):
-                    ep2 += cfg.batch_size_run
-                    fill2 = min(fill2 + cfg.batch_size_run,
-                                buffer_capacity)
-                    g = (fill2 >= cfg.batch_size
-                         and ep2 >= cfg.accumulated_episodes)
-                    gated.append(g)
-                    if P:
-                        if g:
-                            row = []
-                            for m in range(P):
-                                key2[m], k_s = jax.random.split(key2[m])
-                                row.append(k_s)
-                            key_rows.append(jnp.stack(row))
+                # host work between the boundary and the dispatch (the key
+                # splits enqueue tiny device programs; nothing blocks)
+                with rec.span("driver.prepare", t_env=t_env):
+                    ep2, fill2 = episode, buffer_filled
+                    key2 = list(key) if P else key
+                    key_rows, gated = [], []
+                    for _ in range(K):
+                        ep2 += cfg.batch_size_run
+                        fill2 = min(fill2 + cfg.batch_size_run,
+                                    buffer_capacity)
+                        g = (fill2 >= cfg.batch_size
+                             and ep2 >= cfg.accumulated_episodes)
+                        gated.append(g)
+                        if P:
+                            if g:
+                                row = []
+                                for m in range(P):
+                                    key2[m], k_s = jax.random.split(key2[m])
+                                    row.append(k_s)
+                                key_rows.append(jnp.stack(row))
+                            else:
+                                key_rows.append(jnp.zeros(
+                                    (P,) + key2[0].shape, key2[0].dtype))
+                        elif g:
+                            key2, k_sample = jax.random.split(key2)
+                            key_rows.append(k_sample)
                         else:
-                            key_rows.append(jnp.zeros(
-                                (P,) + key2[0].shape, key2[0].dtype))
-                    elif g:
-                        key2, k_sample = jax.random.split(key2)
-                        key_rows.append(k_sample)
-                    else:
-                        key_rows.append(jnp.zeros_like(key2))
+                            key_rows.append(jnp.zeros_like(key2))
                 def _fused(ts=ts, key_rows=key_rows):
                     if P:
                         # (P, K, 2) — the vmapped program maps axis 0,
@@ -1575,15 +1585,17 @@ def run_sequential(exp: Experiment, logger: Logger,
                 except watchdog.DispatchFailed as df:
                     _dispatch_ladder(df)
                     continue
-                key, episode, buffer_filled = key2, ep2, fill2
-                t_env += K * steps_per_rollout
-                for i, g in enumerate(gated):
-                    if g:
-                        # population infos carry the leading (P,) member
-                        # axis; the scan's (K,) axis is the next one
-                        train_infos.append(jax.tree.map(
-                            (lambda x, i=i: x[:, i]) if P
-                            else (lambda x, i=i: x[i]), infos))
+                with rec.span("driver.account", t_env=t_env):
+                    key, episode, buffer_filled = key2, ep2, fill2
+                    t_env += K * steps_per_rollout
+                    for i, g in enumerate(gated):
+                        if g:
+                            # population infos carry the leading (P,)
+                            # member axis; the scan's (K,) axis is the
+                            # next one
+                            train_infos.append(jax.tree.map(
+                                (lambda x, i=i: x[:, i]) if P
+                                else (lambda x, i=i: x[i]), infos))
             else:
                 # ------------ rollout (no grad by construction) -------------
                 def _roll(ts=ts):
@@ -1616,7 +1628,8 @@ def run_sequential(exp: Experiment, logger: Logger,
                 else:
                     can = buffer_filled >= cfg.batch_size
                 if can and episode >= cfg.accumulated_episodes:
-                    key2, k_sample = jax.random.split(key)
+                    with rec.span("driver.prepare", t_env=t_env):
+                        key2, k_sample = jax.random.split(key)
 
                     # NB: not named `_train` — graftlint's traced-region
                     # discovery is name-keyed per module, and `_train` is
@@ -1865,74 +1878,79 @@ def run_sequential(exp: Experiment, logger: Logger,
                         return flags, jax.device_get(train_infos[-1])
                     try:
                         flags, last = _sync_point("fetch.train_infos",
-                                                  _fetch_infos)
+                                                  _fetch_infos,
+                                                  train_infos=train_infos)
                     except watchdog.DispatchFailed as df:
                         _dispatch_ladder(df, can_degrade=False)
                         continue
-                    if P:
-                        # (n, P) member flags: a train step counts as
-                        # finite only when EVERY member's update was —
-                        # one poisoned member is a restore-worthy event
-                        # exactly like a solo NaN (the stacked state is
-                        # one checkpoint)
-                        flags = flags.reshape(len(train_infos), -1)\
-                                     .all(axis=1)
-                    for ok in flags:
-                        if ok:
-                            nonfinite_streak = 0
-                        else:
-                            nonfinite_streak += 1
-                            nonfinite_total += 1
-                    if not flags.all():
-                        logger.log_stat("nonfinite_steps", nonfinite_total,
-                                        t_env)
-                        # non-finite trip: event + flight persist, so a
-                        # later divergence abort has the phase history
-                        # leading up to the first trip on disk already
-                        rec.mark("nonfinite", t_env=t_env,
-                                 streak=nonfinite_streak,
-                                 total=nonfinite_total)
-                        _persist_flight(os.path.join(
-                            results_dir, "flight_recorder.json"))
-                        log.warning(
-                            f"non-finite loss/grads in "
-                            f"{int((~flags).sum())}/{len(flags)} train steps "
-                            f"since last log (streak={nonfinite_streak}, "
-                            f"total={nonfinite_total}); parameter updates "
-                            f"were skipped")
-                    for k in ("loss", "grad_norm", "td_error_abs",
-                              "q_taken_mean", "target_mean"):
+                    # host-only from here: the fetch above was the
+                    # blocking part
+                    with rec.span("driver.log", t_env=t_env):
                         if P:
-                            # aggregate row = population mean; per-
-                            # member rows (pop<i>_*) only at P > 1 so a
-                            # P=1 run keeps the solo metric stream
-                            v = np.asarray(last[k], np.float64)
-                            logger.log_stat(k, float(v.mean()), t_env)
-                            if P > 1:
-                                for m in range(P):
-                                    logger.log_stat(f"pop{m}_{k}",
-                                                    float(v[m]), t_env)
-                        else:
-                            logger.log_stat(k, float(last[k]), t_env)
-                    if sight_mon is not None:
-                        # graftsight detector pass over the SAME fetched
-                        # info (no extra device traffic; the monitor
-                        # logs the sight_* stats at full fidelity). A
-                        # fresh trip persists the flight ring like a
-                        # non-finite trip does — the post-mortem then
-                        # carries the verdict even if the run dies later
-                        with rec.span("sight.detect", t_env=t_env):
-                            trips = sight_mon.observe(last, t_env)
-                        if trips:
-                            log.warning(
-                                f"graftsight: detector(s) tripped at "
-                                f"t_env={t_env}: {', '.join(trips)} — "
-                                f"/healthz degraded; run `python -m "
-                                f"t2omca_tpu.obs learning "
-                                f"{results_dir}` for the read")
+                            # (n, P) member flags: a train step counts as
+                            # finite only when EVERY member's update was —
+                            # one poisoned member is a restore-worthy event
+                            # exactly like a solo NaN (the stacked state is
+                            # one checkpoint)
+                            flags = flags.reshape(len(train_infos), -1)\
+                                         .all(axis=1)
+                        for ok in flags:
+                            if ok:
+                                nonfinite_streak = 0
+                            else:
+                                nonfinite_streak += 1
+                                nonfinite_total += 1
+                        if not flags.all():
+                            logger.log_stat("nonfinite_steps", nonfinite_total,
+                                            t_env)
+                            # non-finite trip: event + flight persist, so a
+                            # later divergence abort has the phase history
+                            # leading up to the first trip on disk already
+                            rec.mark("nonfinite", t_env=t_env,
+                                     streak=nonfinite_streak,
+                                     total=nonfinite_total)
                             _persist_flight(os.path.join(
                                 results_dir, "flight_recorder.json"))
-                    train_infos = []
+                            log.warning(
+                                f"non-finite loss/grads in "
+                                f"{int((~flags).sum())}/{len(flags)} train "
+                                f"steps "
+                                f"since last log (streak={nonfinite_streak}, "
+                                f"total={nonfinite_total}); parameter updates "
+                                f"were skipped")
+                        for k in ("loss", "grad_norm", "td_error_abs",
+                                  "q_taken_mean", "target_mean"):
+                            if P:
+                                # aggregate row = population mean; per-
+                                # member rows (pop<i>_*) only at P > 1 so a
+                                # P=1 run keeps the solo metric stream
+                                v = np.asarray(last[k], np.float64)
+                                logger.log_stat(k, float(v.mean()), t_env)
+                                if P > 1:
+                                    for m in range(P):
+                                        logger.log_stat(f"pop{m}_{k}",
+                                                        float(v[m]), t_env)
+                            else:
+                                logger.log_stat(k, float(last[k]), t_env)
+                        if sight_mon is not None:
+                            # graftsight detector pass over the SAME fetched
+                            # info (no extra device traffic; the monitor
+                            # logs the sight_* stats at full fidelity). A
+                            # fresh trip persists the flight ring like a
+                            # non-finite trip does — the post-mortem then
+                            # carries the verdict even if the run dies later
+                            with rec.span("sight.detect", t_env=t_env):
+                                trips = sight_mon.observe(last, t_env)
+                            if trips:
+                                log.warning(
+                                    f"graftsight: detector(s) tripped at "
+                                    f"t_env={t_env}: {', '.join(trips)} — "
+                                    f"/healthz degraded; run `python -m "
+                                    f"t2omca_tpu.obs learning "
+                                    f"{results_dir}` for the read")
+                                _persist_flight(os.path.join(
+                                    results_dir, "flight_recorder.json"))
+                        train_infos = []
                     if (res.nonfinite_tolerance
                             and nonfinite_streak >= res.nonfinite_tolerance):
                         found = (find_checkpoint(model_dir)
@@ -1961,45 +1979,51 @@ def run_sequential(exp: Experiment, logger: Logger,
                         restores += 1
                         nonfinite_streak = 0
                         continue
-                if kit.dispatch_faults:
-                    # ladder visibility: cumulative transient dispatch
-                    # errors (in-place retries included); per-escalation
-                    # counters land in _dispatch_ladder as they happen
-                    logger.log_stat("dispatch_faults",
-                                    kit.dispatch_faults, t_env)
-                if rec.enabled:
-                    # device-fetch accounting (utils/stats.py): how many
-                    # blocking stat round-trips the cadences have cost
-                    logger.log_stat("stat_fetches",
-                                    train_acc.fetches + test_acc.fetches,
-                                    t_env)
-                logger.log_stat("episode", episode, t_env)
-                # wall-clock throughput including everything (train, logging,
-                # cadences) — the honest live rate; the async loop makes the
-                # per-stage timings dispatch-enqueue times unless
-                # profile_stages is on
-                now = time.time()
-                if last_log_time is not None:
-                    rate = ((t_env - last_log_t)
-                            / max(now - last_log_time, 1e-9))
-                    logger.log_stat("env_steps_per_sec", rate, t_env)
+                with rec.span("driver.log", t_env=t_env):
+                    if kit.dispatch_faults:
+                        # ladder visibility: cumulative transient dispatch
+                        # errors (in-place retries included); per-escalation
+                        # counters land in _dispatch_ladder as they happen
+                        logger.log_stat("dispatch_faults",
+                                        kit.dispatch_faults, t_env)
+                    if rec.enabled:
+                        # device-fetch accounting (utils/stats.py): how many
+                        # blocking stat round-trips the cadences have cost
+                        logger.log_stat("stat_fetches",
+                                        train_acc.fetches + test_acc.fetches,
+                                        t_env)
+                    logger.log_stat("episode", episode, t_env)
+                    # wall-clock throughput including everything (train,
+                    # logging, cadences) — the honest live rate; the async
+                    # loop makes the per-stage timings dispatch-enqueue
+                    # times unless profile_stages is on (and they are
+                    # logged only then, below)
+                    now = time.time()
+                    if last_log_time is not None:
+                        rate = ((t_env - last_log_t)
+                                / max(now - last_log_time, 1e-9))
+                        logger.log_stat("env_steps_per_sec", rate, t_env)
+                        if pulse is not None:
+                            pulse.set("env_steps_per_sec", rate)
+                    last_log_time = now
+                    # memwatch phase boundary + the live-plane cadence
+                    # gauges (both no-ops when the plane is off)
+                    pulse_snap = mw.snapshot("log", t_env=t_env)
                     if pulse is not None:
-                        pulse.set("env_steps_per_sec", rate)
-                last_log_time = now
-                # memwatch phase boundary + the live-plane cadence
-                # gauges (both no-ops when the plane is off)
-                pulse_snap = mw.snapshot("log", t_env=t_env)
-                if pulse is not None:
-                    pulse.set("nonfinite_streak", nonfinite_streak)
-                    pulse.set("nonfinite_total", nonfinite_total)
-                    pulse.set("dispatch_faults", kit.dispatch_faults)
-                    pulse.set("ladder_failures", ladder.failures)
-                    pulse.set("restores", restores)
-                    pulse.set("superstep_k", K)
-                    pulse.set_memwatch(pulse_snap)
-                timer.log_and_reset(logger, t_env)
-                logger.print_recent_stats()
-                last_log_t = t_env
+                        pulse.set("nonfinite_streak", nonfinite_streak)
+                        pulse.set("nonfinite_total", nonfinite_total)
+                        pulse.set("dispatch_faults", kit.dispatch_faults)
+                        pulse.set("ladder_failures", ladder.failures)
+                        pulse.set("restores", restores)
+                        pulse.set("superstep_k", K)
+                        pulse.set_memwatch(pulse_snap)
+                    if sync_stages:
+                        # without the per-stage barrier a stage's wall
+                        # clock is enqueue time, not device time: not
+                        # logged under a name that reads as device time
+                        timer.log_and_reset(logger, t_env)
+                    logger.print_recent_stats()
+                    last_log_t = t_env
 
     except BaseException as e:
         # crash path: leave the same causal trail a stall does — the
@@ -2163,7 +2187,8 @@ def run_sebulba(exp: Experiment, logger: Logger, results_dir: str,
     sb = cfg.sebulba
     log = logger.console_logger
     if rec is None:
-        rec = obs_spans.make_recorder(cfg.obs, results_dir)
+        rec = obs_spans.make_recorder(
+            cfg.obs, results_dir, annotate=jax.profiler.TraceAnnotation)
 
     # ---- graftpop population axis over the decoupled loop ---------------
     # (graftlattice, docs/POPULATION.md §composition): P > 0 stacks a

@@ -212,12 +212,13 @@ class ParallelRunner:
         # whole distribution — fixed/uniform/mixture alike). The sampler
         # key folds off rs.key so the env/action key streams are
         # untouched (bit-parity at the fixed default scenario)
-        env_params = self._sample_scenarios(rs.key, member=member)
+        with jax.named_scope("rollout.reset"):
+            env_params = self._sample_scenarios(rs.key, member=member)
 
-        # reset every lane, carrying each lane's Welford normalizer (Q4)
-        reset_keys = jax.random.split(k_reset, b)
-        env_states, obs, gstate, avail = jax.vmap(self.env.reset)(
-            reset_keys, rs.env_states.norm, env_params)
+            # reset every lane, carrying each lane's Welford normalizer (Q4)
+            reset_keys = jax.random.split(k_reset, b)
+            env_states, obs, gstate, avail = jax.vmap(self.env.reset)(
+                reset_keys, rs.env_states.norm, env_params)
 
         hidden = self.mac.init_hidden(b)
 
@@ -254,10 +255,11 @@ class ParallelRunner:
             # pure function of the carried env state (same post-update norm
             # stats the carried obs was normalized with), so recompute it
             # here instead of widening the carry
-            compact = (jax.vmap(self.env.compact_obs)(env_states,
-                                                      env_params)
-                       if self.mac.use_entity_tables or compact_store
-                       else None)
+            with jax.named_scope("env.obs"):
+                compact = (jax.vmap(self.env.compact_obs)(env_states,
+                                                          env_params)
+                           if self.mac.use_entity_tables or compact_store
+                           else None)
             actions, hidden, eps = self.mac.select_actions(
                 params, obs, avail, hidden, k_act, t_env,
                 test_mode=test_mode, compact=compact, eps_scale=eps_scale)
@@ -266,18 +268,21 @@ class ParallelRunner:
             # representation (the f32 episode stack is the HBM hot spot);
             # avail narrows to bool — it is a predicate, and bool storage
             # makes arithmetic misuse a type error
-            pre = (obs_store(env_states, obs, compact), gstate.astype(sd),
-                   avail > 0, actions)
+            with jax.named_scope("rollout.store"):
+                pre = (obs_store(env_states, obs, compact),
+                       gstate.astype(sd), avail > 0, actions)
             viz = ((env_states.pos, env_states.mec_index)
                    if capture else None)
-            env_states, reward, terminated, info, obs, gstate, avail = \
-                jax.vmap(self.env.step)(
-                    env_states, actions, jax.random.split(k_env, b),
-                    env_params)
-            if scale_on:
-                rscale, rec_reward = scale_reward(rscale, reward)
-            else:
-                rec_reward = reward
+            with jax.named_scope("env.step"):
+                env_states, reward, terminated, info, obs, gstate, avail = \
+                    jax.vmap(self.env.step)(
+                        env_states, actions, jax.random.split(k_env, b),
+                        env_params)
+            with jax.named_scope("rollout.store"):
+                if scale_on:
+                    rscale, rec_reward = scale_reward(rscale, reward)
+                else:
+                    rec_reward = reward
             env_terminal = terminated & ~info.episode_limit        # Q7
             ys = (pre, reward, rec_reward, env_terminal, info, eps,
                   (viz + (env_states.last_ack,)) if capture else ())
@@ -291,23 +296,23 @@ class ParallelRunner:
         (pre, reward, rec_reward, env_terminal, info, eps, viz_seq) = ys
         obs_seq, gstate_seq, avail_seq, action_seq = pre
 
-        if compact_store:
-            last_obs_store = obs_store(
-                env_states, last_obs,
-                jax.vmap(self.env.compact_obs)(env_states, env_params))
-        else:
-            last_obs_store = last_obs.astype(sd)
-        tm = TimeMajorEpisodes(
-            obs=obs_seq,
-            state=gstate_seq,
-            avail_actions=avail_seq,
-            actions=action_seq,
-            reward=rec_reward,       # scaled under reward_scaling; else raw
-            terminated=env_terminal,
-            last_obs=last_obs_store,
-            last_state=last_gstate.astype(sd),
-            last_avail=last_avail > 0,
-        )
+        with jax.named_scope("env.obs"):
+            last_compact = (jax.vmap(self.env.compact_obs)(env_states,
+                                                           env_params)
+                            if compact_store else None)
+        with jax.named_scope("rollout.store"):
+            last_obs_store = obs_store(env_states, last_obs, last_compact)
+            tm = TimeMajorEpisodes(
+                obs=obs_seq,
+                state=gstate_seq,
+                avail_actions=avail_seq,
+                actions=action_seq,
+                reward=rec_reward,   # scaled under reward_scaling; else raw
+                terminated=env_terminal,
+                last_obs=last_obs_store,
+                last_state=last_gstate.astype(sd),
+                last_avail=last_avail > 0,
+            )
 
         last = lambda x: x[-1]             # terminal-step info values
         stats = RolloutStats(

@@ -54,9 +54,13 @@ logger = logging.getLogger(__name__)
 #:       raising here simulates a crash mid-checkpoint; truncating
 #:       <dirname>/state.msgpack here simulates a torn write that still
 #:       gets published (the checksum must catch it on resume).
-#:   ``driver.iteration``    t_env=<int>, guard=<ShutdownGuard|None>
+#:   ``driver.iteration``    t_env=<int>, guard=<ShutdownGuard|None>,
+#:                           ts=<TrainState>, key=<driver key>,
+#:                           train_infos=<pending info rows>
 #:       top of every run_sequential iteration — deliver a signal or trip
-#:       the guard at an exact env-step.
+#:       the guard at an exact env-step. ``ts``/``key``/``train_infos``
+#:       are the loop's own objects, for readers (the benchmark's
+#:       window): the next dispatch donates ``ts``, so copy what is kept.
 #:   ``dispatch.superstep``  t_env=<int>, attempt=<int>, k=<int>
 #:       before EACH attempt of the fused K-iteration dispatch (run.py
 #:       `_dispatch`) — sleep here to simulate a hung dispatch (the
@@ -71,7 +75,7 @@ logger = logging.getLogger(__name__)
 #:       blocking point where async device faults surface when
 #:       per-stage sync is off; transient errors route to the ladder's
 #:       restore rung (no in-place retry is possible at a sync point).
-#:   ``fetch.train_infos``   t_env=<int>
+#:   ``fetch.train_infos``   t_env=<int>, train_infos=<the rows fetched>
 #:       before the log-cadence device→host fetch of the accumulated
 #:       train-info rows (non-finite flags + last stats row) — same
 #:       sync-point routing as ``dispatch.wait``.

@@ -150,15 +150,18 @@ def test_compile_cache_placed_from_outside_is_left_alone(tmp_path):
     proc = _python(CACHE_PROBE.format(body="print(enable_compile_cache())"),
                    JAX_COMPILATION_CACHE_DIR=placed)
     assert proc.returncode == 0, proc.stderr[-2000:]
-    assert proc.stdout.splitlines() == [placed, f"[] {placed}"]
+    assert proc.stdout.splitlines() == [
+        placed, f"['jax_compilation_cache_include_metadata_in_key'] {placed}"]
 
 
 def test_compile_cache_defaults_to_the_checkout():
     proc = _python(CACHE_PROBE.format(body="print(enable_compile_cache())"))
     assert proc.returncode == 0, proc.stderr[-2000:]
     want = os.path.join(REPO, ".jax_cache")
+    # the scopes of a program are metadata: they are part of the key
     assert proc.stdout.splitlines() == [
-        want, f"['jax_compilation_cache_dir'] {want}"]
+        want, f"['jax_compilation_cache_include_metadata_in_key', "
+              f"'jax_compilation_cache_dir'] {want}"]
 
 
 def test_serve_export_and_load_do_not_move_the_cache(trained, tmp_path,

@@ -47,8 +47,9 @@ def logged_keys(results_root):
     return keys, rows
 
 
-def test_run_sequential_end_to_end(tmp_path):
-    cfg = tiny_cfg(tmp_path)
+@pytest.mark.parametrize("profile_stages", [True, False])
+def test_run_sequential_end_to_end(tmp_path, profile_stages):
+    cfg = tiny_cfg(tmp_path, profile_stages=profile_stages)
     ts = run(cfg, Logger())
     # the loop ran past t_max, counting B env-steps per slot
     assert int(jax.device_get(ts.runner.t_env)) > cfg.t_max
@@ -60,8 +61,11 @@ def test_run_sequential_end_to_end(tmp_path):
               "task_completion_rate_mean", "episode_limit_mean", "epsilon",
               "loss", "grad_norm", "episode"):
         assert k in keys, (k, sorted(keys))
-    # profiling timers flow into the same stream (SURVEY.md §5(1))
-    assert "time_rollout_ms" in keys
+    # profiling timers flow into the same stream (SURVEY.md §5(1)) where
+    # each stage ends on a device barrier; without it a stage's wall
+    # clock is enqueue time and is not logged
+    assert ("time_rollout_ms" in keys) == profile_stages
+    assert ("time_train_ms" in keys) == profile_stages
     # checkpoints: numeric step dirs under models/<token>/
     dirs = glob.glob(os.path.join(tmp_path, "models", "*", "*"))
     assert dirs and all(os.path.basename(d).isdigit() for d in dirs)

@@ -307,7 +307,7 @@ def test_sigterm_writes_emergency_checkpoint_and_returns(tmp_path):
     save_model_interval steps."""
     cfg = tiny_cfg(tmp_path, t_max=100_000, save_model_interval=10_000)
 
-    def _preempt(t_env, guard):
+    def _preempt(t_env, guard, **kw):
         if t_env >= 24:
             signal.raise_signal(signal.SIGTERM)
 

@@ -1,0 +1,279 @@
+"""The tracing vocabulary (``obs/spans.py:KNOWN_SCOPES`` /
+``KNOWN_PHASES``): every ``jax.named_scope`` of the package is in the
+vocabulary and every member is opened; the four driver programs carry the
+scopes they should and compute the same thing without them; the span
+recorder's injected ``annotate`` nests LIFO; and a profiler trace of a
+real ``run.run`` holds the driver's spans as host events."""
+
+import ast
+import contextlib
+import glob
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from t2omca_tpu.config import from_dict, sanity_check
+from t2omca_tpu.obs import spans
+from t2omca_tpu.obs.spans import KNOWN_PHASES, KNOWN_SCOPES
+from t2omca_tpu.utils import resilience
+from t2omca_tpu.utils.logging import Logger
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+PKG = os.path.join(REPO, "t2omca_tpu")
+
+ROLLOUT = {"rollout.reset", "env.obs", "env.step", "env.normalizer",
+           "act.forward", "act.select", "rollout.store", "agent.embed",
+           "agent.attention", "agent.ff", "agent.head"}
+TRAIN = {"replay.sample", "replay.priority", "learner.agent",
+         "learner.mixer", "learner.target", "learner.loss",
+         "learner.optimizer", "sight", "agent.embed", "agent.attention",
+         "agent.ff", "agent.head"}
+CARRIES = {"_rollout": ROLLOUT, "_insert": {"replay.insert"},
+           "_train_iter": TRAIN, "_superstep": set(KNOWN_SCOPES)}
+
+
+def tiny(**kw):
+    """Entity tables, compact storage, PER, remat and sight on: every
+    scope of the vocabulary has work to name."""
+    d = {"batch_size_run": 4, "batch_size": 4, "superstep": 2,
+         "t_max": 96, "test_interval": 48, "test_nepisode": 4,
+         "log_interval": 24, "runner_log_interval": 24,
+         "save_model": False,
+         "env_args": {"agv_num": 3, "mec_num": 2, "num_channels": 2,
+                      "episode_limit": 6},
+         "model": {"emb": 8, "heads": 2, "depth": 2, "mixer_emb": 8,
+                   "mixer_heads": 2, "mixer_depth": 2,
+                   "standard_heads": True, "remat": True},
+         "replay": {"buffer_size": 8, "store_dtype": "bfloat16"},
+         "obs": {"enabled": True, "pulse_port": 0,
+                 "sight": {"enabled": True}}}
+    d.update(kw)
+    return sanity_check(from_dict(d))
+
+
+def token(scope: str):
+    """A scope as a token of a name stack: inside ``vmap(...)``,
+    ``transpose(jvp(...))`` and the like, never as part of a longer
+    dotted name."""
+    return re.compile(r"(?<![\w.])" + re.escape(scope) + r"(?![\w.])")
+
+
+# ------------------------------------------------------------ (a) sources
+
+def _named_scope_calls():
+    """→ [(file, line, argument node)] of every ``jax.named_scope(...)``
+    in the package."""
+    out = []
+    for path in glob.glob(os.path.join(PKG, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            tree = ast.parse(f.read(), path)
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "named_scope"):
+                out.append((os.path.relpath(path, REPO), node.lineno,
+                            node.args[0] if node.args else None))
+    return out
+
+
+def test_every_named_scope_is_a_literal_of_the_vocabulary():
+    calls = _named_scope_calls()
+    assert calls
+    for path, line, arg in calls:
+        # no scope name is built from a runtime value
+        assert isinstance(arg, ast.Constant) and isinstance(arg.value, str), \
+            f"{path}:{line}: named_scope takes a literal"
+        assert arg.value in KNOWN_SCOPES, \
+            f"{path}:{line}: {arg.value!r} is not in KNOWN_SCOPES"
+
+
+def test_every_member_of_the_vocabulary_is_opened_somewhere():
+    opened = {arg.value for _, _, arg in _named_scope_calls()
+              if isinstance(arg, ast.Constant)}
+    assert KNOWN_SCOPES - opened == set()
+    assert not (KNOWN_SCOPES & KNOWN_PHASES)
+
+
+# ---------------------------------------------------- (b), (c) programs
+
+def _lowered_texts():
+    """The four driver programs of the tiny configuration, lowered from
+    shapes → {program: (text with debug info, text without)}."""
+    from t2omca_tpu.run import Experiment
+    cfg = tiny()
+    exp = Experiment.build(cfg)
+    ts = jax.eval_shape(lambda: exp.init_train_state(0))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    k = cfg.superstep
+    keys = jax.ShapeDtypeStruct((k,) + key.shape, key.dtype)
+    t_env = jnp.asarray(0)
+    rollout, insert, train_iter = exp.jitted_programs()
+    params, rs = ts.learner.params["agent"], ts.runner
+    _, batch, _ = jax.eval_shape(
+        lambda p, r: rollout(p, r, test_mode=False), params, rs)
+    lowered = {
+        "_rollout": rollout.lower(params, rs, test_mode=False),
+        "_insert": insert.lower(ts.buffer, batch),
+        "_train_iter": train_iter.lower(ts, key, t_env),
+        "_superstep": exp.superstep_program(k).lower(ts, keys, t_env),
+    }
+    return {name: (lo.as_text(debug_info=True), lo.as_text())
+            for name, lo in lowered.items()}
+
+
+@pytest.fixture(scope="module")
+def texts():
+    return _lowered_texts()
+
+
+@pytest.fixture(scope="module")
+def texts_without_scopes():
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax, "named_scope", contextlib.nullcontext)
+    try:
+        return _lowered_texts()
+    finally:
+        mp.undo()
+
+
+@pytest.mark.parametrize("program", sorted(CARRIES))
+def test_program_carries_its_scopes(texts, program):
+    debug, _ = texts[program]
+    missing = {s for s in CARRIES[program] if not token(s).search(debug)}
+    assert missing == set()
+    if program in ("_train_iter", "_superstep"):
+        # the wrappers split the learner into forward and backward
+        assert re.search(r"transpose\(jvp\(learner\.agent\)\)", debug)
+        assert re.search(r"(?<!transpose\()jvp\(learner\.agent\)", debug)
+        assert "checkpoint" in debug or "remat" in debug
+    if program in ("_rollout", "_insert"):
+        assert not token("learner.optimizer").search(debug)
+
+
+@pytest.mark.parametrize("program", sorted(CARRIES))
+def test_scopes_change_no_computation(texts, texts_without_scopes, program):
+    debug_off, plain_off = texts_without_scopes[program]
+    assert not any(token(s).search(debug_off) for s in KNOWN_SCOPES)
+    assert texts[program][1] == plain_off
+
+
+# -------------------------------------------------------- (d) the recorder
+
+class _Annotation:
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _Annotation.log.append(("enter", self.name))
+
+    def __exit__(self, exc_type, exc, tb):
+        _Annotation.log.append(("exit", self.name, exc_type))
+
+
+def test_injected_annotate_nests_lifo_and_survives_an_exception(tmp_path):
+    _Annotation.log = []
+    path = str(tmp_path / "spans.jsonl")
+    rec = spans.SpanRecorder(jsonl_path=path, flush_every=1,
+                             annotate=_Annotation)
+    with rec.span("dispatch.superstep"):
+        with rec.span("fetch.train_stats"):
+            pass
+        with pytest.raises(KeyError):
+            with rec.span("dispatch.wait"):
+                raise KeyError("x")
+    rec.close()
+    assert _Annotation.log == [
+        ("enter", "dispatch.superstep"),
+        ("enter", "fetch.train_stats"), ("exit", "fetch.train_stats", None),
+        ("enter", "dispatch.wait"), ("exit", "dispatch.wait", KeyError),
+        ("exit", "dispatch.superstep", None)]
+    assert rec._open_ann == {} and rec.current_phase() is None
+    import json
+    with open(path) as f:
+        events = [json.loads(line) for line in f]
+    assert [e["phase"] for e in events] == [
+        "fetch.train_stats", "dispatch.wait", "dispatch.superstep"]
+    assert events[1]["outcome"] == "error:KeyError"
+    # t0 is the wall clock as read, not rounded to the millisecond
+    assert any(e["t0"] != round(e["t0"], 3) for e in events)
+    # without an annotate the recorder opens none
+    plain = spans.SpanRecorder()
+    with plain.span("dispatch.wait"):
+        pass
+    assert plain._open_ann == {}
+
+
+def test_spans_module_imports_no_jax():
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, t2omca_tpu.obs.spans as s; "
+         "assert 'jax' not in sys.modules, 'spans imports jax'; "
+         "assert s.KNOWN_SCOPES and s.KNOWN_PHASES"],
+        cwd=REPO, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-1000:]
+
+
+# ------------------------------------------- (e) the profiler's host plane
+
+@pytest.fixture(scope="module")
+def traced_run(tmp_path_factory):
+    """One ``run.run`` of the tiny configuration with the repo's own
+    trace window over two fused dispatches, and a hook that keeps what
+    the driver hands it."""
+    from t2omca_tpu.run import run
+    root = tmp_path_factory.mktemp("traced_run")
+    trace_dir = str(root / "trace")
+    cfg = tiny(local_results_path=str(root / "results"),
+               profile_dir=trace_dir, profile_start=48,
+               profile_iterations=2)
+    seen = {"driver.iteration": [], "fetch.train_infos": []}
+    resilience.clear_faults()
+    for point in seen:
+        resilience.register_fault(
+            point, lambda _p=point, **kw: seen[_p].append(kw))
+    try:
+        run(cfg, Logger())
+    finally:
+        resilience.clear_faults()
+    return cfg, trace_dir, seen
+
+
+def test_profiler_trace_holds_the_drivers_spans_as_host_events(traced_run):
+    from jax.profiler import ProfileData
+    _, trace_dir, _ = traced_run
+    paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert paths
+    names = {}
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in KNOWN_PHASES:
+                    names[ev.name] = names.get(ev.name, 0) + 1
+    assert names.get("dispatch.superstep", 0) >= 2, names
+    assert names.get("driver.prepare", 0) >= 1, names
+    assert names.get("driver.account", 0) >= 1, names
+
+
+def test_hooks_hand_over_state_key_and_info_rows(traced_run):
+    cfg, _, seen = traced_run
+    boundaries = seen["driver.iteration"]
+    assert len(boundaries) >= 3
+    for kw in boundaries:
+        assert {"t_env", "guard", "ts", "key", "train_infos"} <= set(kw)
+        assert hasattr(kw["ts"], "learner") and hasattr(kw["ts"], "buffer")
+        assert kw["key"].shape == jax.random.PRNGKey(0).shape
+        assert isinstance(kw["train_infos"], list)
+    fetched = seen["fetch.train_infos"]
+    assert fetched
+    for kw in fetched:
+        assert kw["train_infos"] and "all_finite" in kw["train_infos"][-1]

@@ -222,7 +222,7 @@ def test_shutdown_guard_exits_at_dispatch_boundary(tmp_path):
     cfg = tiny_cfg(tmp_path, t_max=100_000, superstep=2, save_model=True,
                    save_model_interval=10_000)
 
-    def _preempt(t_env, guard):
+    def _preempt(t_env, guard, **kw):
         if t_env >= 48:
             signal.raise_signal(signal.SIGTERM)
 
