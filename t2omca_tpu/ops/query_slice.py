@@ -39,6 +39,20 @@ the perf mode (mirroring ``models/transformer.py:101-105``).
 Forward-compatible with gradient flow: everything here is plain jnp, so
 ``jax.grad`` through it yields the same gradients as the dense module (same
 function, different association).
+
+**Two associations of the entity-table forward.** The fold above is made for
+the obs path (``transformer_rows``), whose keys are per-sequence
+``(S, T, E)`` tensors: not projecting them is the whole gain, and it holds at
+either head geometry. ``agent_forward_qslice_entity`` contracts against
+per-env TABLES instead, and there the fold only pays where a head is as wide
+as the embedding (``head_dim == emb``, the reference's Q1 geometry). With
+``standard_heads`` (``head_dim = emb / heads``) every folded query and context
+row is ``heads`` times wider than the mathematics needs, so that forward
+projects queries, keys and values as the dense module does — the entity
+tables' keys and values straight from the nine normalised features, the
+embedding being affine (``_fold_entity_heads``) — and contracts at head width
+(``_entity_attention_heads``). One sum, two associations, chosen at trace time
+from ``head_dim`` and ``emb`` alone.
 """
 
 from __future__ import annotations
@@ -143,6 +157,40 @@ def _fold_block(bp: dict, emb: int, heads: int, head_dim: int,
     wvu = jnp.einsum("ehd,hdf->hef", wv_h, wu_h)                 # (H, E, E)
     return (wqk.reshape(e, h * e).astype(dtype),
             wvu.reshape(h * e, e).astype(dtype))
+
+
+def _fold_entity_heads(p: dict, *, head_dim: int, depth: int, dtype) -> list:
+    """Per-block kernels of the HEAD-WIDTH entity-table forward
+    (``head_dim < emb``; ``_entity_attention_heads``): the dense module's
+    own four projections — ``wq`` / ``wk`` ``(E, H·D)`` each carrying its
+    Q1 ``head_dim**-0.25``, ``wv``, ``wu (H·D, E)`` with ``u_bias`` — plus
+    the entity
+    tables' keys and values as affine maps of the NINE normalised
+    features, ``wek = we · wk`` ``(9, H·D)`` with ``bek = be · wk``
+    (``wev`` / ``bev`` likewise): the embedding is affine, so no
+    ``(B, A, E)`` table needs projecting. O(params), differentiable."""
+    fe = p["feat_embedding"]
+    we = fe["kernel"].astype(jnp.float32)                          # (9, E)
+    be = fe["bias"].astype(jnp.float32)
+    scale = head_dim ** -0.25
+    hi = jax.lax.Precision.HIGHEST
+    out = []
+    for i in range(depth):
+        at = p["transformer"][f"block_{i}"]["attention"]
+        with jax.named_scope("agent.attention"):
+            wk = at["tokeys"]["kernel"].astype(jnp.float32) * scale
+            wv = at["tovalues"]["kernel"].astype(jnp.float32)
+            out.append({
+                "wq": (at["toqueries"]["kernel"].astype(jnp.float32)
+                       * scale).astype(dtype),
+                "wk": wk.astype(dtype), "wv": wv.astype(dtype),
+                "wu": at["unifyheads"]["kernel"].astype(dtype),
+                "u_bias": at["unifyheads"]["bias"],
+                "wek": jnp.dot(we, wk, precision=hi).astype(dtype),
+                "bek": jnp.dot(be, wk, precision=hi),
+                "wev": jnp.dot(we, wv, precision=hi).astype(dtype),
+                "bev": jnp.dot(be, wv, precision=hi)})
+    return out
 
 
 def fold_transformer(tf_params: dict, *, emb: int, heads: int,
@@ -290,12 +338,19 @@ def fold_agent_params(variables: dict, *, emb: int, heads: int, depth: int,
         return variables
     p = variables["params"]
     head_dim = emb // heads if standard_heads else emb
-    return {FOLDED: True,
+    tree = {FOLDED: True,
             "fe": p["feat_embedding"],
             "tf": fold_transformer(p["transformer"], emb=emb, heads=heads,
                                    head_dim=head_dim, depth=depth,
                                    dtype=dtype),
             "qb": p["q_basic"]}
+    if head_dim < emb:
+        # heads narrower than emb: the entity-table forward contracts at
+        # head width instead of through wqk / wvu (dead leaves in every
+        # program that runs the obs path)
+        tree["ent"] = _fold_entity_heads(p, head_dim=head_dim, depth=depth,
+                                         dtype=dtype)
+    return tree
 
 
 def agent_forward_qslice(variables: dict, inputs: jnp.ndarray,
@@ -362,6 +417,180 @@ def make_mixer_qslice(mixer):
     return fold, apply
 
 
+#: lanes of a TPU vector register / MXU tile: the head-width entity forward
+#: contracts its heads in groups of ``_LANES // head_dim`` (a group's
+#: ``(h, a)`` rows against a group's lanes), the smallest unit that fills a
+#: tile — every head beyond it only adds cross-head products that are
+#: thrown away
+_LANES = 128
+
+
+def _entity_attention_heads(hp: dict, x0: jnp.ndarray, h_tok: jnp.ndarray,
+                            feats: jnp.ndarray, inv_self: jnp.ndarray,
+                            seen: jnp.ndarray, *, heads: int, dtype
+                            ) -> jnp.ndarray:
+    """One block's entity-table attention at HEAD width (``D < E``).
+
+    ``hp`` one block of ``_fold_entity_heads``; ``x0 (B, A, E)`` the query
+    rows; ``h_tok (B, A, E)`` the layer-0 hidden tokens (key 0); ``feats
+    (B, 2A, 9)`` the normalised features of the visible then the masked
+    entity rows; ``inv_self (B, A, 1)`` f32, the is-self feature's
+    ``1 / std``; ``seen (B, 2A, A)`` bool, row ``j < A``: agent ``a`` sees
+    entity ``j``, row ``A + j``: it does not. Returns ``attended
+    (B·A, E)`` f32."""
+    b, a, emb = x0.shape
+    hd = hp["wq"].shape[1]
+    d = hd // heads
+    g = max(1, min(heads, _LANES // d))       # heads a group
+    while heads % g:
+        g -= 1
+    ctx = []
+    for lo in range(0, hd, g * d):
+        cols = lambda name: hp[name][..., lo:lo + g * d]
+        ctx.append(_entity_context_heads(
+            {name: cols(name)
+             for name in ("wq", "wk", "wv", "wek", "bek", "wev", "bev")},
+            x0, h_tok, feats, inv_self, seen, heads=g, dtype=dtype))
+    ctx = jnp.concatenate(ctx, axis=-1)                       # (B, A, H·D)
+    return (jnp.dot(ctx.reshape(b * a, hd), hp["wu"],
+                    preferred_element_type=jnp.float32)
+            + hp["u_bias"].astype(jnp.float32))
+
+
+def _entity_context_heads(hp: dict, x0: jnp.ndarray, h_tok: jnp.ndarray,
+                          feats: jnp.ndarray, inv_self: jnp.ndarray,
+                          seen: jnp.ndarray, *, heads: int, dtype
+                          ) -> jnp.ndarray:
+    """The attention context of ``heads`` heads whose kernels' columns
+    ``hp`` holds (``H·D`` below is THEIR width) → ``(B, A, H·D)`` in
+    ``dtype``.
+
+    Every operand keeps ``H·D`` in the lanes and is never split into heads:
+    a head is a block mask on the ``H·A`` expanded query rows (fused into
+    the contraction's operand), the logits are laid out ``(B, 2A, H·A)`` so
+    that the softmax reduces over rows, and of the context's
+    ``(B, H·A, H·D)`` product each row keeps its own head's lanes."""
+    b, a, emb = x0.shape
+    hd = hp["wq"].shape[1]
+    d = hd // heads
+    f32 = jnp.float32
+    # (H, H·D): the lanes of head h
+    hmask = jnp.arange(hd)[None, :] // d == jnp.arange(heads)[:, None]
+
+    def proj(x, w):                                   # (B, n, E) → (B, n, H·D)
+        y = jnp.dot(x.reshape(-1, emb), w, preferred_element_type=f32)
+        return y.astype(dtype).reshape(b, x.shape[1], hd)
+
+    def table(w, bias):                               # (B, 2A, H·D)
+        return (jnp.dot(feats, w, preferred_element_type=f32)
+                + bias.astype(f32)).astype(dtype)
+
+    def columns(x):                                   # (B·A, H) → (B, 1, H·A)
+        return x.reshape(b, a, heads).transpose(0, 2, 1).reshape(
+            b, 1, heads * a)
+
+    q = proj(x0, hp["wq"])
+    k_0, v_0 = proj(h_tok, hp["wk"]), proj(h_tok, hp["wv"])
+    k_ent, v_ent = table(hp["wek"], hp["bek"]), table(hp["wev"], hp["bev"])
+
+    # logits of key 0 (the own hidden token) and the is-self correction of
+    # the diagonal: per-head sums over the lanes, as contractions with hmask
+    l0 = columns(jnp.dot(
+        (q.astype(f32) * k_0.astype(f32)).reshape(b * a, hd),
+        hmask.T.astype(f32), precision=jax.lax.Precision.HIGHEST))
+    ls = columns(jnp.dot(
+        q.reshape(b * a, hd),
+        jnp.where(hmask.T, hp["wek"][8][:, None], 0).astype(dtype),
+        preferred_element_type=f32) * inv_self.reshape(b * a, 1))
+    # logits against both entity tables, transposed: (B, 2A, H·A)
+    qx = jnp.where(hmask[None, :, None, :], q[:, None], 0).reshape(
+        b, heads * a, hd)
+    lt = jnp.einsum("bje,bme->bjm", k_ent, qx,
+                    preferred_element_type=f32).astype(dtype)
+    # (·, 2A, H·A): every head's columns see the same rows
+    seen = jnp.tile(seen, (1, 1, heads))
+    own = jnp.tile(jnp.eye(2 * a, a, dtype=bool), (1, heads))[None]
+    # parity mode keeps f32 softmax; bf16 perf mode stays in bf16 (mirrors
+    # models/transformer.py); unseen rows drop out of max and sum
+    sdt = f32 if dtype == jnp.float32 else dtype
+    lg = jnp.where(seen, lt.astype(f32) + jnp.where(own, ls, 0.0),
+                   jnp.finfo(sdt).min).astype(sdt)
+    l0 = l0.astype(sdt)
+    top = jax.lax.stop_gradient(
+        jnp.maximum(l0, lg.max(axis=1, keepdims=True)))
+    e0, ex = jnp.exp(l0 - top), jnp.exp(lg - top)
+    den = e0 + ex.sum(axis=1, keepdims=True)
+    a0 = (e0 / den).astype(dtype)                             # (B, 1, H·A)
+    pt = (ex / den).astype(dtype)                             # (B, 2A, H·A)
+    diag = jnp.where(own, pt, 0).sum(axis=1, keepdims=True)
+    # context of every (h, a) row over ALL H·D lanes, of which it keeps its
+    # own head's
+    full = jnp.einsum("bjm,bje->bme", pt, v_ent,
+                      preferred_element_type=dtype)           # (B, H·A, H·D)
+    full = full.reshape(b, heads, a, hd)
+    ctx = full[:, 0]
+    for h in range(1, heads):
+        ctx = jnp.where(hmask[h], full[:, h], ctx)            # (B, A, H·D)
+
+    def lanes(x):             # (B, 1, H·A) → (B, A, H·D): a head's value on
+        x = x.reshape(b, heads, a).transpose(0, 2, 1)         # its own lanes
+        return jnp.dot(x.reshape(b * a, heads), hmask.astype(dtype),
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=f32).reshape(b, a, hd)
+
+    v_self = (hp["wev"][8].astype(f32) * inv_self).astype(dtype)  # (B, A, H·D)
+    return (ctx.astype(f32) + lanes(a0) * v_0.astype(f32)
+            + lanes(diag) * v_self.astype(f32)).astype(dtype)
+
+
+def _entity_attention_folded(bp: dict, x0: jnp.ndarray, h_tok: jnp.ndarray,
+                             e_vis: jnp.ndarray, e_hid: jnp.ndarray,
+                             self_corr: jnp.ndarray, vis: jnp.ndarray,
+                             eye: jnp.ndarray, idx_diag: jnp.ndarray, *,
+                             heads: int, dtype) -> jnp.ndarray:
+    """One block's entity-table attention through the FOLDED kernels
+    (``head_dim == emb``): ``bp`` one block of ``fold_transformer``, the
+    embedded ``(B, A, E)`` tables as keys and values, never projected.
+    Returns ``attended (B·A, E)`` f32."""
+    b, a, emb = x0.shape
+    s = b * a
+    qp = jnp.dot(x0.reshape(s, emb), bp["wqk"],
+                 preferred_element_type=jnp.float32)
+    qp = qp.astype(dtype).reshape(b, a, heads, emb)
+    # logits against key 0 (own hidden token) and the entity tables
+    l0 = jnp.einsum("bahe,bae->bah", qp, h_tok,
+                    preferred_element_type=jnp.float32)
+    lv = jnp.einsum("bahe,bje->bahj", qp, e_vis,
+                    preferred_element_type=jnp.float32)
+    lh = jnp.einsum("bahe,bje->bahj", qp, e_hid,
+                    preferred_element_type=jnp.float32)
+    ls = jnp.einsum("bahe,bae->bah", qp, self_corr,
+                    preferred_element_type=jnp.float32)
+    lent = jnp.where(vis, lv, lh) + eye.astype(jnp.float32) \
+        * ls[..., None]
+    logits = jnp.concatenate([l0[..., None], lent], axis=-1)
+    if dtype == jnp.float32:
+        attn = jax.nn.softmax(logits, axis=-1)
+    else:
+        attn = jax.nn.softmax(logits.astype(dtype), axis=-1)
+    attn = attn.astype(dtype)
+    a0, ae = attn[..., 0], attn[..., 1:]                  # (B,A,H[,A])
+    av = ae * vis.astype(dtype)
+    ah = ae - av  # masked branch
+    diag = jnp.take_along_axis(ae, idx_diag, axis=-1)[..., 0]
+    ctx = (a0[..., None] * h_tok[:, :, None, :]
+           + jnp.einsum("bahj,bje->bahe", av, e_vis,
+                        preferred_element_type=jnp.float32
+                        ).astype(dtype)
+           + jnp.einsum("bahj,bje->bahe", ah, e_hid,
+                        preferred_element_type=jnp.float32
+                        ).astype(dtype)
+           + diag[..., None] * self_corr[:, :, None, :])
+    ctx = ctx.astype(dtype).reshape(s, heads * emb)
+    return (jnp.dot(ctx, bp["wvu"], preferred_element_type=jnp.float32)
+            + bp["u_bias"].astype(jnp.float32))
+
+
 def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
                                 same_mec: jnp.ndarray, mean: jnp.ndarray,
                                 std: jnp.ndarray, hidden_state: jnp.ndarray,
@@ -370,9 +599,10 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
                                 dtype=jnp.float32,
                                 noise_key: jnp.ndarray | None = None
                                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Entity-table acting forward: ``agent_forward_qslice`` without ever
-    materializing per-agent token embeddings. ``noise_key`` as in
-    ``_q_head`` (noisy heads supported).
+    """Entity-table forward (acting, and the learner's compact-storage
+    unroll): ``agent_forward_qslice`` without ever materializing per-agent
+    token embeddings. ``noise_key`` as in ``_q_head`` (noisy heads
+    supported).
 
     Exploits the structure of the entity observation
     (``envs/mec_offload.py:_raw_obs`` + the shared ``fast_norm`` affine):
@@ -387,6 +617,16 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
     the acting path. Exact to float reassociation vs the obs-path forward
     (pinned in tests/test_entity_tables.py).
 
+    Where ``head_dim == emb`` the blocks run the folded kernels (``wqk`` /
+    ``wvu``) against the embedded ``(B, A, E)`` tables, so keys and values
+    are never projected. Where ``head_dim < emb`` (``standard_heads``) that
+    fold would make every intermediate ``(B, A, H, E)``, ``heads`` times
+    wider than a head needs: the blocks then project per-env key and value
+    tables (``(B, 2A, H·D)``, from the nine features) and contract at head
+    width (``_entity_attention_heads``). Which one runs follows from the
+    shapes of the parameters (``fold_agent_params`` gives the ``"ent"``
+    kernels exactly when ``head_dim < emb``) — no option selects it.
+
     Inputs per ``MultiAgvOffloadingEnv.compact_obs``: ``rows (B, A, 8)``,
     ``same_mec (B, A, A)`` bool, ``mean/std (B, A, 9)``; ``hidden_state
     (B, A, emb)``. Requires ``obs_entity_mode`` + ``fast_norm`` and no
@@ -396,6 +636,9 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
     b, a, _ = rows.shape
     s = b * a
 
+    # one association of the same sum per head geometry, known from the
+    # shapes alone (fold_agent_params; docstring)
+    head_width = "ent" in f
     with jax.named_scope("agent.embed"):
         # ---- per-env embedding tables (feat 8 = is_self; _raw_obs layout)
         denom = std.astype(jnp.float32) + 1e-8                    # (B, A, 9)
@@ -403,60 +646,38 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
             [rows.astype(jnp.float32), jnp.zeros((b, a, 1))], axis=-1)
         nv = ((rows9 - mean) / denom).astype(dtype)               # visible row
         nh = ((-mean) / denom).astype(dtype)                      # masked row
-        we = f["fe"]["kernel"].astype(dtype)                      # (9, E)
-        be = f["fe"]["bias"].astype(jnp.float32)
-        e_vis = (jnp.dot(nv, we, preferred_element_type=jnp.float32)
-                 + be).astype(dtype)                              # (B, A, E)
-        e_hid = (jnp.dot(nh, we, preferred_element_type=jnp.float32)
-                 + be).astype(dtype)
-        self_corr = (we[8][None, None, :].astype(jnp.float32)
-                     / denom[..., 8:9]).astype(dtype)             # (B, A, E)
-
-        h_tok = hidden_state.astype(dtype)                        # (B, A, E)
-        vis = same_mec[:, :, None, :]  # (B, A, 1, A)
-        eye = jnp.eye(a, dtype=dtype)[None, :, None, :]  # (1, A, 1, A)
-        idx_diag = jnp.arange(a)[None, :, None, None]
+        if head_width:
+            h_tok = hidden_state.astype(dtype)                    # (B, A, E)
+            feats = jnp.concatenate([nv, nh], axis=1)             # (B, 2A, 9)
+            inv_self = 1.0 / denom[..., 8:9]                      # (B, A, 1)
+            sees = jnp.swapaxes(same_mec, 1, 2)                   # (B, j, a)
+            seen = jnp.concatenate([sees, ~sees], axis=1)         # (B, 2A, A)
+        else:
+            we = f["fe"]["kernel"].astype(dtype)                  # (9, E)
+            be = f["fe"]["bias"].astype(jnp.float32)
+            e_vis = (jnp.dot(nv, we, preferred_element_type=jnp.float32)
+                     + be).astype(dtype)                          # (B, A, E)
+            e_hid = (jnp.dot(nh, we, preferred_element_type=jnp.float32)
+                     + be).astype(dtype)
+            self_corr = (we[8][None, None, :].astype(jnp.float32)
+                         / denom[..., 8:9]).astype(dtype)         # (B, A, E)
+            h_tok = hidden_state.astype(dtype)
+            vis = same_mec[:, :, None, :]  # (B, A, 1, A)
+            eye = jnp.eye(a, dtype=dtype)[None, :, None, :]  # (1, A, 1, A)
+            idx_diag = jnp.arange(a)[None, :, None, None]
 
     x0 = h_tok
     for i in range(depth):
         bp = f["tf"]["blocks"][i]
         with jax.named_scope("agent.attention"):
-            qp = jnp.dot(x0.reshape(s, emb), bp["wqk"],
-                         preferred_element_type=jnp.float32)
-            qp = qp.astype(dtype).reshape(b, a, heads, emb)
-            # logits against key 0 (own hidden token) and the entity tables
-            l0 = jnp.einsum("bahe,bae->bah", qp, h_tok,
-                            preferred_element_type=jnp.float32)
-            lv = jnp.einsum("bahe,bje->bahj", qp, e_vis,
-                            preferred_element_type=jnp.float32)
-            lh = jnp.einsum("bahe,bje->bahj", qp, e_hid,
-                            preferred_element_type=jnp.float32)
-            ls = jnp.einsum("bahe,bae->bah", qp, self_corr,
-                            preferred_element_type=jnp.float32)
-            lent = jnp.where(vis, lv, lh) + eye.astype(jnp.float32) \
-                * ls[..., None]
-            logits = jnp.concatenate([l0[..., None], lent], axis=-1)
-            if dtype == jnp.float32:
-                attn = jax.nn.softmax(logits, axis=-1)
+            if head_width:
+                attended = _entity_attention_heads(
+                    f["ent"][i], x0, h_tok, feats, inv_self, seen,
+                    heads=heads, dtype=dtype)
             else:
-                attn = jax.nn.softmax(logits.astype(dtype), axis=-1)
-            attn = attn.astype(dtype)
-            a0, ae = attn[..., 0], attn[..., 1:]                  # (B,A,H[,A])
-            av = ae * vis.astype(dtype)
-            ah = ae - av  # masked branch
-            diag = jnp.take_along_axis(ae, idx_diag, axis=-1)[..., 0]
-            ctx = (a0[..., None] * h_tok[:, :, None, :]
-                   + jnp.einsum("bahj,bje->bahe", av, e_vis,
-                                preferred_element_type=jnp.float32
-                                ).astype(dtype)
-                   + jnp.einsum("bahj,bje->bahe", ah, e_hid,
-                                preferred_element_type=jnp.float32
-                                ).astype(dtype)
-                   + diag[..., None] * self_corr[:, :, None, :])
-            ctx = ctx.astype(dtype).reshape(s, heads * emb)
-            attended = (jnp.dot(ctx, bp["wvu"],
-                                preferred_element_type=jnp.float32)
-                        + bp["u_bias"].astype(jnp.float32))
+                attended = _entity_attention_folded(
+                    bp, x0, h_tok, e_vis, e_hid, self_corr, vis, eye,
+                    idx_diag, heads=heads, dtype=dtype)
         x0 = _block_tail(bp, attended, x0.reshape(s, emb), dtype) \
             .reshape(b, a, emb)
 

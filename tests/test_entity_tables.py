@@ -24,8 +24,9 @@ def _cfg(**model_kw):
         batch_size_run=4,
         env_args=EnvConfig(agv_num=5, mec_num=2, num_channels=3,
                            episode_limit=6, fast_norm=True),
-        model=ModelConfig(emb=16, heads=2, depth=2, mixer_emb=16,
-                          mixer_heads=2, mixer_depth=2, **model_kw),
+        model=ModelConfig(**{**dict(emb=16, heads=2, depth=2, mixer_emb=16,
+                                    mixer_heads=2, mixer_depth=2),
+                             **model_kw}),
     ))
 
 
@@ -64,9 +65,11 @@ def test_entity_forward_matches_obs_forward(steps, standard_heads):
     np.testing.assert_allclose(h_ent, h_obs, rtol=2e-4, atol=2e-5)
 
 
-def test_entity_forward_matches_dense_flax():
-    """Transitively exact vs the dense module too."""
-    cfg = _cfg()
+@pytest.mark.parametrize("standard_heads", [False, True])
+def test_entity_forward_matches_dense_flax(standard_heads):
+    """Transitively exact vs the dense module too — in the folded form
+    (``head_dim == emb``) and in the head-width form (``head_dim < emb``)."""
+    cfg = _cfg(standard_heads=standard_heads)
     exp = Experiment.build(cfg)
     env, mac = exp.env, exp.mac
     b = cfg.batch_size_run
@@ -108,13 +111,16 @@ def _assert_close_bf16_ulp(actual, desired, max_ulp=32):
         f"{max_ulp} bf16 ULPs (worst {err.max():.1f})")
 
 
-def test_entity_forward_bf16_matches_obs_forward():
+@pytest.mark.parametrize("standard_heads", [True, False])
+def test_entity_forward_bf16_matches_obs_forward(standard_heads):
     """The production bench config (bfloat16 + standard heads + fast_norm)
     runs exactly this path — pin its numerics too. Both forwards compute
     in bf16, so equivalence is asserted in the storage dtype with a
-    per-element ULP bound, not a flat f32 atol (see
-    ``_assert_close_bf16_ulp``)."""
-    cfg = _cfg(standard_heads=True, dtype="bfloat16")
+    per-element ULP bound (32 bf16 ULPs at the tensor's scale), not a flat
+    f32 atol (see ``_assert_close_bf16_ulp``). At ``standard_heads`` the
+    entity path contracts at head width and rounds where the dense module
+    does (q, k, v), the obs path where the fold does (wqk, qp, wvu)."""
+    cfg = _cfg(standard_heads=standard_heads, dtype="bfloat16")
     exp = Experiment.build(cfg)
     env, mac = exp.env, exp.mac
     assert mac.use_entity_tables
@@ -128,6 +134,189 @@ def test_entity_forward_bf16_matches_obs_forward():
     q_ent, h_ent = mac.forward_entity(params, compact, hidden)
     _assert_close_bf16_ulp(q_ent, q_obs)
     _assert_close_bf16_ulp(h_ent, h_obs)
+
+
+@pytest.mark.parametrize("standard_heads", [False, True])
+def test_entity_unroll_gradients_match_obs_unroll(standard_heads):
+    """The learner differentiates the entity forward: a short unroll of
+    ``QMixLearner._unroll_agent`` over compact storage (``forward_entity``)
+    gives the gradient of the obs-path unroll (``forward_qslice``), for
+    every agent parameter, at both head geometries."""
+    cfg = _cfg(standard_heads=standard_heads)
+    exp = Experiment.build(cfg)
+    env, mac, learner = exp.env, exp.mac, exp.learner
+    b, t = cfg.batch_size_run, 3
+    key = jax.random.PRNGKey(5)
+    obs_t, compact_t = [], []
+    for step in range(t):
+        states, obs = _rolled_states(env, b, step, key)
+        obs_t.append(obs)
+        compact_t.append(jax.vmap(env.compact_obs)(states))
+    obs_tm = jnp.stack(obs_t)
+    compact_tm = tuple(jnp.stack(x) for x in zip(*compact_t))
+    params = mac.init_params(key, env.obs_dim)
+
+    def loss(p, **kw):
+        qs, hs = learner._unroll_agent(p, **kw)
+        return (qs ** 2).mean() + (hs ** 2).mean()
+
+    g_obs = jax.grad(lambda p: loss(p, obs_tm=obs_tm))(params)
+    g_ent = jax.grad(lambda p: loss(p, obs_tm=None,
+                                    compact_tm=compact_tm))(params)
+    flat_obs = jax.tree_util.tree_leaves_with_path(g_obs)
+    flat_ent = jax.tree.leaves(g_ent)
+    assert len(flat_obs) == len(flat_ent)
+    for (path, a), e in zip(flat_obs, flat_ent):
+        scale = float(jnp.abs(a).max())
+        assert scale > 0, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(e, a, rtol=2e-3, atol=2e-4 * scale,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("standard_heads", [False, True])
+def test_noisy_entity_head_with_key(standard_heads):
+    """NoisyNet head through the entity forward with a key, at both head
+    geometries: the draw perturbs q off the mu path and leaves the hidden
+    stream alone, the same key gives the same draw, and — the q-head being
+    shared code — the obs-path forward with that key gives the same q."""
+    cfg = sanity_check(_cfg(standard_heads=standard_heads).replace(
+        action_selector="noisy-new"))
+    exp = Experiment.build(cfg)
+    env, mac = exp.env, exp.mac
+    assert mac.use_entity_tables and mac.agent.noisy
+    b = cfg.batch_size_run
+    key = jax.random.PRNGKey(2)
+    states, obs = _rolled_states(env, b, 2, key)
+    compact = jax.vmap(env.compact_obs)(states)
+    params = mac.init_params(key, env.obs_dim)
+    hidden = jax.random.normal(jax.random.fold_in(key, 1),
+                               (b, env.n_agents, cfg.model.emb))
+    k = jax.random.PRNGKey(9)
+    q_mu, h_mu = mac.forward_entity(params, compact, hidden)
+    q_n, h_n = mac.forward_entity(params, compact, hidden, key=k,
+                                  deterministic=False)
+    q_n2, _ = mac.forward_entity(params, compact, hidden, key=k,
+                                 deterministic=False)
+    q_obs, _ = mac.forward_qslice(params, obs, hidden, key=k,
+                                  deterministic=False)
+    np.testing.assert_array_equal(np.asarray(h_n), np.asarray(h_mu))
+    np.testing.assert_array_equal(np.asarray(q_n), np.asarray(q_n2))
+    assert not np.allclose(np.asarray(q_n), np.asarray(q_mu))
+    np.testing.assert_allclose(q_n, q_obs, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("lanes", [8, 16])
+def test_head_groups_agree(monkeypatch, lanes):
+    """The head-width form contracts its heads in groups that fill a lane
+    tile (``_LANES // head_dim`` heads each). At emb 32 x 4 heads
+    (``head_dim`` 8): one head a group, two a group, and all four in one
+    (the default tile) give the same Q-values, and the obs path's."""
+    from t2omca_tpu.ops import query_slice
+    cfg = _cfg(standard_heads=True, emb=32, heads=4, mixer_emb=32)
+    exp = Experiment.build(cfg)
+    env, mac = exp.env, exp.mac
+    b = cfg.batch_size_run
+    key = jax.random.PRNGKey(4)
+    states, obs = _rolled_states(env, b, 2, key)
+    compact = jax.vmap(env.compact_obs)(states)
+    params = mac.init_params(key, env.obs_dim)
+    hidden = jax.random.normal(jax.random.fold_in(key, 1),
+                               (b, env.n_agents, cfg.model.emb))
+    q_one, h_one = mac.forward_entity(params, compact, hidden)
+    monkeypatch.setattr(query_slice, "_LANES", lanes)
+    q_grp, h_grp = mac.forward_entity(params, compact, hidden)
+    q_obs, h_obs = mac.forward_qslice(params, obs, hidden)
+    np.testing.assert_allclose(q_grp, q_one, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(h_grp, h_one, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(q_grp, q_obs, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(h_grp, h_obs, rtol=2e-4, atol=2e-5)
+
+
+def _acting_step_jaxpr(standard_heads, dtype="bfloat16"):
+    # four heads: the (B, 2A, H·D) tables stay under H·E an agent-step
+    cfg = _cfg(standard_heads=standard_heads, dtype=dtype, heads=4)
+    exp = Experiment.build(cfg)
+    env, mac = exp.env, exp.mac
+    b = cfg.batch_size_run
+    key = jax.random.PRNGKey(0)
+    states, _ = _rolled_states(env, b, 0, key)
+    compact = jax.vmap(env.compact_obs)(states)
+    params = mac.prepare_acting_params(mac.init_params(key, env.obs_dim))
+    hidden = mac.init_hidden(b)
+    step = lambda p, c, h: mac.forward_entity(p, c, h, acting=True)
+    closed = jax.make_jaxpr(step)(params, compact, hidden)
+    # the folded kernels among the step's inputs, and whether any op reads them
+    folded = [v for (path, _), v in zip(
+        jax.tree_util.tree_leaves_with_path(params), closed.jaxpr.invars)
+        if any(k in jax.tree_util.keystr(path) for k in ("wqk", "wvu"))]
+    assert len(folded) == 2 * cfg.model.depth
+    read = {id(v) for eqn in closed.jaxpr.eqns for v in eqn.invars}
+    return cfg, closed, [id(v) in read for v in folded]
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_head_width_acting_step_has_no_folded_intermediates():
+    """Structure of one acting step at ``head_dim < emb`` (config 3's
+    geometry): no op reads the fold's ``wqk (E, H·E)`` / ``wvu (H·E, E)``;
+    inside ``agent.attention`` nothing float32 is as large as ``H·E``
+    elements an agent-step; and of the tensors of that size that every op
+    of the folded form made (a dozen a block, float32 among them) only the
+    two the head-width form is built on remain, in the compute dtype: the
+    head-masked query rows (a broadcast and a select) and the context
+    product (one contraction a block). Fails if the folded association, a
+    float32 copy of those two, or a second wide contraction comes back."""
+    cfg, closed, folded_read = _acting_step_jaxpr(standard_heads=True)
+    assert not any(folded_read)
+    m = cfg.model
+    wide = cfg.batch_size_run * cfg.env_args.agv_num * m.heads * m.emb
+    big = [(eqn.primitive.name, v.aval)
+           for eqn in _eqns(closed.jaxpr)
+           if "agent.attention" in str(eqn.source_info.name_stack)
+           for v in eqn.outvars
+           if v.aval.size >= wide and jnp.issubdtype(v.aval.dtype,
+                                                     jnp.floating)]
+    assert big and all(av.dtype == jnp.bfloat16 for _, av in big), big
+    assert sum(name == "dot_general" for name, _ in big) == m.depth, big
+
+
+#: sha256[:16] of the ``head_dim == emb`` lowering under this suite's
+#: conftest, recorded from the parent commit of PR 26 by this same recipe
+#: (JAX 0.9.0; another JAX, or a deliberate change of the folded body,
+#: re-records it)
+FOLDED_LOWERING = "0f57f99f6a96078a"
+
+
+def test_full_width_heads_keep_the_folded_lowering():
+    """At ``head_dim == emb`` (the reference's geometry, configs 1 and 2)
+    the function lowers to exactly what it lowered to before the
+    head-width form existed: the folded kernels feed its contractions and
+    the fingerprint of the lowered module is the recorded one (taken from
+    the parent commit by this same recipe; a deliberate change of the
+    folded body re-records it)."""
+    import hashlib
+    import re
+    _, _, folded_read = _acting_step_jaxpr(standard_heads=False)
+    assert all(folded_read)
+    from t2omca_tpu.ops.query_slice import agent_forward_qslice_entity
+    b, a, e = 3, 5, 16
+    agent = Experiment.build(_cfg()).mac.agent.clone(
+        n_agents=a, n_entities=a, emb=e, heads=2, depth=2, n_actions=4)
+    params = jax.eval_shape(
+        lambda k: agent.init(k, jnp.zeros((1, a, a * 9)),
+                             agent.initial_hidden(1)), jax.random.PRNGKey(0))
+    args = (jnp.zeros((b, a, 8)), jnp.zeros((b, a, a), bool),
+            jnp.zeros((b, a, 9)), jnp.ones((b, a, 9)), jnp.zeros((b, a, e)))
+    fn = lambda p, *xs: agent_forward_qslice_entity(
+        p, *xs, emb=e, heads=2, depth=2, n_actions=4, standard_heads=False,
+        dtype=jnp.float32)
+    text = re.sub(r"loc\(.*?\)", "", jax.jit(fn).lower(params, *args).as_text())
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == FOLDED_LOWERING
 
 
 @pytest.mark.slow   # two rollout compiles (~16 s); numeric equivalence of the paths pinned above
