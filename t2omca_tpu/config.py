@@ -111,6 +111,59 @@ class EnvConfig:
 
 
 @dataclass(frozen=True)
+class TrunkConfig:
+    """A decoder trunk from the public catalog as the transformer agent's
+    token stack (``models/trunk.py``; ``model.trunk`` — absent, the agent
+    is the T2OMCA stack and nothing here is read). The keys carry the
+    names of the model's own published ``config.json``; the last three
+    say which part of each layer THIS chip holds: the deployment divides a
+    layer's experts ``moe_num_primary_experts / experts_held`` ways and
+    its attention ``num_attention_heads / heads_held`` ways (each
+    attention share goes with ``num_key_value_heads`` over that many ways
+    key/value heads), and ``share_index`` is this chip's place in the
+    expert group — expert block ``share_index``, attention share
+    ``share_index`` modulo the attention ways. The router keeps all
+    ``moe_num_primary_experts`` outputs and ``moe_num_active_primary_experts``
+    experts a token at every share. The layer runs without its exchange:
+    the partial sums of this share are what the next layer reads."""
+
+    hidden_size: int = 2560
+    head_dim: int = 128
+    num_attention_heads: int = 28
+    num_key_value_heads: int = 4
+    num_hidden_layers: int = 4
+    moe_ffn_hidden_size: int = 768
+    moe_num_primary_experts: int = 64
+    moe_num_active_primary_experts: int = 6
+    moe_primary_router_apply_softmax: bool = True
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    # per layer, as published: 1 = rotary positions / sliding window,
+    # 0 = no positional encoding / the whole prefix. Layer l reads entry
+    # l; entries past num_hidden_layers belong to layers on other chips
+    rope_layout: Tuple[int, ...] = (0, 1, 1, 1)
+    sliding_window_layout: Tuple[int, ...] = (0, 1, 1, 1)
+    sliding_window_size: int = 4096
+    rope_theta: float = 1_500_000.0
+    experts_held: int = 8
+    heads_held: int = 7
+    share_index: int = 0
+
+    @property
+    def attention_ways(self) -> int:
+        return self.num_attention_heads // self.heads_held
+
+    @property
+    def kv_heads_held(self) -> int:
+        return self.num_key_value_heads // self.attention_ways
+
+    @property
+    def expert_offset(self) -> int:
+        """The first expert this chip holds."""
+        return self.share_index * self.experts_held
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Agent/mixer model flags (SURVEY.md §5.6 'model')."""
 
@@ -166,6 +219,10 @@ class ModelConfig:
     # path keeps model.dtype untouched (f32 parity configs stay
     # bit-identical between acting and learner unroll).
     act_dtype: str = ""
+    # a catalog decoder trunk in place of the T2OMCA stack (TrunkConfig;
+    # emb must equal its hidden_size and depth its num_hidden_layers —
+    # heads / ff_hidden_mult / standard_heads are then the mixer's alone)
+    trunk: Optional[TrunkConfig] = None
 
 
 @dataclass(frozen=True)
@@ -637,7 +694,10 @@ def sanity_check(cfg: TrainConfig) -> TrainConfig:
             "reward-scale remedies; enabling both would double-scale the "
             "train-time reward (running-std AND /reward_unit) — pick one")
     if cfg.model.standard_heads:
-        if cfg.model.emb % cfg.model.heads or cfg.model.mixer_emb % cfg.model.mixer_heads:
+        # a trunk's heads are its own (head_dim is published, not
+        # emb / heads): model.heads is not read by it
+        if ((cfg.model.trunk is None and cfg.model.emb % cfg.model.heads)
+                or cfg.model.mixer_emb % cfg.model.mixer_heads):
             raise ValueError(
                 f"standard_heads requires emb divisible by heads: got "
                 f"emb={cfg.model.emb}/heads={cfg.model.heads}, "
@@ -1000,6 +1060,48 @@ def sanity_check(cfg: TrainConfig) -> TrainConfig:
         raise ValueError(
             f"env_args.scenario.min_agents must be in "
             f"[0, agv_num={cfg.env_args.agv_num}], got {scn.min_agents}")
+    tk = cfg.model.trunk
+    if tk is not None:
+        if cfg.agent != "transformer":
+            raise ValueError("model.trunk is the transformer agent's token "
+                             f"stack; agent={cfg.agent!r} has none")
+        if (cfg.model.emb != tk.hidden_size
+                or cfg.model.depth != tk.num_hidden_layers):
+            raise ValueError(
+                f"model.trunk: emb must equal hidden_size and depth "
+                f"num_hidden_layers (got emb={cfg.model.emb}/"
+                f"{tk.hidden_size}, depth={cfg.model.depth}/"
+                f"{tk.num_hidden_layers})")
+        if (tk.num_attention_heads % tk.heads_held
+                or tk.num_key_value_heads % tk.attention_ways
+                or tk.heads_held % tk.kv_heads_held
+                or tk.moe_num_primary_experts % tk.experts_held):
+            raise ValueError(
+                f"model.trunk: heads_held={tk.heads_held} / experts_held="
+                f"{tk.experts_held} must divide the published counts "
+                f"({tk.num_attention_heads} heads over "
+                f"{tk.num_key_value_heads} key/value heads, "
+                f"{tk.moe_num_primary_experts} experts) evenly")
+        ways = tk.moe_num_primary_experts // tk.experts_held
+        if ways % tk.attention_ways or not 0 <= tk.share_index < ways:
+            raise ValueError(
+                f"model.trunk: share_index={tk.share_index} must lie in "
+                f"[0, {ways}) and the {tk.attention_ways} attention shares "
+                f"must divide the {ways} expert shares")
+        if (len(tk.rope_layout) < tk.num_hidden_layers
+                or len(tk.sliding_window_layout) < tk.num_hidden_layers
+                or tk.head_dim % 2):
+            raise ValueError("model.trunk: rope_layout / "
+                             "sliding_window_layout need an entry per layer "
+                             "and head_dim must be even")
+        if (not tk.moe_primary_router_apply_softmax or not tk.norm_topk_prob
+                or cfg.model.dropout or cfg.action_selector == "noisy-new"
+                or not cfg.env_args.obs_entity_mode
+                or cfg.model.n_entities_obs):
+            raise ValueError(
+                "model.trunk covers the softmax top-k router with "
+                "renormalised weights, entity observations, no dropout "
+                "and no noisy head")
     if cfg.mixer == "transformer" and cfg.model.mixer_emb != cfg.model.emb:
         raise ValueError(
             "mixer_emb must equal emb: the transformer mixer concatenates "
@@ -1038,6 +1140,21 @@ def _coerce_scenario(base: ScenarioConfig, kw: dict) -> ScenarioConfig:
     if "weights" in kw:
         kw["weights"] = tuple(float(w) for w in kw["weights"])
     return dataclasses.replace(base, **kw)
+
+
+def _coerce_trunk(base, kw: dict) -> Optional[TrunkConfig]:
+    """``model.trunk`` in any of its written forms onto the frozen
+    TrunkConfig (lists become tuples); ``None`` with no keys stays
+    ``None``."""
+    if base is None and not kw:
+        return None
+    if isinstance(base, TrunkConfig):
+        base = dataclasses.asdict(base)
+    kw = dict(base or {}, **kw)
+    for k in ("rope_layout", "sliding_window_layout"):
+        if k in kw:
+            kw[k] = tuple(int(v) for v in kw[k])
+    return TrunkConfig(**kw)
 
 
 def _merge_nested(cfg: TrainConfig, updates: dict) -> TrainConfig:
@@ -1111,6 +1228,14 @@ def _merge_nested(cfg: TrainConfig, updates: dict) -> TrainConfig:
                                                   scn_kw)
         updates["env_args"] = dataclasses.replace(cfg.env_args, **env_kw)
     if model_kw:
+        # trunk sub-tree: a nested dict (YAML / JSON round trip), dotted
+        # keys (CLI `model.trunk.share_index=...` arrives here as
+        # "trunk.share_index"), an already-built TrunkConfig, or None
+        trunk_kw = {k.split(".", 1)[1]: model_kw.pop(k)
+                    for k in [k for k in model_kw if k.startswith("trunk.")]}
+        if "trunk" in model_kw or trunk_kw:
+            model_kw["trunk"] = _coerce_trunk(
+                model_kw.get("trunk", cfg.model.trunk), trunk_kw)
         updates["model"] = dataclasses.replace(cfg.model, **model_kw)
     if replay_kw:
         updates["replay"] = dataclasses.replace(cfg.replay, **replay_kw)

@@ -26,6 +26,7 @@ from ..components.schedules import DecayThenFlatSchedule
 from ..config import TrainConfig
 from ..models.agent import TransformerAgent
 from ..models.rnn_agent import RNNAgent
+from ..models.trunk import TrunkAgent
 
 #: agent families (parent PyMARL lineage registry pattern, SURVEY.md §2.3 M7)
 AGENT_REGISTRY = {"transformer": TransformerAgent, "rnn": RNNAgent}
@@ -50,6 +51,10 @@ class BasicMAC:
     # dense-path module clone at act_dtype (None = share `agent`); the
     # qslice/entity forwards take the dtype as an argument instead
     act_agent: object = None
+    # a catalog trunk (config.TrunkConfig; models/trunk.py) in place of
+    # the T2OMCA stack: `agent` is then a TrunkAgent and every forward
+    # below that slices or tables is off (no token is pinned to layer 0)
+    trunk: object = None
 
     @classmethod
     def build(cls, cfg: TrainConfig, env_info: dict) -> "BasicMAC":
@@ -60,22 +65,30 @@ class BasicMAC:
             # flat-obs mode / flat-input agents: the whole obs vector is one
             # entity token
             n_entities, feat = 1, env_info["obs_shape"]
-        agent = AGENT_REGISTRY[cfg.agent](
-            n_agents=n_agents,
-            n_entities=n_entities + 0,
-            feat_dim=feat,
-            emb=cfg.model.emb,
-            heads=cfg.model.heads,
-            depth=cfg.model.depth,
-            n_actions=env_info["n_actions"],
-            ff_hidden_mult=cfg.model.ff_hidden_mult,
-            dropout=cfg.model.dropout,
-            noisy=cfg.action_selector == "noisy-new",
-            standard_heads=cfg.model.standard_heads,
-            use_orthogonal=cfg.model.use_orthogonal,
-            dtype=jnp.dtype(cfg.model.dtype),
-            attn_impl=cfg.kernels.attention,
-        )
+        if cfg.model.trunk is not None:
+            # a catalog trunk as the token stack (models/trunk.py): the
+            # eligibility predicates below all read False for it
+            agent = TrunkAgent(
+                n_agents=n_agents, n_entities=n_entities, feat_dim=feat,
+                emb=cfg.model.emb, n_actions=env_info["n_actions"],
+                trunk=cfg.model.trunk, dtype=jnp.dtype(cfg.model.dtype))
+        else:
+            agent = AGENT_REGISTRY[cfg.agent](
+                n_agents=n_agents,
+                n_entities=n_entities + 0,
+                feat_dim=feat,
+                emb=cfg.model.emb,
+                heads=cfg.model.heads,
+                depth=cfg.model.depth,
+                n_actions=env_info["n_actions"],
+                ff_hidden_mult=cfg.model.ff_hidden_mult,
+                dropout=cfg.model.dropout,
+                noisy=cfg.action_selector == "noisy-new",
+                standard_heads=cfg.model.standard_heads,
+                use_orthogonal=cfg.model.use_orthogonal,
+                dtype=jnp.dtype(cfg.model.dtype),
+                attn_impl=cfg.kernels.attention,
+            )
         schedule = DecayThenFlatSchedule(
             cfg.epsilon_start, cfg.epsilon_finish, cfg.epsilon_anneal_time)
         selector = SELECTOR_REGISTRY[cfg.action_selector](schedule)
@@ -93,7 +106,8 @@ class BasicMAC:
                    use_qslice=use_qslice,
                    use_entity_tables=(use_qslice
                                       and entity_tables_eligible(cfg)),
-                   act_dtype=act_dtype, act_agent=act_agent)
+                   act_dtype=act_dtype, act_agent=act_agent,
+                   trunk=cfg.model.trunk)
 
     # ------------------------------------------------------------------ state
 
@@ -189,6 +203,31 @@ class BasicMAC:
             dtype=self._acting_dtype if acting else a.dtype,
             noise_key=self._noise_key(key, deterministic))
 
+    def trunk_tokens(self, obs, compact=None) -> jnp.ndarray:
+        """The normalised entity tokens ``(B, A, A, 9)`` a catalog trunk
+        reads: rebuilt from the factored observation where there is one
+        (``compact``: the ``env.compact_obs`` tuple or compact storage's),
+        else the dense ``obs (B, A, obs_dim)`` reshaped."""
+        from ..models.trunk import entity_tokens
+        if compact is not None:
+            return entity_tokens(*compact)
+        a = self.agent
+        return obs.reshape(obs.shape[:2] + (a.n_entities, a.feat_dim))
+
+    def forward_trunk(self, params, obs, hidden: jnp.ndarray,
+                      compact=None, acting: bool = False):
+        """The catalog trunk's forward (models/trunk.py) → (q, hidden',
+        aux): every token through every layer (``trunk_tokens`` for its
+        inputs). ``aux`` feeds the ``moe_*`` counters
+        (``models/trunk.moe_counters``). ``params``: the raw tree or a
+        ``prepare_acting_params`` / ``cast_weights`` result."""
+        from ..models.trunk import agent_forward_trunk
+        with jax.named_scope("agent.embed"):
+            tokens = self.trunk_tokens(obs, compact)
+        return agent_forward_trunk(
+            params, tokens, hidden, tk=self.trunk,
+            dtype=self._acting_dtype if acting else self.agent.dtype)
+
     def prepare_acting_params(self, params, dtype=None):
         """Pre-fold the qslice projection products ONCE, outside any scan
         that calls ``select_actions``/``forward_qslice`` in its body (the
@@ -205,6 +244,11 @@ class BasicMAC:
         not the training run's rollout knob)."""
         ad = jnp.dtype(dtype) if dtype is not None else self._acting_dtype
         with jax.named_scope("act.forward"):
+            if self.trunk is not None:
+                # the trunk's matmul weights at the acting dtype, once a
+                # rollout (the router, the norms and the head stay f32)
+                from ..models.trunk import cast_weights
+                return cast_weights(params, ad)
             if not self.use_qslice:
                 return self._cast_acting(params, ad)
             from ..ops.query_slice import fold_agent_params
@@ -233,15 +277,31 @@ class BasicMAC:
                        t_env: jnp.ndarray, test_mode: bool = False,
                        compact=None, eps_scale=None
                        ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """→ (actions ``(B, A)`` int32, hidden', epsilon). The avail mask is
+        """``act`` without its counters → (actions, hidden', epsilon)."""
+        return self.act(params, obs, avail, hidden, key, t_env,
+                        test_mode=test_mode, compact=compact,
+                        eps_scale=eps_scale)[:3]
+
+    def act(self, params, obs: jnp.ndarray, avail: jnp.ndarray,
+            hidden: jnp.ndarray, key: jax.Array, t_env: jnp.ndarray,
+            test_mode: bool = False, compact=None, eps_scale=None):
+        """→ (actions ``(B, A)`` int32, hidden', epsilon, aux). ``aux`` is
+        what the forward counts of itself — a catalog trunk's routed
+        pairs (``forward_trunk``); ``{}`` for every other agent, which
+        adds nothing to a traced program. The avail mask is
         applied inside the selector (illegal-action masking, M7).
         ``compact`` (the batched ``env.compact_obs`` tuple) activates the
         entity-table forward when the MAC was built eligible.
         ``eps_scale`` (optional traced scalar) is the graftpop
         per-member epsilon multiplier, forwarded to the selector."""
         k_noise, k_sel = jax.random.split(key)
+        aux = {}
         with jax.named_scope("act.forward"):
-            if self.use_entity_tables and compact is not None:
+            if self.trunk is not None:
+                q, hidden, aux = self.forward_trunk(params, obs, hidden,
+                                                    compact=compact,
+                                                    acting=True)
+            elif self.use_entity_tables and compact is not None:
                 q, hidden = self.forward_entity(params, compact, hidden,
                                                 key=k_noise,
                                                 deterministic=test_mode,
@@ -258,7 +318,7 @@ class BasicMAC:
         actions, eps = self.selector.select(k_sel, q, avail, t_env,
                                             test_mode=test_mode,
                                             eps_scale=eps_scale)
-        return actions.astype(jnp.int32), hidden, eps
+        return actions.astype(jnp.int32), hidden, eps, aux
 
 
 MAC_REGISTRY = {"basic_mac": BasicMAC}

@@ -270,6 +270,29 @@ class QMixLearner:
                 (obs_tm, keys))
         return qs, hs
 
+    def _unroll_trunk(self, agent_params, obs_tm, compact_tm):
+        """``_unroll_agent`` for a catalog trunk (``model.trunk``,
+        models/trunk.py) → (qs, hiddens, aux) with ``aux`` the routed-pair
+        counts of every step: a scan of the acting forward
+        (``trunk.unroll``) over the entity tokens — rebuilt from
+        ``compact_tm`` where the ring stores the factored form, else
+        ``obs_tm``. The matmul weights are cast to the compute dtype once,
+        outside the scan (rounded inside it, every step reads the float32
+        leaves, and the compiler keeps float32 gradient sums per scan:
+        2 GB more of temporaries at the benchmark's size, PERF.md par.4);
+        the scan then sums its steps' weight gradients in that dtype."""
+        from ..models import trunk
+        mac = self.mac
+        with jax.named_scope("agent.embed"):
+            tokens = (jax.vmap(mac.trunk_tokens)(obs_tm)
+                      if compact_tm is None else
+                      jax.vmap(lambda c: mac.trunk_tokens(None, c))(
+                          compact_tm))
+        return trunk.unroll(
+            trunk.cast_weights(agent_params, mac.agent.dtype), tokens,
+            mac.init_hidden(tokens.shape[1]), tk=mac.trunk,
+            dtype=mac.agent.dtype, wrap=self._scan_body)
+
     def _unroll_mixer(self, mixer_params, q_tm: jnp.ndarray,
                       hid_tm: jnp.ndarray, state_tm: jnp.ndarray,
                       obs_tm: jnp.ndarray,
@@ -360,12 +383,22 @@ class QMixLearner:
         # both into one stacked scan would re-attach the target lane to the
         # VJP (zero cotangents still cost full backward matmuls + 2x scan
         # residual memory), trading a halved forward for a heavier backward
+        trunk = self.mac.trunk
         with jax.named_scope("learner.agent"):
-            qs, hs = self._unroll_agent(params["agent"], obs, k_ag,
-                                        compact_tm=compact_tm)
+            if trunk is not None:
+                qs, hs, moe_aux = self._unroll_trunk(params["agent"], obs,
+                                                     compact_tm)
+            else:
+                qs, hs = self._unroll_agent(params["agent"], obs, k_ag,
+                                            compact_tm=compact_tm)
         with jax.named_scope("learner.target"):
-            target_qs, target_hs = self._unroll_agent(
-                target_params["agent"], obs, k_tag, compact_tm=compact_tm)
+            if trunk is not None:
+                target_qs, target_hs, _ = self._unroll_trunk(
+                    target_params["agent"], obs, compact_tm)
+            else:
+                target_qs, target_hs = self._unroll_agent(
+                    target_params["agent"], obs, k_tag,
+                    compact_tm=compact_tm)
 
         # mixer-side padding mask (graftworld fleet-size randomization,
         # ROADMAP item 3's open remainder): padded agents are
@@ -482,6 +515,14 @@ class QMixLearner:
                 # per-episode priorities (Q9): masked mean |TD| per sample
                 "td_errors_abs": jnp.abs(td).sum(axis=0) / ep_mask,   # (B,)
             }
+            if trunk is not None:
+                # the online unroll's routed pairs (the target's routing
+                # is the last sync's parameters' and is not counted)
+                from ..models.trunk import moe_counters
+                a = self.mac.n_agents
+                info.update(moe_counters(
+                    jax.lax.stop_gradient(moe_aux),
+                    avail.shape[0] * avail.shape[1] * a * (a + 1), trunk))
         if cfg.obs.sight.enabled:
             # graftsight in-graph diagnostics (docs/OBSERVABILITY.md §6):
             # value-scale histograms + one-timestep attention-entropy
@@ -493,7 +534,7 @@ class QMixLearner:
             sg = jax.lax.stop_gradient
             info.update(graftsight.loss_sight_info(
                 cfg.obs.sight, sg(td), sg(chosen), sg(targets), mask))
-            if cfg.agent == "transformer":
+            if graftsight.agent_probe(cfg):
                 info["sight_attn_entropy_agent"] = \
                     graftsight.agent_attention_entropy(
                         self, params["agent"],
@@ -523,6 +564,9 @@ class QMixLearner:
             "td_errors_abs": jnp.zeros((batch_size,), jnp.float32),
             "all_finite": jnp.ones((), bool),
         }
+        if self.mac.trunk is not None:
+            from ..models.trunk import MOE_COUNTERS
+            out.update({k: z for k in MOE_COUNTERS})
         if self.cfg.obs.sight.enabled:
             # graftsight keys are part of the emitted pytree when the
             # static gate is on — the skip branch must mirror them

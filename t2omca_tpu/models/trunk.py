@@ -1,0 +1,416 @@
+"""A catalog decoder trunk as the transformer agent's token stack.
+
+``model.trunk`` (``config.TrunkConfig``) replaces the T2OMCA blocks of
+``TransformerAgent`` with the decoder layers of a public language model at
+their published widths — here written for SmallThinker's layer: RMSNorm
+pre-norm residuals, grouped-query causal attention with a published
+``head_dim``, rotary positions and a sliding window on the layers the
+layouts mark (none and the whole prefix on the others), a softmax top-k
+router that reads the layer's *input*, and ReGLU experts. The language
+model's embedding table and output head have no counterpart here: tokens
+are the agent's ``A`` entity rows (``feat_embedding``) followed by the
+hidden token that carries memory, outputs are ``q_basic`` of that token.
+
+Per agent-step the sequence is ``x = [E(e_1) … E(e_A), h_{t-1}]`` at
+positions ``0 … A``; the hidden token is LAST so that causal attention
+lets it read every entity. For layer ``l`` with input ``h``:
+
+    r = softmax_topk(W_r h)                 float32, before the input norm
+    a = h + W_o GQA(RMSNorm(h))             RoPE + window where the layouts
+                                            say so
+    y = a + sum_{e in topk} r_e W_down,e (relu(W_gate,e m) * W_up,e m),
+                                            m = RMSNorm(a)
+
+and after the last layer ``h_t = RMSNorm(y)[last]`` in float32.
+
+**One chip's share.** The layer is told which experts and heads this chip
+holds (``experts_held``, ``heads_held``, ``share_index``): the router
+scores all experts and keeps its ``k`` a token, the chip computes the
+token-expert pairs whose expert it holds, and ``W_o`` contracts the heads
+it holds. There is no exchange here — the partial sums are what the next
+layer reads, in the program and in its reference alike
+(``benchmark/reference/trunk.py``) — and no code stands in for the absent
+chips. ``tests/test_trunk.py`` ties the shares to the model: over every
+share of a small deployment the partial sums add up to the uncut layer.
+
+**Routing is dropless, and its time does not depend on the routing.**
+Every held expert runs over every token as one wide product, and each
+token's result is weighted by its routing weight for that expert (zero for
+an expert the token did not choose) before the down projection sums the
+experts. All shapes are static and no pair can be left out under any skew.
+A grouped product over the held pairs alone (``jax.lax.ragged_dot``) costs
+less when the load is even, but its time follows the load: the entity
+tokens are few distinct vectors (an entity of another MEC is a masked
+row), an untrained router sends like tokens to the same six experts, and
+how many of those six this chip holds is the seed's draw — the same
+program ran 32 to 59 s a period by seed
+(PERF.md par.6). One path, one cost.
+
+Everything is plain ``jax.numpy`` over the parameter tree (the
+``ops/query_slice.py`` pattern); ``TrunkAgent`` is the flax face that
+declares the tree and serves ``BasicMAC.forward``.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from ..config import TrunkConfig
+
+#: parameter leaves that stay float32 at every compute dtype: the router
+#: (a rounded logit flips the top-k choice), the norms' scales, the Q head
+KEEP_F32 = ("router", "input_norm", "post_norm", "norm", "q_basic")
+
+#: float32 contractions that must not fall to the chip's default
+#: (bfloat16-pass) precision
+_HI = jax.lax.Precision.HIGHEST
+
+
+def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """RMSNorm over the last axis, float32 statistics → float32."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return x32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+
+
+def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary positions over ``x (S, n, H, D)``, positions ``0 … n - 1``:
+    the half-split convention (pairs ``(i, i + D/2)`` rotate together),
+    angles in float32."""
+    n, d = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :d // 2], x32[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def attention_mask(n: int, window: int) -> jnp.ndarray:
+    """``(n, n)`` bool: query ``i`` reads key ``j`` iff ``j <= i`` and,
+    with a window, ``i - j < window`` (``window <= 0``: the whole
+    prefix)."""
+    i, j = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    ok = j <= i
+    return ok & (i - j < window) if window > 0 else ok
+
+
+def attention_part(lp: dict, h: jnp.ndarray, tk: TrunkConfig, layer: int,
+                   dtype) -> jnp.ndarray:
+    """This share's ``W_o · GQA(RMSNorm(h))``: ``h (S, n, d)`` → float32
+    ``(S, n, d)``. Softmax in float32 at every dtype (``n`` is tens)."""
+    s, n, _ = h.shape
+    hq, hkv, d = tk.heads_held, tk.kv_heads_held, tk.head_dim
+    x = rms_norm(h, lp["input_norm"], tk.rms_norm_eps).astype(dtype)
+    proj = lambda w, heads: jnp.dot(                         # noqa: E731
+        x, w.astype(dtype), preferred_element_type=jnp.float32
+    ).astype(dtype).reshape(s, n, heads, d)
+    q, k, v = proj(lp["wq"], hq), proj(lp["wk"], hkv), proj(lp["wv"], hkv)
+    if tk.rope_layout[layer]:
+        q, k = rope(q, tk.rope_theta), rope(k, tk.rope_theta)
+    window = tk.sliding_window_size if tk.sliding_window_layout[layer] else 0
+    q = q.reshape(s, n, hkv, hq // hkv, d)
+    logits = jnp.einsum("sqhgd,skhd->shgqk", q, k,
+                        preferred_element_type=jnp.float32) * d ** -0.5
+    logits = jnp.where(attention_mask(n, window), logits, -jnp.inf)
+    attn = jax.nn.softmax(logits, axis=-1).astype(dtype)
+    out = jnp.einsum("shgqk,skhd->sqhgd", attn, v,
+                     preferred_element_type=jnp.float32)
+    return jnp.dot(out.astype(dtype).reshape(s, n, hq * d),
+                   lp["wo"].astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
+def route(w_router: jnp.ndarray, h: jnp.ndarray, tk: TrunkConfig
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Top-k routing of ``h (N, d)`` (the layer's input, un-normed) over
+    ALL experts, float32 → (weights ``(N, k)``, expert ids ``(N, k)``).
+    Softmax over every expert then renormalised over the kept ones is the
+    softmax over the kept logits."""
+    logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=_HI)
+    top, idx = jax.lax.top_k(logits, tk.moe_num_active_primary_experts)
+    return jax.nn.softmax(top, axis=-1), idx
+
+
+def held_weights(weights: jnp.ndarray, idx: jnp.ndarray, tk: TrunkConfig
+                 ) -> jnp.ndarray:
+    """Routing ``weights, idx (N, k)`` → float32 ``(N, experts_held)``:
+    each token's weight for every expert held here, zero for one it did
+    not choose. A pair whose expert is held elsewhere matches no column."""
+    chosen = ((idx - tk.expert_offset)[:, :, None]
+              == jnp.arange(tk.experts_held))
+    return jnp.where(chosen, weights[:, :, None], 0.0).sum(axis=1)
+
+
+def wide_experts(lp: dict, dtype):
+    """The held experts' kernels as three wide matrices at ``dtype``:
+    gate and up ``(e, d, f)`` → ``(d, e·f)``, down ``(e, f, d)`` →
+    ``(e·f, d)`` — one plain product each over all held experts. The
+    relayout moves every weight, so ``cast_weights`` makes it once outside
+    a scan; kernels that are already wide pass through."""
+    gate, up, down = lp["w_gate"], lp["w_up"], lp["w_down"]
+    if gate.ndim == 3:
+        e, d, f = gate.shape
+        wide = lambda w: jnp.swapaxes(w, 0, 1).reshape(d, e * f)  # noqa
+        gate, up, down = wide(gate), wide(up), down.reshape(e * f, d)
+    return gate.astype(dtype), up.astype(dtype), down.astype(dtype)
+
+
+def experts_part(lp: dict, m: jnp.ndarray, per: jnp.ndarray, dtype
+                 ) -> jnp.ndarray:
+    """This share's ``sum_e r_e W_down,e (relu(W_gate,e m) * W_up,e m)``:
+    ``m (N, d)``, ``per (N, experts_held)`` of ``held_weights`` → float32
+    ``(N, d)``. Every held expert over every token; the routing weight
+    scales the activations, and the down projection contracts experts and
+    expert width together, so the sum over experts is the product's own
+    float32 accumulation."""
+    gate, up, down = wide_experts(lp, dtype)
+    x = m.astype(dtype)
+    n, e = per.shape
+    g = jax.nn.relu(jnp.dot(x, gate, preferred_element_type=dtype))
+    u = jnp.dot(x, up, preferred_element_type=jnp.float32)
+    weight = jnp.broadcast_to(per[:, :, None], (n, e, gate.shape[1] // e))
+    act = (g * u * weight.reshape(n, -1)).astype(dtype)
+    return jnp.dot(act, down, preferred_element_type=jnp.float32)
+
+
+def trunk_layer(lp: dict, h: jnp.ndarray, tk: TrunkConfig, layer: int,
+                dtype):
+    """One decoder layer over ``h (S, n, d)`` → (``y (S, n, d)`` in
+    ``dtype``, aux ``{"load": (experts_held,) pairs that entered the
+    product per held expert, "held": pairs the router sent to an expert
+    id in this share's range}`` — two counts of the same pairs from the
+    expert ids, by the product's own mask and by the range)."""
+    s, n, d = h.shape
+    with jax.named_scope("agent.router"):
+        weights, idx = route(lp["router"], h.reshape(s * n, d), tk)
+        per = held_weights(weights, idx, tk)
+        lo = tk.expert_offset
+        aux = {"load": (per > 0).sum(axis=0).astype(jnp.int32),
+               "held": ((idx >= lo) & (idx < lo + tk.experts_held)
+                        & (weights > 0)).sum().astype(jnp.int32)}
+    with jax.named_scope("agent.attention"):
+        a = (h.astype(jnp.float32)
+             + attention_part(lp, h, tk, layer, dtype)).astype(dtype)
+    with jax.named_scope("agent.experts"):
+        m = rms_norm(a, lp["post_norm"], tk.rms_norm_eps).reshape(s * n, d)
+        y = (a.astype(jnp.float32)
+             + experts_part(lp, m, per, dtype).reshape(s, n, d)).astype(dtype)
+    return y, aux
+
+
+def entity_tokens(rows: jnp.ndarray, same_mec: jnp.ndarray,
+                  mean: jnp.ndarray, std: jnp.ndarray) -> jnp.ndarray:
+    """The normalised entity observation every agent sees, rebuilt from
+    its factored form (``env.compact_obs`` / compact entity storage):
+    ``rows (B, A, 8)``, ``same_mec (B, A, A)``, ``mean/std (B, A, 9)`` →
+    ``(B, A, A, 9)``, observer-major — ``envs/mec_offload._raw_obs``
+    under the shared ``fast_norm`` affine."""
+    a = rows.shape[-2]
+    ent = jnp.where(same_mec[..., None],
+                    rows.astype(jnp.float32)[:, None, :, :], 0.0)
+    is_self = jnp.broadcast_to(jnp.eye(a, dtype=jnp.float32)[..., None],
+                               ent.shape[:-1] + (1,))
+    raw = jnp.concatenate([ent, is_self], axis=-1)
+    return (raw - mean[:, None]) / (std[:, None] + 1e-8)
+
+
+def cast_weights(params: dict, dtype) -> dict:
+    """The matmul weights at the compute dtype, ONCE, outside any scan
+    that runs the forward (every step would otherwise read the float32
+    leaves and round them again), the experts' kernels in their wide
+    layout (``wide_experts``); ``KEEP_F32`` leaves stay float32.
+    Differentiable (casts and a relayout)."""
+    def cast(path, x):
+        names = {getattr(k, "key", None) for k in path}
+        return x if names & set(KEEP_F32) else x.astype(dtype)
+    p = params.get("params", params)
+    out = jax.tree_util.tree_map_with_path(cast, p)
+    layers = {}
+    for name, lp in out["transformer"].items():
+        if isinstance(lp, dict):
+            gate, up, down = wide_experts(lp, dtype)
+            lp = dict(lp, w_gate=gate, w_up=up, w_down=down)
+        layers[name] = lp
+    return dict(out, transformer=layers)
+
+
+def _embed(p: dict, obs: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``obs (B, A, N, F)`` → entity tokens ``(B·A, N, d)``."""
+    b, a, n_ent, f = obs.shape
+    with jax.named_scope("agent.embed"):
+        fe = p["feat_embedding"]
+        return (jnp.dot(obs.reshape(b * a, n_ent, f).astype(dtype),
+                        fe["kernel"].astype(dtype),
+                        preferred_element_type=jnp.float32)
+                + fe["bias"].astype(jnp.float32)).astype(dtype)
+
+
+def _head(p: dict, last: jnp.ndarray, tk: TrunkConfig, shape):
+    """The final norm of the hidden token's output ``(S, d)`` and the Q
+    head, float32 → (q, hidden') shaped ``shape + (·,)``."""
+    with jax.named_scope("agent.head"):
+        h_new = rms_norm(last, p["transformer"]["norm"], tk.rms_norm_eps)
+        qb = p["q_basic"]
+        q = (jnp.dot(h_new, qb["kernel"].astype(jnp.float32))
+             + qb["bias"].astype(jnp.float32))
+    return q.reshape(shape + (-1,)), h_new.reshape(shape + (-1,))
+
+
+def agent_forward_trunk(variables: dict, obs: jnp.ndarray,
+                        hidden: jnp.ndarray, *, tk: TrunkConfig, dtype):
+    """``obs (B, A, A, 9)`` normalised entity tokens, ``hidden (B, A, d)``
+    → (q ``(B, A, n_actions)`` float32, hidden' ``(B, A, d)`` float32,
+    aux) with ``aux`` the layers' (``trunk_layer``) stacked: ``{"load":
+    (layers, experts_held), "held": (layers,)}`` — the sources of the
+    ``moe_*`` counters (``moe_counters``). The one entry: acting calls it
+    a step, the learner scans it (``unroll``)."""
+    p = variables.get("params", variables)
+    b, a = obs.shape[:2]
+    h = jnp.concatenate(
+        [_embed(p, obs, dtype),
+         hidden.reshape(b * a, 1, -1).astype(dtype)], axis=1)
+    auxes = []
+    for layer in range(tk.num_hidden_layers):
+        h, aux = trunk_layer(p["transformer"][f"layer_{layer}"], h, tk,
+                             layer, dtype)
+        auxes.append(aux)
+    aux = jax.tree.map(lambda *x: jnp.stack(x), *auxes)
+    return _head(p, h[:, -1, :], tk, (b, a)) + (aux,)
+
+
+def unroll(variables: dict, obs_tm: jnp.ndarray, hidden: jnp.ndarray, *,
+           tk: TrunkConfig, dtype, wrap=lambda f: f):
+    """The agent over the steps of ``obs_tm (T, B, A, A, 9)``, its hidden
+    token carried from ``hidden`` → (q ``(T, B, A, n_actions)``, hiddens
+    ``(T, B, A, d)``, aux stacked over the steps): a scan of
+    ``agent_forward_trunk``. ``wrap`` wraps the body (``jax.checkpoint``
+    under ``model.remat``)."""
+    def step(h, obs):
+        q, h, aux = agent_forward_trunk(variables, obs, h, tk=tk,
+                                        dtype=dtype)
+        return h, (q, h, aux)
+
+    _, out = jax.lax.scan(wrap(step), hidden, obs_tm)
+    return out
+
+
+def moe_counters(aux: dict, tokens: int, tk: TrunkConfig) -> dict:
+    """The four counters of the training info rows and the rollout stats
+    from the ``aux`` of the forwards they cover, stacked over any leading
+    axes (a scan's steps) and summed here (``tokens``: the tokens those
+    forwards routed, a static count). ``moe_load_max``: per layer the
+    busiest held expert's pairs, summed over the layers, so that
+    ``moe_load_max / moe_pairs_held`` is ``1 / experts_held`` under an
+    even load. ``moe_dropped``: pairs the router sent to this share's
+    range of expert ids less pairs that entered the product with their
+    weight (``trunk_layer``'s two counts) — 0 unless the product's mask
+    leaves a held pair out."""
+    load = aux["load"].astype(jnp.float32)
+    load = load.reshape((-1,) + load.shape[-2:]).sum(axis=0)
+    return {
+        "moe_pairs_held": load.sum(),
+        "moe_pairs_routed": jnp.asarray(
+            float(tokens) * tk.moe_num_active_primary_experts
+            * tk.num_hidden_layers, jnp.float32),
+        "moe_load_max": load.max(axis=-1).sum(),
+        "moe_dropped": aux["held"].astype(jnp.float32).sum() - load.sum(),
+    }
+
+
+MOE_COUNTERS = ("moe_pairs_held", "moe_pairs_routed", "moe_load_max",
+                "moe_dropped")
+
+
+class _Dense(nn.Module):
+    """``{"kernel", "bias"}`` of an ``nn.Dense``, declared and handed
+    back (the forward is ``agent_forward_trunk``'s)."""
+    inputs: int
+    features: int
+
+    @nn.compact
+    def __call__(self) -> dict:
+        return {"kernel": self.param("kernel", nn.initializers.lecun_normal(),
+                                     (self.inputs, self.features)),
+                "bias": self.param("bias", nn.initializers.zeros,
+                                   (self.features,))}
+
+
+class _Layer(nn.Module):
+    """One layer's parameters: this chip's share."""
+    trunk: TrunkConfig
+
+    @nn.compact
+    def __call__(self) -> dict:
+        tk = self.trunk
+        d, f, e = tk.hidden_size, tk.moe_ffn_hidden_size, tk.experts_held
+        hq, hkv = tk.heads_held * tk.head_dim, tk.kv_heads_held * tk.head_dim
+        init = nn.initializers.lecun_normal()
+        per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
+        ones = nn.initializers.ones
+        return {
+            "input_norm": self.param("input_norm", ones, (d,)),
+            "wq": self.param("wq", init, (d, hq)),
+            "wk": self.param("wk", init, (d, hkv)),
+            "wv": self.param("wv", init, (d, hkv)),
+            "wo": self.param("wo", init, (hq, d)),
+            "router": self.param("router", init,
+                                 (d, tk.moe_num_primary_experts)),
+            "post_norm": self.param("post_norm", ones, (d,)),
+            "w_gate": self.param("w_gate", per_expert, (e, d, f)),
+            "w_up": self.param("w_up", per_expert, (e, d, f)),
+            "w_down": self.param("w_down", per_expert, (e, f, d)),
+        }
+
+
+class _Stack(nn.Module):
+    trunk: TrunkConfig
+
+    @nn.compact
+    def __call__(self) -> dict:
+        tk = self.trunk
+        out = {f"layer_{i}": _Layer(tk, name=f"layer_{i}")()
+               for i in range(tk.num_hidden_layers)}
+        out["norm"] = self.param("norm", nn.initializers.ones,
+                                 (tk.hidden_size,))
+        return out
+
+
+class TrunkAgent(nn.Module):
+    """The flax face of ``agent_forward_trunk``: declares the parameter
+    tree (this chip's share of every layer, under ``transformer`` as the
+    T2OMCA stack is) and serves the dense-obs ``BasicMAC.forward``
+    contract of ``TransformerAgent``."""
+
+    n_agents: int
+    n_entities: int
+    feat_dim: int
+    emb: int
+    n_actions: int
+    trunk: TrunkConfig
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, inputs: jax.Array, hidden_state: jax.Array,
+                 deterministic: bool = True) -> Tuple[jax.Array, jax.Array]:
+        b, a, _ = inputs.shape
+        tree = {
+            "feat_embedding": _Dense(self.feat_dim, self.emb,
+                                     name="feat_embedding")(),
+            "transformer": _Stack(self.trunk, name="transformer")(),
+            "q_basic": _Dense(self.emb, self.n_actions, name="q_basic")(),
+        }
+        q, h, _ = agent_forward_trunk(
+            tree, inputs.reshape(b, a, self.n_entities, self.feat_dim),
+            hidden_state, tk=self.trunk, dtype=self.dtype)
+        return q, h
+
+    def initial_hidden(self, batch_size: int) -> jax.Array:
+        return jnp.zeros((batch_size, self.n_agents, self.emb))

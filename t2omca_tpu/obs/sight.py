@@ -62,6 +62,13 @@ def enabled(cfg) -> bool:
     return bool(cfg.obs.sight.enabled)
 
 
+def agent_probe(cfg) -> bool:
+    """The agent-side attention-entropy probe reads the T2OMCA stack's
+    folded blocks (``attention_entropies``): transformer agents without a
+    catalog trunk (``model.trunk``), whose layers it does not describe."""
+    return cfg.agent == "transformer" and cfg.model.trunk is None
+
+
 def module_group_names(cfg) -> Tuple[str, ...]:
     """Static per-config grouping of the param tree for the per-module
     norm breakdown: the agent transformer stack, everything else in the
@@ -366,7 +373,7 @@ def train_info_extras_zeros(cfg) -> dict:
     info["sight_target_drift"] = z
     for k in ("sight_td_hist", "sight_q_taken_hist", "sight_target_hist"):
         info[k] = jnp.zeros((sg.bins,), jnp.float32)
-    if cfg.agent == "transformer":
+    if agent_probe(cfg):
         info["sight_attn_entropy_agent"] = jnp.zeros((cfg.model.depth,),
                                                      jnp.float32)
     if cfg.mixer == "transformer":

@@ -148,6 +148,9 @@ KNOWN_SCOPES = frozenset({
     # the model (models/, ops/query_slice.py), under act.forward and
     # under learner.agent / learner.mixer / learner.target alike
     "agent.embed", "agent.attention", "agent.ff", "agent.head",
+    # a catalog trunk's layers (models/trunk.py): the router product,
+    # top-k and the held experts' weights a token; the experts' products
+    "agent.router", "agent.experts",
     # replay ring (components/episode_buffer.py)
     "replay.insert", "replay.sample", "replay.priority",
     # learner (learners/qmix_learner.py)

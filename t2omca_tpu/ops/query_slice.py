@@ -90,33 +90,54 @@ def agent_qslice_eligible(cfg) -> bool:
     ``QMixLearner`` (both acting and learner unrolls share it)."""
     return (cfg.model.use_qslice
             and cfg.agent == "transformer"
+            # a catalog trunk (models/trunk.py) pins nothing to layer 0
+            # and consumes every token: there is no slice to take
+            and cfg.model.trunk is None
             and cfg.model.dropout == 0.0)
+
+
+def entity_obs_factored(cfg) -> bool:
+    """The factored form ``env.compact_obs`` gives (feature rows, MEC
+    ids, shared statistics) reconstructs every agent's normalised obs
+    exactly: the entity observation mode (the factored structure IS the
+    entity obs), the batched normalizer (the sequential one gives each
+    observer different prefix statistics), and no entity-count override
+    (the rows are the env's own agents)."""
+    return (cfg.env_args.obs_entity_mode
+            and cfg.env_args.fast_norm
+            and cfg.model.n_entities_obs == 0)
+
+
+def trunk_compact_eligible(cfg) -> bool:
+    """A catalog trunk reads the factored observation: its MAC and its
+    learner's unroll rebuild the entity tokens from the compact rows
+    (``models/trunk.entity_tokens``) and run the dense token path — the
+    compact ring layout without the sliced forward."""
+    return (cfg.model.trunk is not None and cfg.agent == "transformer"
+            and entity_obs_factored(cfg))
 
 
 def entity_tables_eligible(cfg) -> bool:
     """Entity-table eligibility: needs the ``use_entity_tables`` kill
     switch on (it covers BOTH acting and the learner's compact-storage
-    unroll), the qslice agent path, the entity observation mode (the
-    factored structure IS the entity obs), the batched normalizer (the
-    sequential one gives each observer different prefix statistics), and
-    no entity-count override (tables are derived from the env's own
-    agents)."""
+    unroll), the qslice agent path, and the factored observation
+    (``entity_obs_factored``)."""
     return (cfg.model.use_entity_tables
             and agent_qslice_eligible(cfg)
-            and cfg.env_args.obs_entity_mode
-            and cfg.env_args.fast_norm
-            and cfg.model.n_entities_obs == 0)
+            and entity_obs_factored(cfg))
 
 
 def entity_store_eligible(cfg) -> bool:
-    """Compact entity episode STORAGE eligibility: on top of the acting
-    eligibility, the learner must be able to unroll through the entity
-    forward (deterministic transformer — already implied) and the mixer
-    must not consume stored obs (Q12 fallback needs the full tensor), and
-    the host-RAM buffer keeps the plain layout (its escape-hatch use case
-    predates the 20× shrink)."""
+    """Compact entity episode STORAGE eligibility: the learner must be
+    able to unroll from the factored rows — through the entity-table
+    forward (the sliced T2OMCA agent, ``entity_tables_eligible``) or by
+    rebuilding the tokens for the dense path (a catalog trunk,
+    ``trunk_compact_eligible``); storage does not imply the sliced
+    forward — the mixer must not consume stored obs (Q12 fallback needs
+    the full tensor), and the host-RAM buffer keeps the plain layout (its
+    escape-hatch use case predates the 20× shrink)."""
     return (cfg.replay.compact_entity_store
-            and entity_tables_eligible(cfg)
+            and (entity_tables_eligible(cfg) or trunk_compact_eligible(cfg))
             and cfg.env_args.state_entity_mode
             and not cfg.replay.buffer_cpu_only
             # the stored mec_index narrows to int8

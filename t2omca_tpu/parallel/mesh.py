@@ -269,12 +269,17 @@ AUDIT_MESH_DEVICES = 2
 #: ff_hidden_mult*emb hidden) shard over ``model``; "embed" stays
 #: replicated (it is every block's residual/LayerNorm axis — splitting
 #: it would put a collective inside every residual add); "batch"
-#: follows the data axis like every env-lane tensor.
+#: follows the data axis like every env-lane tensor. "expert" is the
+#: leading axis of a catalog trunk's expert weights (models/trunk.py
+#: ``w_gate`` / ``w_up`` / ``w_down``): declared here with every other
+#: axis, over ``model``; today one chip holds its share of it
+#: (``TrunkConfig.experts_held``) and no program shards it.
 LOGICAL_AXIS_RULES = (
     ("batch", "data"),
     ("heads", "model"),
     ("joined_kv", "model"),
     ("mlp", "model"),
+    ("expert", "model"),
     ("embed", None),
     ("tokens", None),
     ("kv", None),

@@ -1932,6 +1932,12 @@ def run_sequential(exp: Experiment, logger: Logger,
                                                         float(v[m]), t_env)
                             else:
                                 logger.log_stat(k, float(last[k]), t_env)
+                        # a catalog trunk's routing counters of the last
+                        # update (models/trunk.moe_counters), same fetch
+                        for k in sorted(last):
+                            if k.startswith("moe_"):
+                                logger.log_stat(
+                                    k, float(np.mean(last[k])), t_env)
                         if sight_mon is not None:
                             # graftsight detector pass over the SAME fetched
                             # info (no extra device traffic; the monitor

@@ -100,6 +100,10 @@ class RolloutStats:
     # episode ran under — the stats accumulators group the terminal-info
     # aggregation by it (per-slice generalization eval, utils/stats.py)
     scenario: jnp.ndarray                  # (B,) int32
+    # a catalog trunk's routing counters over the whole rollout
+    # (models/trunk.moe_counters: moe_pairs_held / _routed / _load_max /
+    # _dropped, scalars); empty — no leaves — for every other agent
+    moe: Dict[str, jnp.ndarray] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,6 +171,18 @@ class ParallelRunner:
             rscale=RewardScaleState.create(gamma=self.cfg.gamma,
                                            dim=self.batch_size),
             env_params=env_params)
+
+    def _moe_counters(self, aux_seq, env_steps: int) -> dict:
+        """The rollout's ``moe_*`` counters from the per-step ``aux`` of
+        ``BasicMAC.act`` (a catalog trunk's routed pairs, stacked over the
+        scan); ``{}`` where the agent routes nothing."""
+        if not aux_seq:
+            return {}
+        from ..models.trunk import moe_counters
+        a = self.mac.n_agents
+        with jax.named_scope("act.forward"):
+            return moe_counters(aux_seq, env_steps * a * (a + 1),
+                                self.mac.trunk)
 
     # ------------------------------------------------------------------ rollout
 
@@ -260,7 +276,7 @@ class ParallelRunner:
                                                           env_params)
                            if self.mac.use_entity_tables or compact_store
                            else None)
-            actions, hidden, eps = self.mac.select_actions(
+            actions, hidden, eps, aux = self.mac.act(
                 params, obs, avail, hidden, k_act, t_env,
                 test_mode=test_mode, compact=compact, eps_scale=eps_scale)
             # Q15: the action is recorded with the pre-step observation.
@@ -285,7 +301,7 @@ class ParallelRunner:
                     rec_reward = reward
             env_terminal = terminated & ~info.episode_limit        # Q7
             ys = (pre, reward, rec_reward, env_terminal, info, eps,
-                  (viz + (env_states.last_ack,)) if capture else ())
+                  (viz + (env_states.last_ack,)) if capture else (), aux)
             t_env = t_env + jnp.where(jnp.asarray(test_mode), 0, b)
             return (env_states, obs, gstate, avail, hidden, t_env,
                     rscale), ys
@@ -293,7 +309,8 @@ class ParallelRunner:
         carry = (env_states, obs, gstate, avail, hidden, rs.t_env, rscale0)
         carry, ys = jax.lax.scan(step_fn, carry, jax.random.split(k_scan, t_len))
         env_states, last_obs, last_gstate, last_avail, _, t_env, rscale = carry
-        (pre, reward, rec_reward, env_terminal, info, eps, viz_seq) = ys
+        (pre, reward, rec_reward, env_terminal, info, eps, viz_seq,
+         aux_seq) = ys
         obs_seq, gstate_seq, avail_seq, action_seq = pre
 
         with jax.named_scope("env.obs"):
@@ -329,6 +346,7 @@ class ParallelRunner:
             deadline_miss_rate=last(info.deadline_miss_rate),
             epsilon=eps[-1],
             scenario=env_params.family,
+            moe=self._moe_counters(aux_seq, t_len * b),
         )
         new_rs = RunnerState(env_states=env_states, key=key, t_env=t_env,
                              rscale=rscale if scale_on else rs.rscale,

@@ -92,6 +92,13 @@ def load_acting_params(cfg: TrainConfig, ckpt_dir: str, load_step: int = 0):
     agent parameters restored host-side (``restore_host_state`` — no
     device-resident replay ring), shape-validated against the config's
     own init, and pre-folded for acting."""
+    if cfg.model.trunk is not None:
+        # the artifact's program (serve/program.py) is the T2OMCA sliced
+        # or dense forward; a catalog trunk's share is trained, not served
+        raise ValueError(
+            "serve/export does not export a model.trunk agent "
+            "(models/trunk.py): the serving program has no forward for a "
+            "catalog trunk's share of a layer yet — ROADMAP B7")
     found = find_checkpoint(ckpt_dir, load_step)
     if found is None:
         raise FileNotFoundError(

@@ -128,6 +128,11 @@ class StatsAccumulator:
                 v = getattr(s, k, None)
                 if v is not None:
                     self._stats[k] += float(np.sum(v))
+            # a catalog trunk's routing counters (RolloutStats.moe: one
+            # scalar a rollout, absent for every other agent) sum like
+            # the terminal-info keys and flush as ``<k>_mean`` per episode
+            for k, v in (getattr(s, "moe", None) or {}).items():
+                self._stats[k] += float(np.sum(v))
             if self.population:
                 # per-member aggregation off the SAME fetched arrays:
                 # leaf layout (P, ...) — member i is row i

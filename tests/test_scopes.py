@@ -33,8 +33,12 @@ TRAIN = {"replay.sample", "replay.priority", "learner.agent",
          "learner.mixer", "learner.target", "learner.loss",
          "learner.optimizer", "sight", "agent.embed", "agent.attention",
          "agent.ff", "agent.head"}
+#: opened by a catalog trunk's layers alone (models/trunk.py), which in
+#: turn have no ``agent.ff``
+TRUNK_ONLY = {"agent.router", "agent.experts"}
 CARRIES = {"_rollout": ROLLOUT, "_insert": {"replay.insert"},
-           "_train_iter": TRAIN, "_superstep": set(KNOWN_SCOPES)}
+           "_train_iter": TRAIN,
+           "_superstep": set(KNOWN_SCOPES) - TRUNK_ONLY}
 
 
 def tiny(**kw):
@@ -54,6 +58,22 @@ def tiny(**kw):
                  "sight": {"enabled": True}}}
     d.update(kw)
     return sanity_check(from_dict(d))
+
+
+def tiny_trunk():
+    """``tiny`` with a catalog trunk as the agent's stack."""
+    model = {"emb": 16, "depth": 2, "mixer_emb": 16, "mixer_heads": 2,
+             "mixer_depth": 2, "standard_heads": True, "remat": True,
+             "trunk": {"hidden_size": 16, "head_dim": 4,
+                       "num_attention_heads": 4, "num_key_value_heads": 2,
+                       "num_hidden_layers": 2, "moe_ffn_hidden_size": 8,
+                       "moe_num_primary_experts": 4,
+                       "moe_num_active_primary_experts": 2,
+                       "rope_layout": [0, 1],
+                       "sliding_window_layout": [0, 1],
+                       "sliding_window_size": 2, "experts_held": 2,
+                       "heads_held": 2}}
+    return tiny(model=model)
 
 
 def token(scope: str):
@@ -101,11 +121,11 @@ def test_every_member_of_the_vocabulary_is_opened_somewhere():
 
 # ---------------------------------------------------- (b), (c) programs
 
-def _lowered_texts():
+def _lowered_texts(cfg=None):
     """The four driver programs of the tiny configuration, lowered from
     shapes → {program: (text with debug info, text without)}."""
     from t2omca_tpu.run import Experiment
-    cfg = tiny()
+    cfg = cfg or tiny()
     exp = Experiment.build(cfg)
     ts = jax.eval_shape(lambda: exp.init_train_state(0))
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
@@ -153,6 +173,37 @@ def test_program_carries_its_scopes(texts, program):
         assert "checkpoint" in debug or "remat" in debug
     if program in ("_rollout", "_insert"):
         assert not token("learner.optimizer").search(debug)
+    # the T2OMCA stack opens none of a catalog trunk's scopes
+    assert not any(token(s).search(debug) for s in TRUNK_ONLY)
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("_rollout", (ROLLOUT | TRUNK_ONLY) - {"agent.ff"}),
+    ("_train_iter", TRAIN | TRUNK_ONLY),
+    ("_superstep", set(KNOWN_SCOPES))],
+    ids=["_rollout", "_train_iter", "_superstep"])
+def test_trunk_program_carries_the_trunk_scopes(program, scopes):
+    """A ``model.trunk`` configuration opens ``agent.router`` and
+    ``agent.experts`` beside the other ``agent.*`` — under ``act.forward``
+    and under the learner's scopes (``checkpoint`` bodies, forward and
+    backward) — and no ``agent.ff`` outside the mixer's blocks (the
+    rollout has no mixer)."""
+    debug, _ = _trunk_texts()[program]
+    assert {s for s in scopes if not token(s).search(debug)} == set()
+    if program == "_rollout":
+        assert not token("agent.ff").search(debug)
+    else:
+        assert re.search(r"transpose\(jvp\(learner\.agent\)\)", debug)
+        assert re.search(r"rematted_computation/agent\.experts", debug)
+
+
+_TRUNK_TEXTS = []
+
+
+def _trunk_texts():
+    if not _TRUNK_TEXTS:
+        _TRUNK_TEXTS.append(_lowered_texts(tiny_trunk()))
+    return _TRUNK_TEXTS[0]
 
 
 @pytest.mark.parametrize("program", sorted(CARRIES))
