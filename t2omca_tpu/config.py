@@ -518,7 +518,13 @@ class KernelsConfig:
     docs/PERF.md). Every entry keeps the XLA lowering as the default
     with CPU-gate parity tests pinning the hand-written kernel against
     it, so flipping a switch is a performance decision, never a
-    semantics one."""
+    semantics one.
+
+    Not governed from here: acting's entity-table attention kernel
+    (``kernels/entity_attention.py``). It engages by itself — from the
+    head-width kernels' shapes, the lowering platform (TPU) and the env
+    lanes sitting on one device — and no key selects it
+    (``ops/query_slice.agent_forward_qslice_entity``, docs/PERF.md §1)."""
 
     # attention kernel for MultiHeadAttention (per-agent transformer AND
     # the mixer): "xla" = the einsum→softmax→einsum path (materializes

@@ -41,6 +41,13 @@ class BasicMAC:
     emb: int
     use_qslice: bool = False    # exact token-0-only forward (ops/query_slice)
     use_entity_tables: bool = False   # table-contracted entity acting
+    # acting's entity-table attention may run as the Pallas kernel
+    # (kernels/entity_attention.py; engaged from shapes and platform, see
+    # agent_forward_qslice_entity). Off where the env lanes of one program
+    # span devices or members: the kernel is one custom call, which GSPMD
+    # cannot partition (dp_devices, sebulba), and under a population's vmap
+    # it has never run on the chip — those keep the XLA association
+    entity_kernel: bool = True
     # acting-path compute dtype (model.act_dtype, docs/PERF.md): None =
     # inherit the agent's (train) dtype — byte-identical to pre-act_dtype
     # builds. When it differs, select_actions runs its forwards in this
@@ -106,6 +113,9 @@ class BasicMAC:
                    use_qslice=use_qslice,
                    use_entity_tables=(use_qslice
                                       and entity_tables_eligible(cfg)),
+                   entity_kernel=not (cfg.dp_devices > 1
+                                      or cfg.sebulba.actor_devices > 1
+                                      or cfg.population.size > 0),
                    act_dtype=act_dtype, act_agent=act_agent,
                    trunk=cfg.model.trunk)
 
@@ -201,7 +211,35 @@ class BasicMAC:
             emb=a.emb, heads=a.heads, depth=a.depth, n_actions=a.n_actions,
             standard_heads=a.standard_heads,
             dtype=self._acting_dtype if acting else a.dtype,
-            noise_key=self._noise_key(key, deterministic))
+            noise_key=self._noise_key(key, deterministic),
+            kernel=acting and self.entity_kernel)
+
+    def describe_acting(self, lanes: int, platform: str) -> str:
+        """What ``act`` compiles for ``lanes`` envs on ``platform``, for
+        the run's start-up log: its forward (trunk / entity tables /
+        qslice / obs — the order ``act`` tries them in) and that forward's
+        attention (``kernel``: acting's entity kernel, engaged from the
+        same shapes ``agent_forward_qslice_entity`` reads; ``pallas``: the
+        flash kernel of the dense module under ``kernels.attention``;
+        ``xla`` otherwise)."""
+        a = self.agent
+        attn = "xla"
+        if self.trunk is not None:
+            fwd = "trunk"
+        elif self.use_entity_tables:
+            fwd = "entity tables"
+            from ..kernels import entity_attention as ek
+            if (self.entity_kernel and platform == "tpu"
+                    and a.standard_heads and a.heads > 1
+                    and ek.eligible(lanes, self.n_agents, a.emb, a.emb,
+                                    a.heads)):
+                attn = "kernel"
+        elif self.use_qslice:
+            fwd = "qslice"
+        else:
+            fwd = "obs"
+            attn = getattr(a, "attn_impl", "none")
+        return f"acting forward: {fwd}, attention: {attn}"
 
     def trunk_tokens(self, obs, compact=None) -> jnp.ndarray:
         """The normalised entity tokens ``(B, A, A, 9)`` a catalog trunk

@@ -618,7 +618,8 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
                                 *, emb: int, heads: int, depth: int,
                                 n_actions: int, standard_heads: bool = False,
                                 dtype=jnp.float32,
-                                noise_key: jnp.ndarray | None = None
+                                noise_key: jnp.ndarray | None = None,
+                                kernel: bool = False
                                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Entity-table forward (acting, and the learner's compact-storage
     unroll): ``agent_forward_qslice`` without ever materializing per-agent
@@ -648,6 +649,16 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
     shapes of the parameters (``fold_agent_params`` gives the ``"ent"``
     kernels exactly when ``head_dim < emb``) — no option selects it.
 
+    ``kernel=True`` is acting's call (``BasicMAC.forward_entity``, forward
+    only, one device): its head-width blocks run as ONE Pallas kernel each
+    (``kernels/entity_attention.py``: projections, logits, softmax and
+    context of an env stay in VMEM) where the shapes are the kernel's
+    (``entity_attention.eligible``) and the program is lowered for a TPU.
+    The learner's unrolls — the online one is differentiated, and the
+    kernel has no backward pass — and every other platform and shape run
+    the contractions below, unchanged. ``kernels.attention`` (the flash
+    kernel of ``MultiHeadAttention``) has no say in it.
+
     Inputs per ``MultiAgvOffloadingEnv.compact_obs``: ``rows (B, A, 8)``,
     ``same_mec (B, A, A)`` bool, ``mean/std (B, A, 9)``; ``hidden_state
     (B, A, emb)``. Requires ``obs_entity_mode`` + ``fast_norm`` and no
@@ -660,6 +671,11 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
     # one association of the same sum per head geometry, known from the
     # shapes alone (fold_agent_params; docstring)
     head_width = "ent" in f
+    use_kernel = False
+    if kernel and head_width:
+        from ..kernels import entity_attention as ek
+        hd = f["ent"][0]["wq"].shape[1]
+        use_kernel = ek.eligible(b, a, emb, hd, heads)
     with jax.named_scope("agent.embed"):
         # ---- per-env embedding tables (feat 8 = is_self; _raw_obs layout)
         denom = std.astype(jnp.float32) + 1e-8                    # (B, A, 9)
@@ -673,6 +689,11 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
             inv_self = 1.0 / denom[..., 8:9]                      # (B, A, 1)
             sees = jnp.swapaxes(same_mec, 1, 2)                   # (B, j, a)
             seen = jnp.concatenate([sees, ~sees], axis=1)         # (B, 2A, A)
+            if use_kernel:
+                # the same tables and visibility in the kernel's layout;
+                # dead (and removed) on the platforms that run the XLA form
+                k_tables = ek.tables(feats, inv_self, seen, dtype,
+                                     ek.group(hd, heads))
         else:
             we = f["fe"]["kernel"].astype(dtype)                  # (9, E)
             be = f["fe"]["bias"].astype(jnp.float32)
@@ -692,9 +713,18 @@ def agent_forward_qslice_entity(variables: dict, rows: jnp.ndarray,
         bp = f["tf"]["blocks"][i]
         with jax.named_scope("agent.attention"):
             if head_width:
-                attended = _entity_attention_heads(
-                    f["ent"][i], x0, h_tok, feats, inv_self, seen,
-                    heads=heads, dtype=dtype)
+                xla = lambda x, hp=f["ent"][i]: _entity_attention_heads(
+                    hp, x, h_tok, feats, inv_self, seen, heads=heads,
+                    dtype=dtype)
+                if not use_kernel:
+                    attended = xla(x0)
+                else:
+                    one = lambda x, hp=f["ent"][i]: ek.entity_attention(
+                        hp, x, h_tok, *k_tables, heads=heads,
+                        interpret=ek.INTERPRET)
+                    attended = (one(x0) if ek.INTERPRET
+                                else jax.lax.platform_dependent(
+                                    x0, tpu=one, default=xla))
             else:
                 attended = _entity_attention_folded(
                     bp, x0, h_tok, e_vis, e_hid, self_corr, vis, eye,

@@ -1102,6 +1102,8 @@ def run_sequential(exp: Experiment, logger: Logger,
                  "keeps the three-program path)")
     elif K > 1 and not P:
         log.info(f"fused superstep: {K} iterations per dispatch")
+    log.info(exp.mac.describe_acting(cfg.batch_size_run,
+                                     jax.default_backend()))
     # per-member driver key streams under a population (each member's
     # stream splits exactly like the classic loop's single one)
     key = graftpop.member_keys(cfg) if P else jax.random.PRNGKey(

@@ -319,6 +319,177 @@ def test_full_width_heads_keep_the_folded_lowering():
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == FOLDED_LOWERING
 
 
+#: as ``FOLDED_LOWERING``: the gradient of the HEAD-WIDTH forward (the
+#: learner's differentiated unroll runs it, ``kernel`` left False), from
+#: the parent commit of PR 28
+HEAD_WIDTH_GRAD_LOWERING = "407055a471c3cbc8"
+
+
+def _kernel_shapes_agent(b=3, a=16, e=128, heads=4):
+    """An agent at shapes acting's entity kernel engages for (16 agents on
+    the sublane quantum, four heads of 32 = one 128-lane group), its
+    abstract parameters and the entity forward's zero inputs."""
+    agent = Experiment.build(_cfg()).mac.agent.clone(
+        n_agents=a, n_entities=a, emb=e, heads=heads, depth=2, n_actions=4,
+        standard_heads=True)
+    params = jax.eval_shape(
+        lambda k: agent.init(k, jnp.zeros((1, a, a * 9)),
+                             agent.initial_hidden(1)), jax.random.PRNGKey(0))
+    args = (jnp.zeros((b, a, 8)), jnp.zeros((b, a, a), bool),
+            jnp.zeros((b, a, 9)), jnp.ones((b, a, 9)), jnp.zeros((b, a, e)))
+    return agent, params, args
+
+
+def test_learner_unroll_keeps_the_head_width_lowering():
+    """The learner differentiates the head-width forward and acting's
+    kernel has no backward pass, so the two have their own paths: with
+    ``kernel`` left False the gradient of ``agent_forward_qslice_entity``
+    lowers to exactly what it lowered to on the parent commit of PR 28 —
+    at shapes where acting's call takes the kernel."""
+    import hashlib
+    import re
+    from t2omca_tpu.kernels import entity_attention as ek
+    from t2omca_tpu.ops.query_slice import agent_forward_qslice_entity
+    agent, params, args = _kernel_shapes_agent()
+    b, a, e = args[-1].shape
+    assert ek.eligible(b, a, e, e, 4)
+
+    def loss(p, *xs):
+        q, h = agent_forward_qslice_entity(
+            p, *xs, emb=e, heads=4, depth=2, n_actions=4,
+            standard_heads=True, dtype=jnp.bfloat16)
+        return (q ** 2).sum() + (h ** 2).sum()
+    text = re.sub(r"loc\(.*?\)", "",
+                  jax.jit(jax.grad(loss)).lower(params, *args).as_text())
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
+            == HEAD_WIDTH_GRAD_LOWERING)
+
+
+def _float_tensors(stablehlo: str):
+    """Shapes of every float32 / bfloat16 tensor type in a lowered
+    module's text."""
+    import re
+    return {tuple(int(n) for n in dims[:-1].split("x"))
+            for dims in re.findall(r"tensor<((?:\d+x)+)(?:f32|bf16)>",
+                                   stablehlo)}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_acting_tpu_lowering_keeps_the_logits_inside_the_kernel(dtype):
+    """Acting's step lowered FOR A TPU (from here, no chip): each block's
+    attention is one Mosaic call, and outside it no floating tensor holds
+    an env's logits — nothing batched over the envs has ``H·A x 2A``
+    elements an env, bar the activations ``(B, A, ·)``. The same step
+    lowered with ``kernel`` off (what the learner runs, and acting
+    anywhere but on a TPU) has such tensors: the logits ``(B, 2A, H·A)``
+    among them."""
+    from t2omca_tpu.ops.query_slice import agent_forward_qslice_entity
+    agent, params, args = _kernel_shapes_agent()
+    b, a, e = args[-1].shape
+    heads = 4
+
+    def lowered(kernel, platform):
+        fn = lambda p, *xs: agent_forward_qslice_entity(
+            p, *xs, emb=e, heads=heads, depth=2, n_actions=4,
+            standard_heads=True, dtype=jnp.dtype(dtype), kernel=kernel)
+        return jax.jit(fn).trace(params, *args).lower(
+            lowering_platforms=(platform,)).as_text()
+
+    logits = b * heads * a * 2 * a
+    wide = lambda text: {s for s in _float_tensors(text)
+                         if s[0] == b and int(np.prod(s)) >= logits
+                         and s[:2] != (b, a)}
+    tpu = lowered(True, "tpu")
+    assert tpu.count("tpu_custom_call") == 2          # one a block
+    assert not wide(tpu), wide(tpu)
+    assert (b, 2 * a, heads * a) in wide(lowered(False, "tpu"))
+    # anywhere but on a TPU acting runs the XLA form, and no kernel
+    cpu = lowered(True, "cpu")
+    assert "tpu_custom_call" not in cpu
+    assert (b, 2 * a, heads * a) in wide(cpu)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_acting_through_the_kernel_matches_the_learner_path(monkeypatch,
+                                                            dtype):
+    """``forward_entity(acting=True)`` run THROUGH the kernel (interpreted
+    here; on a TPU it is compiled) against ``acting=False`` (the XLA
+    association the learner unrolls), on real env states, both blocks of a
+    two-block forward: Q-values and next hidden within the tolerance the
+    two associations of this file are held to."""
+    from t2omca_tpu.kernels import entity_attention as ek
+    cfg = _cfg(standard_heads=True, emb=128, heads=4, mixer_emb=128,
+               mixer_heads=4, dtype=dtype)
+    cfg = cfg.replace(env_args=dataclasses.replace(
+        cfg.env_args, agv_num=16, mec_num=3))
+    exp = Experiment.build(sanity_check(cfg))
+    env, mac = exp.env, exp.mac
+    assert mac.use_entity_tables and mac.entity_kernel
+    b = cfg.batch_size_run
+    key = jax.random.PRNGKey(6)
+    states, _ = _rolled_states(env, b, 3, key)
+    compact = jax.vmap(env.compact_obs)(states)
+    params = mac.prepare_acting_params(mac.init_params(key, env.obs_dim))
+    hidden = jax.random.normal(jax.random.fold_in(key, 1),
+                               (b, env.n_agents, cfg.model.emb))
+    q_xla, h_xla = mac.forward_entity(params, compact, hidden)
+    monkeypatch.setattr(ek, "INTERPRET", True)
+    step = lambda p, c, h: mac.forward_entity(p, c, h, acting=True)
+    jaxpr = str(jax.make_jaxpr(step)(params, compact, hidden))
+    assert jaxpr.count("pallas_call") == cfg.model.depth
+    q_ker, h_ker = step(params, compact, hidden)
+    if dtype == "float32":
+        np.testing.assert_allclose(q_ker, q_xla, rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(h_ker, h_xla, rtol=2e-4, atol=2e-5)
+    else:
+        _assert_close_bf16_ulp(q_ker, q_xla)
+        _assert_close_bf16_ulp(h_ker, h_xla)
+
+
+@pytest.mark.parametrize("change,platform,line", [
+    ({}, "tpu", "entity tables, attention: kernel"),
+    ({}, "cpu", "entity tables, attention: xla"),
+    ({"entity_kernel": False}, "tpu", "entity tables, attention: xla"),
+    ({"n_agents": 5}, "tpu", "entity tables, attention: xla"),
+    ({"use_entity_tables": False}, "tpu", "qslice, attention: xla"),
+    ({"use_entity_tables": False, "use_qslice": False}, "tpu",
+     "obs, attention: xla"),
+    ({"trunk": object()}, "tpu", "trunk, attention: xla"),
+], ids=["kernel", "cpu", "lanes-span-devices", "five-agents", "qslice",
+        "obs", "trunk"])
+def test_describe_acting(change, platform, line):
+    """The start-up log's line follows ``act``'s own order of forwards,
+    and says ``kernel`` exactly where ``agent_forward_qslice_entity``
+    engages it: head-width kernels, the kernel's shapes, a TPU, one
+    device."""
+    info = {"n_agents": 16, "n_entities": 16, "obs_entity_feats": 9,
+            "n_actions": 5, "obs_shape": 144}
+    mac = BasicMAC.build(_cfg(standard_heads=True, emb=128, heads=4,
+                              mixer_emb=128, mixer_heads=4), info)
+    mac = dataclasses.replace(mac, **change)
+    assert mac.describe_acting(4, platform) == "acting forward: " + line
+    full = BasicMAC.build(_cfg(emb=128, heads=4, mixer_emb=128,
+                               mixer_heads=4), info)
+    assert full.describe_acting(4, "tpu").endswith("attention: xla")
+
+
+def test_entity_kernel_is_for_one_device_and_one_member():
+    """Env lanes that span devices (``dp_devices``, sebulba's actor mesh:
+    GSPMD has no rule for the kernel's custom call) or members (a
+    population's vmap) keep the XLA association in acting."""
+    info = {"n_agents": 16, "n_entities": 16, "obs_entity_feats": 9,
+            "n_actions": 5, "obs_shape": 144}
+    base = _cfg(standard_heads=True, emb=128, heads=4, mixer_emb=128,
+                mixer_heads=4)
+    assert BasicMAC.build(base, info).entity_kernel
+    for off in (dict(dp_devices=2),
+                dict(sebulba=dataclasses.replace(
+                    base.sebulba, actor_devices=2, learner_devices=1)),
+                dict(population=dataclasses.replace(
+                    base.population, size=2))):
+        assert not BasicMAC.build(base.replace(**off), info).entity_kernel
+
+
 @pytest.mark.slow   # two rollout compiles (~16 s); numeric equivalence of the paths pinned above
 def test_rollout_actions_match_obs_path():
     """Greedy episode through the runner: entity-table acting and obs-path

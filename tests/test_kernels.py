@@ -568,3 +568,122 @@ def test_pallas_superstep_compile_budget():
     assert log.count == 1
     assert np.isfinite(
         np.asarray(jax.device_get(stats.episode_return))).all()
+
+
+# ------------------------------------------ acting's entity-table attention
+
+def _entity_block_inputs(b, a, emb, heads, dtype, visibility, seed=0):
+    """One head-width block's inputs as ``agent_forward_qslice_entity``
+    hands them to ``_entity_attention_heads``: the folded kernels of a
+    random block, query rows, hidden tokens, feature tables, the is-self
+    ``1 / std`` and the same-MEC visibility."""
+    from t2omca_tpu.ops.query_slice import _fold_entity_heads
+    ks = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
+    n = lambda shape, scale: jax.random.normal(next(ks), shape) * scale
+    at = {"toqueries": {"kernel": n((emb, emb), emb ** -0.5)},
+          "tokeys": {"kernel": n((emb, emb), emb ** -0.5)},
+          "tovalues": {"kernel": n((emb, emb), emb ** -0.5)},
+          "unifyheads": {"kernel": n((emb, emb), emb ** -0.5),
+                         "bias": n((emb,), 0.1)}}
+    p = {"feat_embedding": {"kernel": n((9, emb), 0.3),
+                            "bias": n((emb,), 0.1)},
+         "transformer": {"block_0": {"attention": at}}}
+    hp = _fold_entity_heads(p, head_dim=emb // heads, depth=1,
+                            dtype=dtype)[0]
+    x0 = n((b, a, emb), 1.0).astype(dtype)
+    h_tok = n((b, a, emb), 1.0).astype(dtype)
+    feats = n((b, 2 * a, 9), 1.0).astype(dtype)
+    inv_self = 1.0 / (jax.random.uniform(next(ks), (b, a, 1)) * 0.3 + 0.05)
+    mec = {"one-mec": jnp.zeros((b, a), jnp.int32),
+           "alone": jnp.broadcast_to(jnp.arange(a), (b, a)),
+           "random": jax.random.randint(next(ks), (b, a), 0, 4)}.get(
+               visibility)
+    if visibility == "padded":
+        # the env's padded agents: each a negative mec_index of its own
+        mec = jnp.where(jnp.arange(a) >= a - 3, -1 - jnp.arange(a),
+                        jax.random.randint(next(ks), (b, a), 0, 3))
+    same_mec = mec[:, :, None] == mec[:, None, :]
+    return hp, x0, h_tok, feats, inv_self, same_mec
+
+
+def _entity_block_xla(hp, x0, h_tok, feats, inv_self, same_mec, heads,
+                      dtype):
+    from t2omca_tpu.ops.query_slice import _entity_attention_heads
+    sees = jnp.swapaxes(same_mec, 1, 2)
+    seen = jnp.concatenate([sees, ~sees], axis=1)
+    cast = lambda t: jax.tree.map(
+        lambda x: x.astype(dtype) if x.dtype == jnp.bfloat16 else x, t)
+    return _entity_attention_heads(cast(hp), cast(x0), cast(h_tok),
+                                   cast(feats), inv_self, seen,
+                                   heads=heads, dtype=dtype)
+
+
+def _entity_block_kernel(hp, x0, h_tok, feats, inv_self, same_mec, heads):
+    from t2omca_tpu.kernels import entity_attention as ek
+    b, a, emb = x0.shape
+    assert ek.eligible(b, a, emb, hp["wq"].shape[1], heads)
+    sees = jnp.swapaxes(same_mec, 1, 2)
+    tables = ek.tables(feats, inv_self,
+                       jnp.concatenate([sees, ~sees], axis=1), x0.dtype,
+                       ek.group(hp["wq"].shape[1], heads))
+    return ek.entity_attention(hp, x0, h_tok, *tables, heads=heads,
+                               interpret=True)
+
+
+_rms = lambda x: float(np.sqrt(np.mean(np.square(np.asarray(x, np.float64)))))
+
+
+@pytest.mark.parametrize("visibility", ["one-mec", "alone", "random",
+                                        "padded"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("emb,heads", [(256, 4), (256, 2), (128, 4)],
+                         ids=["4x64", "2x128", "4x32"])
+@pytest.mark.parametrize("a", [16, 64])
+def test_entity_attention_kernel_matches_xla_form(a, emb, heads, dtype,
+                                                  visibility):
+    """The kernel (interpreted) against ``_entity_attention_heads`` on the
+    same inputs. float32: the same sum to reassociation. bfloat16: both
+    round q, k, v, the tables, the probabilities and the context to
+    bf16; the kernel keeps logits and softmax in float32 where the XLA
+    form rounds them to bf16 — so it must lie at least as near the
+    float32 form (run on the same bf16-rounded inputs) as the XLA form
+    does, and near the XLA form by that same measure."""
+    dtype = jnp.dtype(dtype)
+    args = _entity_block_inputs(2, a, emb, heads, dtype, visibility)
+    got = _entity_block_kernel(*args, heads)
+    want = _entity_block_xla(*args, heads, dtype)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+        return
+    exact = _entity_block_xla(*args, heads, jnp.float32)
+    err_xla, err_kernel = _rms(want - exact), _rms(got - exact)
+    assert err_kernel <= 1.1 * err_xla, (err_kernel, err_xla)
+    assert _rms(got - want) <= 1.5 * err_xla, (_rms(got - want), err_xla)
+    assert err_xla < 0.02 * _rms(exact)
+
+
+def test_entity_attention_kernel_grid_of_env_tiles():
+    """Sixteen 64-agent envs are two grid steps of eight envs: the
+    per-tile blocks and the per-env slices of the scratch line up."""
+    from t2omca_tpu.kernels import entity_attention as ek
+    assert ek._env_tile(16, 64) == 8
+    args = _entity_block_inputs(16, 64, 256, 4, jnp.float32, "random", 3)
+    np.testing.assert_allclose(_entity_block_kernel(*args, 4),
+                               _entity_block_xla(*args, 4, jnp.float32),
+                               rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("b,a,emb,hd,heads,ok", [
+    (1024, 64, 256, 256, 4, True),     # the north-star cell
+    (256, 16, 128, 128, 4, True),      # 16 AGVs, four heads of 32
+    (4, 64, 256, 256, 2, True),        # one head a group
+    (4, 5, 16, 16, 2, False),          # A off the sublane quantum
+    (4, 16, 96, 96, 2, False),         # 48-wide heads do not tile 128 lanes
+    (4, 16, 64, 64, 2, False),         # H·D under one lane tile
+    (8, 128, 256, 256, 4, True),       # a full lane tile of agents
+    (8, 144, 256, 256, 4, False),      # more: the logits tiles outgrow VMEM
+])
+def test_entity_attention_kernel_eligibility(b, a, emb, hd, heads, ok):
+    from t2omca_tpu.kernels import entity_attention as ek
+    assert ek.eligible(b, a, emb, hd, heads) is ok

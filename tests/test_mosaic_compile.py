@@ -101,6 +101,50 @@ def test_flash_attention_lowers_to_mosaic(v5e, shape, dtype, mask_heads,
         assert "tpu_custom_call" in text
 
 
+# (B, A, emb, heads) of acting's entity-table attention kernel
+# (kernels/entity_attention.py): the north-star cell's, and a 16-AGV
+# ``standard_heads`` shape (four heads of 32: one 128-lane group)
+ENTITY_CASES = {
+    "agv64-d256-bf16": ((1024, 64, 256, 4), "bfloat16"),
+    "agv64-d256-f32": ((1024, 64, 256, 4), "float32"),
+    "agv16-d128-bf16": ((256, 16, 128, 4), "bfloat16"),
+    "agv16-d128-f32": ((256, 16, 128, 4), "float32"),
+    "agv64-d256-2x128-bf16": ((1024, 64, 256, 2), "bfloat16"),
+    "agv128-d256-bf16": ((64, 128, 256, 4), "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("shape,dtype", ENTITY_CASES.values(),
+                         ids=ENTITY_CASES.keys())
+def test_entity_attention_lowers_to_mosaic(v5e, shape, dtype):
+    """One block of acting's entity-table attention compiles for the chip
+    as ONE Mosaic kernel, tables and all, under this suite's ``highest``
+    default matmul precision too (bf16 operands take the MXU's one
+    pass)."""
+    from t2omca_tpu.kernels import entity_attention as ek
+    b, a, emb, heads = shape
+    dt = jnp.dtype(dtype)
+    assert ek.eligible(b, a, emb, emb, heads)
+
+    def aval(*s, dt=dt):
+        return jax.ShapeDtypeStruct(s, jnp.dtype(dt), sharding=v5e)
+
+    hp = {"wq": aval(emb, emb), "wk": aval(emb, emb), "wv": aval(emb, emb),
+          "wu": aval(emb, emb), "u_bias": aval(emb, dt="float32"),
+          "wek": aval(9, emb), "wev": aval(9, emb),
+          "bek": aval(emb, dt="float32"), "bev": aval(emb, dt="float32")}
+
+    def block(hp, x0, h_tok, feats, inv_self, seen):
+        tables = ek.tables(feats, inv_self, seen, dt, ek.group(emb, heads))
+        return ek.entity_attention(hp, x0, h_tok, *tables, heads=heads)
+
+    text = jax.jit(block).lower(
+        hp, aval(b, a, emb), aval(b, a, emb), aval(b, 2 * a, 9),
+        aval(b, a, 1, dt="float32"), aval(b, 2 * a, a, dt="bool")
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
 @pytest.mark.slow   # ~1 min: the whole fused program through the TPU compiler
 def test_config3_programs_fit_one_v5e_chip(v5e):
     """The committed north-star file's training programs, at full
@@ -138,7 +182,11 @@ def test_config3_programs_fit_one_v5e_chip(v5e):
                     train_iter.lower(ts, place(key), t_env)]
     hbm = 15.75 * 2 ** 30               # what the compiler itself allows
     for low in lowered:
-        m = low.compile().memory_analysis()
+        compiled = low.compile()
+        # every one of them acts, and acting's entity-table attention is
+        # the Mosaic kernel wherever the lowering is for a TPU
+        assert "tpu_custom_call" in compiled.as_text()
+        m = compiled.memory_analysis()
         live = (m.argument_size_in_bytes + m.output_size_in_bytes
                 - m.alias_size_in_bytes + m.temp_size_in_bytes)
         assert live < hbm, (live, m)

@@ -289,8 +289,14 @@ def traced_run(tmp_path_factory):
     for point in seen:
         resilience.register_fault(
             point, lambda _p=point, **kw: seen[_p].append(kw))
+    import logging
+    console = logging.Logger("t2omca.traced_run")
+    seen["log"] = []
+    handler = logging.Handler()
+    handler.emit = lambda record: seen["log"].append(record.getMessage())
+    console.addHandler(handler)
     try:
-        run(cfg, Logger())
+        run(cfg, Logger(console))
     finally:
         resilience.clear_faults()
     return cfg, trace_dir, seen
@@ -328,3 +334,12 @@ def test_hooks_hand_over_state_key_and_info_rows(traced_run):
     assert fetched
     for kw in fetched:
         assert kw["train_infos"] and "all_finite" in kw["train_infos"][-1]
+
+
+def test_start_up_log_names_the_acting_forward_and_its_attention(traced_run):
+    """Beside the fused-dispatch line: which forward ``act`` compiled and
+    which attention (``BasicMAC.describe_acting``) — on the CPU the XLA
+    form, whatever the shapes."""
+    _, _, seen = traced_run
+    assert any("fused superstep:" in line for line in seen["log"])
+    assert "acting forward: entity tables, attention: xla" in seen["log"]
