@@ -24,8 +24,9 @@
 # Usage: bash scripts/chaos.sh [N]      (default N=3)
 #
 # Slow by design (each driver scenario is a full run() with fresh
-# compiles; the serve scenario exports an artifact and runs the chaos
-# traffic bench) — this is the soak gate for resilience PRs, not part
+# compiles; the serve scenario, test_fleet_chaos_acceptance, exports an
+# artifact and drives the fleet with open-loop traffic under a fault
+# schedule) — this is the soak gate for resilience PRs, not part
 # of the tier-1 budget (tier-1 excludes them via `-m 'not slow'`).
 set -o pipefail
 N=${1:-3}

@@ -125,69 +125,6 @@ _ctx: Optional[AuditContext] = None
 #: but > 1 so the scan/gate structure is the real fused program's
 AUDIT_SUPERSTEP_K = 2
 
-#: registry program name -> substrings identifying its events in a
-#: ``jax.profiler`` trace (graftscope device-time attribution,
-#: ``obs/device_time.py``). The jitted wrapper functions in
-#: ``run.Experiment.jitted_programs``/``superstep_program`` are named
-#: ``_rollout``/``_insert``/``_train_iter``/``_superstep``; the device
-#: tracks name the XLA module ``jit_<fn>`` while the host executor
-#: track (the only one a CPU trace has — verified against a real
-#: capture) names the call ``PjitFunction(<fn>)``. Both
-#: forms are listed; the parser attributes one track per program, so
-#: listing both never double-counts. Stable as long as the wrapper
-#: names are (renaming one breaks attribution AND the checked-in GP304
-#: fingerprint, so the programs.json re-baseline is the reminder).
-#: Only the four driver hot programs are attributed:
-#: ``dp_superstep``/``learner_train`` lower the same wrappers (or
-#: ambiguous names) and would double-count.
-TRACE_SYMBOLS = {
-    "rollout": ("jit__rollout", "PjitFunction(_rollout)"),
-    "insert": ("jit__insert", "PjitFunction(_insert)"),
-    "train_iter": ("jit__train_iter", "PjitFunction(_train_iter)"),
-    "superstep": ("jit__superstep", "PjitFunction(_superstep)"),
-    # serving process only (serve/frontend.py) — never present in a
-    # training trace, so attribution cannot double-count
-    "serve_step": ("jit__serve_step", "PjitFunction(_serve_step)"),
-    # attention kernel modes (kernels/attention.py). The jit symbols
-    # appear only in standalone kernel dispatches (bench --kernels A/B,
-    # the audit programs); inside a rollout/superstep trace the pallas
-    # kernel instead shows up as its Mosaic kernel launch, whose name
-    # carries the kernel function — listed so fused-kernel device time
-    # is attributed instead of silently falling into the unattributed
-    # bucket. The einsum mode has no distinct device symbol when fused
-    # (XLA melts it into the surrounding fusion), so attn_xla only
-    # attributes standalone dispatches.
-    "attn_xla": ("jit__attn_xla", "PjitFunction(_attn_xla)"),
-    "attn_pallas": ("jit__attn_pallas", "PjitFunction(_attn_pallas)",
-                    "flash_attention_kernel"),
-    # the flash BACKWARD kernels (PR 13). Inside a train trace the two
-    # backward pallas programs show up as their Mosaic kernel-launch
-    # names — listed so learner-side backward device time is attributed
-    # instead of dropping into the unattributed bucket. (The substring
-    # "flash_attention_kernel" does NOT match these names, so forward
-    # and backward attribution can't cross-count.)
-    "attn_pallas_bwd": ("jit__attn_pallas_bwd",
-                        "PjitFunction(_attn_pallas_bwd)",
-                        "flash_attention_bwd_dq_kernel",
-                        "flash_attention_bwd_dkv_kernel"),
-    # graftworld parameterized env programs (envs/graftworld.py). Like
-    # the attention kernels these jit symbols appear only in standalone
-    # dispatches (the audit, micro-benches) — inside a rollout the env
-    # fuses into the scan body with no distinct symbol.
-    "env_reset": ("jit__env_reset", "PjitFunction(_env_reset)"),
-    "env_step": ("jit__env_step", "PjitFunction(_env_step)"),
-    # graftpop population superstep (run.population_superstep_program):
-    # the vmapped fused program dispatched by the population driver
-    # loop — distinct wrapper name, so attribution never collides with
-    # the single-member superstep
-    "superstep_pop": ("jit__superstep_pop",
-                      "PjitFunction(_superstep_pop)"),
-    # graftshard dp×mp dry-run block (parallel/mesh.py dpmp_block): a
-    # standalone audit-only dispatch — never fused into a driver trace,
-    # so attribution cannot double-count
-    "dpmp_block": ("jit__dpmp_block", "PjitFunction(_dpmp_block)"),
-}
-
 
 def audit_config():
     """The frozen tiny CPU config all default programs are built on.

@@ -369,7 +369,7 @@ class ObsConfig:
     """graftscope runtime-telemetry knobs (docs/OBSERVABILITY.md). All
     host-side: nothing here touches the jitted programs, so the
     graftprog fingerprints are identical at any setting — and with
-    ``enabled=False`` (the default) the driver/bench paths are
+    ``enabled=False`` (the default) the driver paths are
     behaviorally identical to a build without the obs layer."""
 
     # master switch: span recording around every watchdog-stamped
@@ -384,10 +384,6 @@ class ObsConfig:
     # spans.jsonl flush cadence in events (amortizes the write syscall;
     # the flight ring covers the unflushed tail on a crash)
     flush_every: int = 32
-    # attribute the jax.profiler window (profile_dir) back to the
-    # registry's named programs: logs device_ms_<program> stats and
-    # writes device_times.json for the report CLI. Needs profile_dir.
-    program_trace: bool = False
     # Logger per-key in-memory history cap (0 = unbounded, the pre-PR-6
     # behavior): self.stats held every (t, value) pair for the life of
     # the run — unbounded host-RAM growth on long runs now that the
@@ -413,8 +409,8 @@ class ObsConfig:
     # HBM memwatch (obs/memwatch.py): per-device memory snapshots at
     # phase boundaries with phase-attributed high-water tracking, merged
     # into flight_recorder.json / stall_diagnosis.json. Requires
-    # `enabled` (the snapshots ride the span/flight machinery — same
-    # dead-knob policy as program_trace).
+    # `enabled` (the snapshots ride the span/flight machinery; without
+    # it the key would be silently dead).
     memwatch: bool = False
     # graftsight learning-dynamics telemetry (obs/sight.py): in-graph
     # train-step diagnostics + host-side RL-health detectors. See
@@ -802,20 +798,6 @@ def sanity_check(cfg: TrainConfig) -> TrainConfig:
     if o.stats_history < 0:
         raise ValueError(f"obs.stats_history must be >= 0 (0 = "
                          f"unbounded), got {o.stats_history}")
-    if o.program_trace and not cfg.profile_dir:
-        raise ValueError(
-            "obs.program_trace attributes the jax.profiler trace window "
-            "to the registry's programs — with profile_dir empty no "
-            "trace is ever captured and the key is silently dead; set "
-            "profile_dir too")
-    if o.program_trace and not o.enabled:
-        raise ValueError(
-            "obs.program_trace is part of the graftscope telemetry "
-            "layer — with obs.enabled=false the master switch promises "
-            "no telemetry side effects, so the combination is "
-            "contradictory (same dead-knob policy as "
-            "first_dispatch_timeout without dispatch_timeout); set "
-            "obs.enabled=true too")
     if not 0 <= o.pulse_port <= 65535:
         raise ValueError(f"obs.pulse_port must be in 0..65535 (0 = no "
                          f"metrics endpoint), got {o.pulse_port}")
@@ -827,8 +809,7 @@ def sanity_check(cfg: TrainConfig) -> TrainConfig:
         raise ValueError(
             "obs.memwatch merges its snapshots into the span/flight "
             "artifacts — with obs.enabled=false none of those exist and "
-            "the key is silently dead (same policy as program_trace); "
-            "set obs.enabled=true too")
+            "the key is silently dead; set obs.enabled=true too")
     sg = o.sight
     if sg.bins < 4:
         raise ValueError(f"obs.sight.bins must be >= 4 (a histogram "

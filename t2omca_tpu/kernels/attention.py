@@ -45,9 +45,8 @@ Numerics contract (pinned by ``tests/test_kernels.py``):
 
 Compiled (Mosaic) is the default. Interpreter mode is opt-in through
 the module attribute :data:`INTERPRET` — the CPU tests
-(``tests/conftest.py``), the CPU-pinned audit CLI
-(``analysis/__main__.py``) and ``bench.py --smoke`` set it; nothing
-else does, so a ``kernels.attention: pallas`` run that finds no chip
+(``tests/conftest.py``) and the CPU-pinned audit CLI
+(``analysis/__main__.py``) set it; nothing else does, so a ``kernels.attention: pallas`` run that finds no chip
 fails in the lowering instead of quietly interpreting. Interpret mode
 also skips the TPU sublane/lane tile quanta (token counts pad only to
 the clamped block sizes, head dim not at all): the kernel *body* is the

@@ -1,30 +1,26 @@
-"""graftscope — runtime observability for the training/bench stack.
+"""graftscope — runtime observability for the training and serving stack.
 
-Four pieces (docs/OBSERVABILITY.md):
+Three pieces (docs/OBSERVABILITY.md):
 
 * **span tracing** (``spans.py``) — a low-overhead host-side span
   recorder threaded through every device-facing boundary the watchdog
   stamps, emitting structured JSONL alongside the Logger sinks;
-* **device-time attribution** (``device_time.py``) — a
-  ``jax.profiler`` trace window that maps captured events back to the
-  registry's named programs (``analysis/registry.TRACE_SYMBOLS``);
 * **flight recorder** (``spans.py``) — a bounded ring of recent
   events persisted atomically on stall/crash/non-finite/SIGTERM and
   merged into the watchdog's ``stall_diagnosis.json``;
 * **report CLI** (``python -m t2omca_tpu.obs report <run_dir>``) —
   joins the runtime telemetry against graftprog's FLOPs/bytes budgets
-  into a roofline-style per-program breakdown.
+  into a per-program breakdown of wall time per dispatch.
 
 Plus the **graftpulse live plane** (docs/OBSERVABILITY.md §pulse):
 ``pulse.py`` (Prometheus-text ``/metrics`` + ``/healthz`` + on-demand
-``/trace`` behind ``obs.pulse_port``), ``memwatch.py`` (phase-
+``/trace`` behind ``obs.pulse_port``) and ``memwatch.py`` (phase-
 attributed HBM high-water snapshots merged into the flight/stall
-artifacts), and ``timeline.py`` (the jax-free
-``python -m t2omca_tpu.obs timeline`` longitudinal BENCH trajectory).
+artifacts). Device time comes from the profiler's trace, read by
+``benchmark/trace.py`` and ``benchmark/scopes.py``.
 
-The span/report half is stdlib-only; ``device_time`` pulls in jax, so
-its names resolve lazily — importing ``t2omca_tpu.obs`` must stay
-cheap enough for the jax-free report CLI.
+Names resolve lazily — importing ``t2omca_tpu.obs`` must stay cheap
+enough for the jax-free report CLI.
 """
 
 from __future__ import annotations
@@ -33,8 +29,6 @@ from .spans import (KNOWN_PHASES, NULL_RECORDER, NullRecorder,
                     SpanRecorder, make_recorder, stacked)
 
 _LAZY = {
-    "ProgramTraceWindow": "device_time",
-    "parse_trace_device_times": "device_time",
     "PHASE_PROGRAMS": "report",
     "report_main": "report",
     # graftpulse live telemetry plane (stdlib-only modules; lazy so the
@@ -45,7 +39,6 @@ _LAZY = {
     "make_pulse": "pulse",
     "MemWatch": "memwatch",
     "make_memwatch": "memwatch",
-    "timeline_main": "timeline",
     # graftsight learning-dynamics telemetry (stdlib+numpy at import;
     # the in-graph helpers pull jax lazily inside their bodies)
     "SightMonitor": "sight",

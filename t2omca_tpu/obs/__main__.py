@@ -3,20 +3,13 @@
 Subcommands:
 
 ``report <run_dir>``
-    Join the run's span telemetry (``spans.jsonl``) and optional
-    device-time attribution (``device_times.json``) against graftprog's
-    FLOPs/bytes budgets (``analysis/programs.json``) into the per-
-    program roofline table (docs/OBSERVABILITY.md). Exit 0 = report
+    Join the run's span telemetry (``spans.jsonl``) against
+    graftprog's FLOPs/bytes budgets (``analysis/programs.json``) into
+    the per-program table (docs/OBSERVABILITY.md). Exit 0 = report
     printed, 2 = usage error. Degraded inputs render instead of
     raising: a torn final JSONL line (killed run) is skipped with a
     warning, and a run dir holding only a ``flight_recorder.json``
     reports from the flight tail.
-
-``timeline [BENCH_r*.json ...] [--runs <run_dir> ...]``
-    The longitudinal perf-trajectory table over bench records (every
-    shape) and recorded runs' metrics.jsonl, distinguishing measured
-    numbers from failed partials
-    (docs/OBSERVABILITY.md §pulse).
 
 ``learning <run_dir>``
     The graftsight learning-health report (docs/OBSERVABILITY.md §6):
@@ -26,7 +19,7 @@ Subcommands:
     tails from killed runs are skipped with a warning). Answers "is
     this run learning?" post-mortem.
 
-All are deliberately jax-free — the post-mortem host may not be able
+Both are deliberately jax-free — the post-mortem host may not be able
 to initialize a backend at all.
 """
 
@@ -43,30 +36,14 @@ def main(argv=None) -> int:
                     "(docs/OBSERVABILITY.md)")
     sub = parser.add_subparsers(dest="cmd", required=True)
     rep = sub.add_parser(
-        "report", help="per-program roofline report for a recorded run")
+        "report", help="per-program wall-time/budget report for a "
+                       "recorded run")
     rep.add_argument("run_dir",
                      help="results directory of a run recorded with "
                           "obs.enabled=true (holds spans.jsonl)")
     rep.add_argument("--programs-json", default=None,
                      help="graftprog budgets to join against "
                           "(default: analysis/programs.json)")
-    rep.add_argument("--peak-gflops", type=float, default=None,
-                     help="chip peak GFLOP/s — adds the roofline bound "
-                          "and achieved fraction per program")
-    rep.add_argument("--peak-gbps", type=float, default=None,
-                     help="chip peak memory bandwidth in GB/s (used "
-                          "with --peak-gflops)")
-    tl = sub.add_parser(
-        "timeline", help="longitudinal perf-trajectory table over "
-                         "BENCH_r*.json records and run dirs")
-    tl.add_argument("paths", nargs="*",
-                    help="BENCH record files (default: BENCH_r*.json "
-                         "in the current directory)")
-    tl.add_argument("--runs", nargs="*", default=[], metavar="RUN_DIR",
-                    help="recorded run directories whose metrics.jsonl "
-                         "joins the table (newest env-steps/s)")
-    tl.add_argument("--json", action="store_true",
-                    help="machine-readable rows instead of the table")
     ln = sub.add_parser(
         "learning", help="graftsight learning-health report for a "
                          "recorded run (docs/OBSERVABILITY.md §6)")
@@ -80,11 +57,7 @@ def main(argv=None) -> int:
         return learning_main(args.run_dir)
     if args.cmd == "report":
         from .report import report_main
-        return report_main(args.run_dir, args.programs_json,
-                           args.peak_gflops, args.peak_gbps)
-    if args.cmd == "timeline":
-        from .timeline import timeline_main
-        return timeline_main(args.paths, args.runs, as_json=args.json)
+        return report_main(args.run_dir, args.programs_json)
     parser.error(f"unknown command {args.cmd!r}")
     return 2
 

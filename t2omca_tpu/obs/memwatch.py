@@ -1,8 +1,10 @@
 """HBM memwatch: per-device memory snapshots with phase attribution.
 
-The repo's memory story so far is all *predictive*: ``bench.py --hbm``
-sizes residents from shapes, graftprog's GP303 ratchets the compiled
-programs' peak (temp + output-alias) at the frozen audit config. What
+The repo's memory story so far is all *predictive*: the compiler's
+``memory_analysis()`` of the programs at full size
+(``benchmark/tests/test_compile_v5e.py``), and graftprog's GP303
+ratchet of the compiled programs' peak (temp + output-alias) at the
+frozen audit config. What
 dies on a chip is the *live* number — and when it does, nothing says
 what held HBM at the time. This module closes that gap:
 

@@ -21,16 +21,16 @@ exist. This module is that surface:
   JSON ``GET /healthz`` (HTTP 200 ok / 503 degraded — a scrape-side
   load balancer or supervisor needs no JSON parsing to act), and
   ``GET|POST /trace`` arming the on-demand trace capture below.
-* :class:`TraceController` — on-demand device-time capture on a LIVE
+* :class:`TraceController` — on-demand profiler capture on a LIVE
   run: a ``<run_dir>/PULSE_TRACE`` file (touch it from any shell) or
   the ``/trace`` endpoint arms one bounded
-  :class:`obs.device_time.ProgramTraceWindow` at the next iteration
+  :class:`utils.profiling.TraceWindow` at the next iteration
   boundary, so a slow TPU session can be profiled without restart.
-  The capture lands in ``<run_dir>/pulse_trace_*`` with
-  ``device_times.json`` refreshed for the report CLI.
+  The capture lands in ``<run_dir>/pulse_trace_*``; its ``.xplane.pb``
+  is what ``benchmark/scopes.py`` reads.
 
-Stdlib-only at import (the bench daemon starts a hub before jax is
-importable); the trace controller pulls jax lazily at arm time only.
+Stdlib-only at import; the trace controller pulls jax lazily at arm
+time only.
 Wiring lives in ``run.run_sequential`` / ``run.run_sebulba`` and
 ``serve/frontend.py`` — all behind ``pulse_port`` / ``hub`` guards, so
 the off state is byte-identical (docs/OBSERVABILITY.md §pulse).
@@ -269,15 +269,6 @@ class _PulseHandler(BaseHTTPRequestHandler):
                 self._reply(200 if ok else 503, json.dumps(payload),
                             "application/json")
         elif path == "/trace":
-            if not getattr(self.server, "trace_supported", True):
-                # no TraceController behind this endpoint (the jax-free
-                # bench daemon): acking would leave the caller waiting
-                # on a capture that can never happen
-                self._reply(501, json.dumps(
-                    {"armed": False,
-                     "error": "no trace consumer on this endpoint"}),
-                    "application/json")
-                return
             with _watched("trace.trigger", rec, source="endpoint"):
                 hub.request_trace()
                 self._reply(200, json.dumps({"armed": True}),
@@ -305,16 +296,12 @@ class PulseServer:
     must never hang the run's exit path."""
 
     def __init__(self, hub: MetricsHub, port: int,
-                 host: str = "127.0.0.1", rec=NULL_RECORDER,
-                 trace_supported: bool = True) -> None:
+                 host: str = "127.0.0.1", rec=NULL_RECORDER) -> None:
         self.hub = hub
         self._srv = ThreadingHTTPServer((host, port), _PulseHandler)
         self._srv.daemon_threads = True
         self._srv.hub = hub             # type: ignore[attr-defined]
         self._srv.rec = rec             # type: ignore[attr-defined]
-        # False = no TraceController consumes this hub's trace requests
-        # (the bench daemon): /trace then reports 501 instead of acking
-        self._srv.trace_supported = trace_supported  # type: ignore[attr-defined]
         self.port = self._srv.server_address[1]
         self._thread: Optional[threading.Thread] = None
 
@@ -449,13 +436,12 @@ def make_pulse(obs_cfg, rec=NULL_RECORDER, log=None) -> Optional[PulseHandle]:
 class TraceController:
     """On-demand trace capture on a live run. ``poll(t_env)`` (called
     once per driver iteration, one ``os.path.exists`` when idle) arms a
-    bounded :class:`~..obs.device_time.ProgramTraceWindow` when either
+    bounded :class:`~..utils.profiling.TraceWindow` when either
     trigger fires; ``tick`` drives the active window exactly like the
     static profiler window. Each capture lands in its own
-    ``pulse_trace_<n>_t<t_env>`` directory and refreshes
-    ``<run_dir>/device_times.json`` (newest capture wins — the report
-    CLI reads the latest). A new trigger is accepted once the previous
-    window closed."""
+    ``pulse_trace_<n>_t<t_env>`` directory. A new trigger is accepted
+    once the previous window closed. ``window_factory`` lets a test
+    substitute a window that starts no profiler."""
 
     #: hard bound on iterations per capture — a fat-fingered config
     #: must not leave the profiler running for the rest of the run
@@ -476,12 +462,11 @@ class TraceController:
 
     def _make_window(self, trace_dir: str):
         if self._factory is not None:
-            return self._factory(trace_dir, out_dir=self.results_dir,
+            return self._factory(trace_dir,
                                  n_iterations=self.n_iterations)
-        from .device_time import ProgramTraceWindow
-        return ProgramTraceWindow(trace_dir, start_t_env=0,
-                                  n_iterations=self.n_iterations,
-                                  out_dir=self.results_dir)
+        from ..utils.profiling import TraceWindow
+        return TraceWindow(trace_dir, start_t_env=0,
+                           n_iterations=self.n_iterations)
 
     def poll(self, t_env: int) -> None:
         if self._win is not None:

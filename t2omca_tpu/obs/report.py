@@ -2,17 +2,15 @@
 
 ``python -m t2omca_tpu.obs report <run_dir>`` reads a run's span
 telemetry (``spans.jsonl``, written by the driver when
-``obs.enabled``) plus its optional device-time attribution
-(``device_times.json``, written by :class:`obs.device_time.
-ProgramTraceWindow`) and joins them against graftprog's checked-in
-FLOPs/bytes budgets (``analysis/programs.json``) into a roofline-style
-per-program table: measured wall (and device) time per dispatch next
-to the program's estimated FLOPs/bytes at the run's shapes, its
-arithmetic intensity, and the achieved FLOP/s — the tool ROADMAP open
-item 1 needs to pick between device-side PER sampling, Pallas
-attention, and bf16 as the next perf target (a program far below the
-intensity-implied bound is latency/dispatch-bound; one near it needs
-less math or fewer bytes, not a faster driver).
+``obs.enabled``) and joins it against graftprog's checked-in
+FLOPs/bytes budgets (``analysis/programs.json``) into a per-program
+table: measured wall time per dispatch next to the program's estimated
+FLOPs/bytes at the run's shapes, its arithmetic intensity, and the
+FLOP/s that wall time implies. Wall time per dispatch includes the
+dispatch overhead; device time per named scope, idle shares and
+roofline shares come from a profiler trace read by
+``benchmark/trace.py`` and ``benchmark/scopes.py`` against
+``benchmark/peaks.json``.
 
 Honesty about the join: programs.json budgets are measured at the
 frozen audit config (``analysis/registry.audit_config``: B=2, T=6,
@@ -21,9 +19,7 @@ run's shapes, and the report scales the audit budgets linearly with the
 per-dispatch env-step/sample counts — a first-order estimate (marked
 ``~``): attention terms scale super-linearly with agents/tokens, so
 cross-*scale* comparisons are indicative, cross-*program* comparisons
-at one scale are solid. Pass ``--peak-gflops``/``--peak-gbps`` (the
-chip's datasheet numbers) to add the roofline bound and the achieved
-fraction.
+at one scale are solid.
 
 stdlib-only on purpose (no jax import): the report must run on a host
 that cannot even initialize the backend — that is the post-mortem case
@@ -109,15 +105,6 @@ def load_flight_events(run_dir: str) -> Optional[List[dict]]:
     if not isinstance(events, list):
         return None
     return [e for e in events if isinstance(e, dict)]
-
-
-def load_device_times(run_dir: str) -> Dict[str, dict]:
-    path = os.path.join(run_dir, "device_times.json")
-    try:
-        with open(path) as f:
-            return dict(json.load(f).get("programs", {}))
-    except (OSError, ValueError):
-        return {}
 
 
 def scenario_slices(run_dir: str) -> Dict[str, Dict[int, dict]]:
@@ -253,9 +240,8 @@ def scale_factor(program: str, header: Optional[dict],
     return None
 
 
-def build_rows(phases: Dict[str, dict], device_times: Dict[str, dict],
-               programs: Dict[str, dict], header: Optional[dict]
-               ) -> List[dict]:
+def build_rows(phases: Dict[str, dict], programs: Dict[str, dict],
+               header: Optional[dict]) -> List[dict]:
     audit = _audit_shapes()
     rows: List[dict] = []
     for phase, prog_name in PHASE_PROGRAMS.items():
@@ -263,71 +249,24 @@ def build_rows(phases: Dict[str, dict], device_times: Dict[str, dict],
         if p is None or p["n"] == 0:
             continue
         entry = programs.get(prog_name, {})
-        dev = device_times.get(prog_name, {})
         sf = scale_factor(prog_name, header, audit)
         flops = entry.get("flops")
         bytes_ = entry.get("bytes_accessed")
-        row = {
+        # steady wall per dispatch includes dispatch overhead — an upper
+        # bound on time, a lower bound on rate (stated in the legend)
+        per_disp_ms = p["steady_ms"] if p["steady_ms"] > 0 else None
+        gflop_disp = (flops * sf / 1e9
+                      if flops is not None and sf else None)
+        rows.append({
             "phase": phase, "program": prog_name, "n": p["n"],
-            "first_ms": p["first_ms"], "steady_ms": p["steady_ms"],
-            "total_ms": p["total_ms"],
-            "device_ms": dev.get("device_ms"),
-            "device_events": dev.get("events"),
-            "flops_audit": flops, "bytes_audit": bytes_,
+            "first_ms": p["first_ms"],
             "intensity": (flops / bytes_ if flops and bytes_ else None),
-            "gflop_disp": (flops * sf / 1e9
-                           if flops is not None and sf else None),
+            "gflop_disp": gflop_disp,
             "gb_disp": (bytes_ * sf / 1e9
                         if bytes_ is not None and sf else None),
-        }
-        # achieved rate: device time when attributed, else the steady
-        # wall per dispatch (which includes dispatch overhead — an
-        # upper bound on time, lower bound on rate, stated in the table
-        # legend). The trace window covers only its OWN dispatches (not
-        # the whole run's span count), so per-dispatch device time is
-        # the window's median event duration — robust to the compile-
-        # inclusive first call on host tracks; mean over the window's
-        # events is the fallback for older device_times.json files.
-        per_disp_ms = None
-        if dev.get("median_ms"):
-            per_disp_ms = dev["median_ms"]
-            row["time_source"] = "device"
-        elif row["device_ms"] and row["device_events"]:
-            per_disp_ms = row["device_ms"] / row["device_events"]
-            row["time_source"] = "device"
-        elif p["steady_ms"] and p["steady_ms"] > 0:
-            per_disp_ms = p["steady_ms"]
-            row["time_source"] = "wall"
-        row["per_disp_ms"] = per_disp_ms
-        row["achieved_gflops"] = (
-            row["gflop_disp"] / (per_disp_ms / 1000.0)
-            if row["gflop_disp"] and per_disp_ms else None)
-        rows.append(row)
-    # device-attributed programs with no dispatch span of their own —
-    # the fused Pallas kernels (attn_pallas etc.) show up only as device
-    # kernel launches inside a larger program's dispatch. Without this
-    # they would silently vanish from the table (their device time
-    # dropped into the unattributed bucket); budgets stay unscaled (no
-    # run-shape mapping for a kernel fragment — stated via sf=None).
-    spanned = {r["program"] for r in rows}
-    for prog_name in sorted(set(device_times) - spanned):
-        dev = device_times[prog_name]
-        entry = programs.get(prog_name, {})
-        flops = entry.get("flops")
-        bytes_ = entry.get("bytes_accessed")
-        per_disp_ms = dev.get("median_ms") or (
-            dev["device_ms"] / dev["events"] if dev.get("events") else None)
-        rows.append({
-            "phase": "(trace-only)", "program": prog_name,
-            "n": dev.get("events", 0), "first_ms": -1.0,
-            "steady_ms": -1.0, "total_ms": dev.get("device_ms", 0.0),
-            "device_ms": dev.get("device_ms"),
-            "device_events": dev.get("events"),
-            "flops_audit": flops, "bytes_audit": bytes_,
-            "intensity": (flops / bytes_ if flops and bytes_ else None),
-            "gflop_disp": None, "gb_disp": None,
-            "per_disp_ms": per_disp_ms, "time_source": "device",
-            "achieved_gflops": None,
+            "per_disp_ms": per_disp_ms,
+            "achieved_gflops": (gflop_disp / (per_disp_ms / 1000.0)
+                                if gflop_disp and per_disp_ms else None),
         })
     return rows
 
@@ -341,9 +280,7 @@ def _fmt(v, nd=1, dash="-") -> str:
 
 
 def render(run_dir: str, events: List[dict], rows: List[dict],
-           phases: Dict[str, dict], header: Optional[dict],
-           peak_gflops: Optional[float], peak_gbps: Optional[float]
-           ) -> str:
+           phases: Dict[str, dict], header: Optional[dict]) -> str:
     lines: List[str] = []
     lines.append(f"graftscope report — {run_dir}")
     if header:
@@ -359,32 +296,21 @@ def render(run_dir: str, events: List[dict], rows: List[dict],
     lines.append("")
     if rows:
         hdr = (f"{'program':<13}{'phase':<20}{'n':>6}{'first ms':>10}"
-               f"{'ms/disp':>10}{'src':>5}{'~GFLOP/d':>10}{'~GB/d':>8}"
+               f"{'ms/disp':>10}{'~GFLOP/d':>10}{'~GB/d':>8}"
                f"{'FLOP/B':>8}{'~GFLOP/s':>10}")
         lines.append(hdr)
         lines.append("-" * len(hdr))
         for r in rows:
-            per_disp = r["per_disp_ms"]
             lines.append(
                 f"{r['program']:<13}{r['phase']:<20}{r['n']:>6}"
-                f"{_fmt(r['first_ms']):>10}{_fmt(per_disp):>10}"
-                f"{r.get('time_source', '-'):>5}"
+                f"{_fmt(r['first_ms']):>10}{_fmt(r['per_disp_ms']):>10}"
                 f"{_fmt(r['gflop_disp'], 3):>10}{_fmt(r['gb_disp'], 3):>8}"
                 f"{_fmt(r['intensity']):>8}"
                 f"{_fmt(r['achieved_gflops']):>10}")
-            if peak_gflops and peak_gbps and r["intensity"] \
-                    and r["achieved_gflops"]:
-                bound = min(peak_gflops, r["intensity"] * peak_gbps)
-                lines.append(
-                    f"{'':<11}  roofline bound {bound:,.1f} GFLOP/s "
-                    f"({'compute' if bound == peak_gflops else 'memory'}"
-                    f"-bound) — achieved "
-                    f"{100.0 * r['achieved_gflops'] / bound:.1f}%")
         lines.append("")
         lines.append("~ = audit-config budgets (analysis/programs.json) "
-                     "scaled linearly to the run shapes; src=wall "
-                     "includes dispatch overhead (device attribution "
-                     "off — obs.program_trace + profile_dir enable it)")
+                     "scaled linearly to the run shapes; ms/disp is "
+                     "steady wall time and includes dispatch overhead")
     else:
         lines.append("no program dispatch spans found (was the run "
                      "recorded with obs.enabled?)")
@@ -516,9 +442,7 @@ def render_comms_census(base: dict) -> List[str]:
     return lines
 
 
-def report_main(run_dir: str, programs_json: Optional[str] = None,
-                peak_gflops: Optional[float] = None,
-                peak_gbps: Optional[float] = None) -> int:
+def report_main(run_dir: str, programs_json: Optional[str] = None) -> int:
     """The ``report`` subcommand body. Exit codes match the analysis
     CLI convention: 0 = report printed, 2 = usage error (missing run
     dir / unreadable telemetry)."""
@@ -549,10 +473,9 @@ def report_main(run_dir: str, programs_json: Optional[str] = None,
               file=sys.stderr)
         return 2
     phases = phase_summary(events)
-    rows = build_rows(phases, load_device_times(run_dir),
-                      base["programs"], run_header(events))
-    print(render(run_dir, events, rows, phases, run_header(events),
-                 peak_gflops, peak_gbps))
+    header = run_header(events)
+    rows = build_rows(phases, base["programs"], header)
+    print(render(run_dir, events, rows, phases, header))
     census = render_comms_census(base)
     if census:
         print("\n".join(census))

@@ -4,13 +4,14 @@ The driver needs to know where a dispatch's wall-clock goes, and which
 phase a stalled or crashed run was in and how long the phases before it
 took. Podracer (arxiv 2104.06272) attributes its TPU
 utilization wins to exactly this per-phase accounting. This module is
-the host half of that story (device-time attribution lives in
-``obs/device_time.py``):
+the host half of that story (device time per named scope is read from
+the profiler's trace by ``benchmark/trace.py`` and
+``benchmark/scopes.py``):
 
 * :class:`SpanRecorder` — a low-overhead span recorder. The driver
   wraps every device-facing boundary it already stamps for the
   watchdog (``run.run_sequential`` ``_watched``/``_sync_point`` sites,
-  ``bench.py`` probe/measure phases, the checkpoint save) in
+  the checkpoint save) in
   ``rec.span(phase, t_env=..., **meta)``; each completed span becomes
   one structured JSONL event in ``<run_dir>/spans.jsonl`` alongside the
   ``Logger`` sinks. Overhead is a couple of ``perf_counter`` calls, a
@@ -61,7 +62,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from ..utils.ioutil import write_json_atomic
 
-#: The span phases the driver/bench are allowed to use. graftlint rule
+#: The span phases the driver and the servers are allowed to use. graftlint rule
 #: GL110 checks every ``_watched``/``_sync_point``/``_dispatch`` call
 #: site with a literal phase against this set, so a NEW device-facing
 #: boundary cannot silently appear without span (and therefore flight-
@@ -100,8 +101,6 @@ KNOWN_PHASES = frozenset({
     # when a peer died mid-preemption)
     "checkpoint.save", "collective.gather", "backend.init",
     "checkpoint.elastic", "preempt.barrier", "checkpoint.shard_save",
-    # bench.py phases (bench harness spans; embedded in every record)
-    "bench.build", "bench.compile", "bench.warm", "bench.measure",
     # graftserve boundaries (serve/export.py, serve/frontend.py): the
     # exporter's lower/compile/export pass, artifact load, and the
     # three per-request front-end stages — `obs report` reads a
@@ -113,10 +112,9 @@ KNOWN_PHASES = frozenset({
     # watchdog-stamped boundary; serve.* spans nest inside it), the
     # engine health-check dispatch, a quarantined engine's restart
     # reload, and the rolling hot-param-refresh path (fold + roll
-    # stages). bench.chaos is the chaos traffic leg's measure window
-    # (bench.py --serve --chaos)
+    # stages)
     "fleet.load", "fleet.dispatch", "fleet.selfcheck", "fleet.restart",
-    "fleet.refresh", "bench.chaos",
+    "fleet.refresh",
     # graftpulse live telemetry plane (obs/pulse.py, obs/memwatch.py):
     # one /metrics-endpoint scrape, one per-device HBM snapshot, the
     # PULSE_TRACE-file / /trace-endpoint arming of a live trace window
@@ -393,14 +391,6 @@ class SpanRecorder:
                 out.append(ev)
         return out
 
-    def current_phase(self) -> Optional[str]:
-        """Innermost still-open span's phase (None when idle) — the
-        bench failure record's ``phase`` field."""
-        with self._lock:
-            if not self._open:
-                return None
-            return self._open[max(self._open)]["phase"]
-
     def persist(self, path: str,
                 extra: Optional[Dict[str, Any]] = None) -> Optional[str]:
         """Atomically write the flight tail as JSON (tmp + rename).
@@ -458,9 +448,6 @@ class NullRecorder:
 
     def tail(self) -> List[Dict[str, Any]]:
         return []
-
-    def current_phase(self) -> Optional[str]:
-        return None
 
     def persist(self, path: str, extra=None) -> Optional[str]:
         return None

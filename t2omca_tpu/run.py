@@ -228,8 +228,8 @@ class Experiment:
         state to ``train_iter`` — XLA then updates both in place instead of
         copying the (largest-on-chip) buffer arrays every call. Only for
         callers that never reuse the pre-call state (the ``run_sequential``
-        loop replaces it immediately); benches/tests that re-time a program
-        on the same inputs must keep the default."""
+        loop replaces it immediately); tests that re-run a program on the
+        same inputs must keep the default."""
         runner, buffer, learner, cfg = (self.runner, self.buffer,
                                         self.learner, self.cfg)
         constrain = constrain_batch or (lambda b: b)
@@ -1283,18 +1283,8 @@ def run_sequential(exp: Experiment, logger: Logger,
     # tracing/profiling (SURVEY.md §5(1)): per-stage wall-clock into the
     # metric stream + optional jax.profiler trace window over the hot loop
     timer = StageTimer()
-    if cfg.obs.program_trace:
-        # graftscope device-time attribution: same trace window, plus a
-        # post-stop parse mapping the captured events back to the
-        # registry's named programs (device_ms_<prog> stats +
-        # device_times.json for the report CLI)
-        from .obs.device_time import ProgramTraceWindow
-        tracer = ProgramTraceWindow(cfg.profile_dir, cfg.profile_start,
-                                    cfg.profile_iterations,
-                                    out_dir=results_dir)
-    else:
-        tracer = TraceWindow(cfg.profile_dir, cfg.profile_start,
-                             cfg.profile_iterations)
+    tracer = TraceWindow(cfg.profile_dir, cfg.profile_start,
+                         cfg.profile_iterations)
     # run header for the report CLI: the shapes that scale graftprog's
     # audit-config budgets to this run (obs/report.py)
     if rec.enabled:

@@ -20,10 +20,10 @@ host-side batcher.
   refresh with fingerprint gate and auto-rollback (ROADMAP item 4).
 
 Gated by the same static machinery as training: the serve step is
-ratcheted in ``analysis/programs.json`` (FLOPs/bytes/fingerprint), the
-span phases are pinned by GL110, and ``bench.py --serve`` measures
-p50/p99 decision latency + decisions/s/chip. docs/SERVING.md is the
-contract.
+ratcheted in ``analysis/programs.json`` (FLOPs/bytes/fingerprint) and
+the span phases are pinned by GL110. Serving latency has not been
+measured on the chip: no cell of ``BENCHMARK.json`` serves yet.
+docs/SERVING.md is the contract.
 """
 
 from .export import (ARTIFACT_FORMAT, DEFAULT_BUCKETS, export_artifact,

@@ -2,8 +2,7 @@
 
 ``python -m t2omca_tpu.serve export <ckpt_dir>`` turns a training
 checkpoint into the self-contained directory the inference front-end
-(``serve/frontend.py``) and the serving bench (``bench.py --serve``)
-load:
+(``serve/frontend.py``) loads:
 
 * ``params_float32.msgpack`` / ``params_bfloat16.msgpack`` — the agent
   parameters ONLY (optimizer, target net, mixer and replay state are

@@ -45,9 +45,10 @@ Telemetry: every boundary is spanned (``fleet.load`` /
 ``obs/spans.KNOWN_PHASES``) and the pulse plane carries queue depth,
 per-engine state, shed/hedge/stall/refresh counters. Chaos hooks
 (``utils/resilience.register_fault``): ``fleet.dispatch``,
-``fleet.selfcheck``, ``fleet.refresh``. ``bench.py --serve --chaos``
-drives the whole layer under bursty heavy-tailed open-loop traffic
-plus a fault schedule; docs/SERVING.md §fleet is the contract.
+``fleet.selfcheck``, ``fleet.refresh``.
+``tests/test_fleet.py::test_fleet_chaos_acceptance`` drives the whole
+layer under bursty heavy-tailed open-loop traffic plus a fault
+schedule; docs/SERVING.md §fleet is the contract.
 """
 
 from __future__ import annotations
@@ -1062,7 +1063,7 @@ class ServeFleet:
                 fe.warmup()
 
     def stats(self) -> dict:
-        """Snapshot for benches/tests: counters, ladder, per-engine
+        """Snapshot for callers and tests: counters, ladder, per-engine
         state, recovery times."""
         with self._counters_lock:
             counters = dict(self.counters)

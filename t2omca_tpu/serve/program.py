@@ -4,9 +4,9 @@ Serving is a different program than training (ROADMAP open item 5): no
 exploration, no schedules, no env — just ``q = forward(params, obs,
 hidden)`` masked-argmaxed over ``avail``. This module is the single
 definition every serve surface builds from: the exporter lowers/compiles
-it per batch bucket, the front-end dispatches it, the graftprog registry
-audits it, and ``bench.py --serve`` times it — so the program the
-latency ratchet pins is the program traffic actually runs.
+it per batch bucket, the front-end dispatches it and the graftprog
+registry audits it — so the program the ratchet pins is the program
+traffic actually runs.
 
 Bit-parity contract (the K=1-parity convention, pinned by
 tests/test_serve.py): with f32 params the step's actions are
@@ -78,7 +78,7 @@ def register_audit_programs(ctx):
     """graftprog registry hook (analysis/registry.py): the greedy serve
     step at the audit config's scale, ratcheted like every other hot
     program — a FLOPs/bytes/fingerprint regression on the serving path
-    fails the tier-1 gate statically, before any latency bench runs.
+    fails the tier-1 gate statically, before any latency is measured.
     ``compile=True``: serving is latency-bound, so the peak-memory and
     optimized-HLO budgets matter and the program is small enough to
     compile inside the prelude budget."""

@@ -1,5 +1,5 @@
 """The device check of every path that measures or proves something on
-the chip (``bench.py``, ``chip_smoke.py``): a TPU, or a non-zero exit.
+the chip (``chip_smoke.py``): a TPU, or a non-zero exit.
 No CPU continuation, no interpret fallback. In-process — the caller is
 the one process that will hold the chip, so a child probe would save
 nothing and a parent that has touched JAX could not start one anyway."""

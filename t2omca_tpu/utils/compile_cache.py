@@ -4,7 +4,7 @@ The cache key includes the directory path, so a directory that moves
 (a temp name, a pid, a timestamp, a per-artifact folder) never hits.
 One rule, applied once per process by every entry point before its
 first compile (``python -m t2omca_tpu``, ``python -m t2omca_tpu.serve``,
-``bench.py``, ``chip_smoke.py``):
+``chip_smoke.py``, ``benchmark/run.py``):
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — the directory is placed from
   outside. JAX reads the variable itself; nothing here touches

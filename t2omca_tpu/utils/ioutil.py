@@ -1,9 +1,8 @@
 """Atomic JSON persistence, shared by the diagnostic writers.
 
-Three places persist post-mortem artifacts — the graftscope flight
-recorder (``obs/spans.py``), the watchdog stall diagnosis
-(``utils/watchdog.py``) and the device-time attribution
-(``obs/device_time.py``) — and each is written on paths (stall, crash,
+Two places persist post-mortem artifacts — the graftscope flight
+recorder (``obs/spans.py``) and the watchdog stall diagnosis
+(``utils/watchdog.py``) — and each is written on paths (stall, crash,
 hard exit) where a torn or lost file defeats the artifact's purpose.
 One helper so the semantics can't drift between copies:
 
@@ -35,7 +34,7 @@ def read_jsonl_tolerant(path: str,
     """Parse a JSONL file, skipping unparseable lines instead of
     raising. A run killed mid-write (crash, SIGKILL, hard watchdog
     exit) leaves exactly one torn artifact: a truncated FINAL line —
-    and the post-mortem readers (``obs report``, ``obs timeline``) must
+    and the post-mortem readers (``obs report``, ``obs learning``) must
     read past it, because that torn tail is precisely the file a dead
     run leaves. ``on_bad(line_no, is_last)`` is invoked per skipped
     line (1-based; ``is_last`` distinguishes the expected torn tail

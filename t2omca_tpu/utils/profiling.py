@@ -45,12 +45,7 @@ class StageTimer:
 class TraceWindow:
     """Start a jax profiler trace when ``t_env`` enters
     [start, start+duration_steps-ish]; stop after ``n_iterations`` driver
-    iterations. No-op when ``trace_dir`` is empty.
-
-    Subclass hook: ``_on_stop(logger, t_env)`` runs once, right after
-    ``jax.profiler.stop_trace()`` — ``obs.device_time.ProgramTraceWindow``
-    overrides it to attribute the captured device time back to the
-    registry's named programs (docs/OBSERVABILITY.md)."""
+    iterations. No-op when ``trace_dir`` is empty."""
 
     def __init__(self, trace_dir: str, start_t_env: int = 0,
                  n_iterations: int = 3):
@@ -75,9 +70,6 @@ class TraceWindow:
             jax.profiler.stop_trace()
             self._active = None
             self._done = True
-            self._on_stop(logger, t_env)
-
-    def _on_stop(self, logger, t_env: int) -> None:
-        if logger is not None:
-            logger.console_logger.info(
-                f"profiler trace written to {self.trace_dir}")
+            if logger is not None:
+                logger.console_logger.info(
+                    f"profiler trace written to {self.trace_dir}")

@@ -414,7 +414,7 @@ def test_gl110_registry_parsed_from_spans_module(tmp_path):
     from t2omca_tpu.analysis.graftlint import collect_span_phases
     phases = collect_span_phases(REPO)
     assert phases is not None
-    assert "dispatch.superstep" in phases and "bench.build" in phases
+    assert "dispatch.superstep" in phases and "fleet.dispatch" in phases
     # a repo without the registry file disarms the rule (None)
     assert collect_span_phases(tmp_path) is None
     # and an unregistered phase in a package file WOULD be a gate

@@ -1,6 +1,6 @@
 """Coverage for the bfloat16 perf modes: compute dtype (model.dtype) and
-episode/replay storage dtype (replay.store_dtype) — the paths bench.py uses
-on TPU, exercised here on CPU at tiny scale."""
+episode/replay storage dtype (replay.store_dtype) — the paths the
+benchmark's cells use on TPU, exercised here on CPU at tiny scale."""
 
 import jax
 import jax.numpy as jnp

@@ -11,10 +11,12 @@ import numpy as np
 import pytest
 
 from t2omca_tpu.config import (EnvConfig, ModelConfig, ReplayConfig,
-                               TrainConfig, sanity_check)
+                               TrainConfig, load_config, sanity_check)
 from t2omca_tpu.run import Experiment, run
 from t2omca_tpu.utils.checkpoint import find_checkpoint, load_checkpoint
 from t2omca_tpu.utils.logging import Logger
+
+REPO = os.path.join(os.path.dirname(__file__), "..")
 
 
 def tiny_cfg(tmp_path, **kw):
@@ -33,6 +35,42 @@ def tiny_cfg(tmp_path, **kw):
     )
     defaults.update(kw)
     return sanity_check(TrainConfig(**defaults))
+
+
+#: every committed preset with the values that identify it: lanes, AGVs,
+#: data-parallel devices, and what the file is there to switch on
+COMMITTED_PRESETS = {
+    "config1_cpu_parity": (8, 4, 0, lambda c: c.env_args.fast_norm is False),
+    "config3_sebulba": (96, 64, 0, lambda c: c.sebulba.actor_devices == 3
+                        and c.sebulba.learner_devices == 1),
+    "config3_tpu_northstar": (1024, 64, 0, lambda c: c.superstep == 4
+                              and c.model.remat),
+    "config5_dp8": (8192, 256, 8, lambda c: c.replay.buffer_size == 16384),
+    "config6_scenarios": (32, 8, 0, lambda c:
+                          c.env_args.scenario.kind == "mixture"),
+    "config7_population": (32, 8, 0, lambda c: c.population.size == 4),
+    "config8_trunk_smallthinker": (32, 16, 0, lambda c:
+                                   c.model.trunk is not None
+                                   and c.model.trunk.experts_held == 8),
+    "serve_smoke": (4, 4, 0, lambda c: c.env_args.episode_limit == 8),
+}
+
+
+@pytest.mark.parametrize("name", sorted(
+    os.path.splitext(os.path.basename(p))[0]
+    for p in glob.glob(os.path.join(REPO, "configs", "*.yaml"))))
+def test_committed_config_presets_load(name):
+    """The configs/ presets (BASELINE measurement points as config files —
+    the reference's sacred-config workflow, M14) must stay loadable and
+    sane as flags evolve: ``load_config`` ends in ``sanity_check``. One
+    case per file on disk, so a new preset needs its line above."""
+    envs, agv, dp, identifies = COMMITTED_PRESETS[name]
+    cfg = load_config(os.path.join(REPO, "configs", name + ".yaml"))
+    assert cfg.batch_size_run == envs
+    assert cfg.env_args.agv_num == agv
+    assert cfg.dp_devices == dp
+    assert identifies(cfg)
+    assert sanity_check(cfg) == cfg
 
 
 def logged_keys(results_root):

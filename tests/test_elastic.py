@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax import struct
+from flax import serialization, struct
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from t2omca_tpu import population as graftpop
@@ -360,9 +360,23 @@ def test_v3_fixture_full_migration_chain(tmp_path):
         np.asarray(jax.tree_util.tree_leaves(ts.runner.env_params)[0]),
         np.asarray(jax.tree_util.tree_leaves(
             ts_template.runner.env_params)[0]))
-    # everything the v3 writer DID store restores verbatim
-    np.testing.assert_array_equal(np.asarray(ts.runner.key),
-                                  np.asarray(ts_template.runner.key))
+    # everything the v3 writer DID store restores verbatim: every leaf
+    # against the fixture's own bytes, decoded without the chain (the
+    # template's values are this installation's PRNG output, not the
+    # fixture's)
+    with open(os.path.join(d, "state.msgpack"), "rb") as f:
+        stored = serialization.msgpack_restore(f.read())
+    restored = serialization.to_state_dict(ts)
+    injected = restored["runner"].pop("env_params")
+    assert "env_params" not in stored["runner"] and injected
+    assert (jax.tree_util.tree_structure(restored)
+            == jax.tree_util.tree_structure(stored))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(stored):
+        got = restored
+        for k in path:
+            got = got[k.key]
+        np.testing.assert_array_equal(np.asarray(got), leaf,
+                                      err_msg=jax.tree_util.keystr(path))
 
     # v3 → v4 → v5: population restore lifts the single member to P=2
     cfg_p = sanity_check(cfg.replace(

@@ -6,16 +6,13 @@ drive a real :class:`~t2omca_tpu.serve.fleet.ServeFleet` (real threads,
 real watchdogs, real supervisor) over stub frontends injected via
 ``frontend_factory``, so no jit and no Experiment build ever runs in the
 tier-1 budget. Everything artifact-backed (refresh bit-parity, the
-fingerprint gate against real lowered programs, the ``bench.py --serve
---chaos`` acceptance run) is ``slow``-marked; the chaos acceptance run
-additionally carries the ``chaos`` marker so ``scripts/chaos.sh`` can
-select it into the soak battery.
+fingerprint gate against real lowered programs, the chaos acceptance
+run) is ``slow``-marked; the chaos acceptance run additionally carries
+the ``chaos`` marker so ``scripts/chaos.sh`` can select it into the soak
+battery.
 """
 
-import json
 import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -119,8 +116,6 @@ def test_fleet_phases_registered():
     assert {"fleet.load", "fleet.dispatch", "fleet.selfcheck",
             "fleet.restart", "fleet.refresh"} <= phases
     assert phases <= KNOWN_PHASES, phases - KNOWN_PHASES
-    # the chaos bench leg's traffic span is registered too
-    assert "bench.chaos" in KNOWN_PHASES
 
 
 # ---------------------------------------------------------------------------
@@ -588,14 +583,124 @@ def test_check_refresh_dry_run_and_cli(exported, capsys):
 
 
 # ---------------------------------------------------------------------------
-# chaos acceptance: bench.py --serve --chaos (slow + chaos battery)
+# chaos acceptance: the fleet under fire (slow + chaos battery)
 # ---------------------------------------------------------------------------
+
+
+def _chaos_traffic(art, n_eng=2, duration=8.0):
+    """Drive a real fleet over ``art`` with bursty heavy-tailed
+    open-loop traffic (Pareto-tailed request sizes; exponential arrivals
+    whose rate steps up 5x inside burst windows; requests are submitted
+    on the clock whether or not earlier ones completed — the only honest
+    way to exercise shedding) while a fault schedule runs underneath:
+    engine 0 killed mid-burst, one dispatch hang on a peer engine, one
+    poisoned hot refresh. Returns every request's result, the fleet's
+    final stats and the refresh outcome."""
+    from t2omca_tpu.serve.fleet import FleetConfig, ServeFleet
+    fcfg = FleetConfig(
+        queue_depth=32,
+        deadline_s=max(2.0, duration / 2.5),
+        dispatch_timeout_s=max(0.75, min(2.0, duration / 6.0)),
+        restart_backoff_s=0.05, restart_backoff_max_s=0.5,
+        ladder_cooldown_s=0.25,
+    )
+    fleet = ServeFleet(art, n_engines=n_eng, dtype="float32",
+                       cfg=fcfg).start()
+    try:
+        assert fleet.serving_engines() > 0, fleet.stats()["engines"]
+        fleet.warmup()
+
+        fe0 = fleet.engines[0].fe
+        a, d, na = fe0.n_agents, fe0.obs_dim, fe0.n_actions
+        bmax = fe0.buckets[-1]
+        rng = np.random.default_rng(0)
+
+        # request pool: heavy-tailed sizes (the Pareto tail past the max
+        # bucket exercises the chunking path), one pre-built request per
+        # distinct size so the open-loop submitter costs ~nothing
+        sizes = np.minimum(1 + rng.pareto(1.1, 4096).astype(np.int64),
+                           2 * bmax)
+        pool = {}
+        for n in np.unique(sizes):
+            n = int(n)
+            obs = rng.standard_normal((n, a, d)).astype(np.float32)
+            avail = rng.random((n, a, na)) < 0.7
+            avail[..., 0] = True
+            pool[n] = (obs, avail)
+
+        # fault schedule (one-shot each, on the fleet's own chaos hooks)
+        kill_at = 0.25 * duration
+        refresh_at = 0.40 * duration
+        hang_at = 0.55 * duration
+        hang_s = fcfg.dispatch_timeout_s + min(1.5, 0.2 * duration)
+        hang_engine = 1 % n_eng
+        t0 = time.monotonic()
+        killed, hung = [], []
+
+        def _fault_schedule(engine, attempt, rid, **kw):
+            now = time.monotonic() - t0
+            if engine == 0 and not killed and now >= kill_at:
+                killed.append(now)
+                raise RuntimeError("chaos: engine killed (injected)")
+            if engine == hang_engine and not hung and now >= hang_at:
+                hung.append(now)
+                time.sleep(hang_s)
+
+        resilience.register_fault("fleet.dispatch", _fault_schedule)
+
+        refresh_out = {}
+
+        def _poisoned_refresh():
+            refresh_out.update(fleet.refresh(
+                os.path.join(art, "_no_such_checkpoint")))
+
+        poison = threading.Timer(refresh_at, _poisoned_refresh)
+        poison.daemon = True
+        poison.start()
+
+        # base rate sized to the measured warm dispatch, so that the
+        # bursts saturate the fleet on any host
+        t_warm0 = time.perf_counter()
+        fleet.select(*pool[min(pool)])
+        warm_s = max(time.perf_counter() - t_warm0, 1e-4)
+        base_rate = max(10.0, min(200.0, 1.5 * n_eng / warm_s))
+        bursts = [(0.2 * duration, 0.3 * duration),
+                  (0.5 * duration, 0.65 * duration),
+                  (0.8 * duration, 0.9 * duration)]
+
+        def rate_at(t):
+            burst = any(lo <= t < hi for lo, hi in bursts)
+            return base_rate * (5.0 if burst else 1.0)
+
+        requests = []
+        t = 0.0
+        i = 0
+        while t < duration:
+            now = time.monotonic() - t0
+            if now < t:
+                time.sleep(min(t - now, 0.05))
+                continue
+            requests.append(fleet.submit(*pool[int(sizes[i % len(sizes)])]))
+            i += 1
+            t += rng.exponential(1.0 / rate_at(t))
+        # drain: every admitted request must resolve (completion, SHED,
+        # deadline or error) — the supervisor's deadline sweep bounds
+        # this wait
+        results = [r.wait(timeout=fcfg.deadline_s + 2.0)
+                   for r in requests]
+        poison.join(timeout=30.0)
+        assert not poison.is_alive(), "poisoned refresh never returned"
+    finally:
+        resilience.clear_faults("fleet.dispatch")
+        stats = fleet.stats()
+        fleet.stop()
+    return results, stats, refresh_out
 
 
 @pytest.mark.slow
 @pytest.mark.chaos
 @pytest.mark.faultinject
-def test_bench_serve_chaos_acceptance(exported):
+def test_fleet_chaos_acceptance(exported):
     """The fleet-under-fire acceptance run (scripts/chaos.sh serve
     scenario): bursty open-loop traffic with engine 0 killed mid-burst,
     a dispatch hang injected on a peer and a poisoned hot refresh —
@@ -603,53 +708,24 @@ def test_bench_serve_chaos_acceptance(exported):
     the quarantined engines must restart and rejoin, and the refresh
     must be refused while serving continues."""
     cfg, ck, art, meta = exported
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--smoke", "--serve", "--chaos",
-         "--artifact", art, "--fleet-engines", "2",
-         "--chaos-seconds", "8"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = [l for l in proc.stdout.splitlines() if l.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])
-    assert rec["metric"] == "serve_chaos_p99_ms"
+    results, stats, refresh = _chaos_traffic(art, n_eng=2, duration=8.0)
+    by = {}
+    for r in results:
+        by[r.status] = by.get(r.status, 0) + 1
     # zero silent hangs: every admitted request resolved to exactly one
     # explicit status, none via the unresolved-at-wait backstop
-    assert rec["unresolved"] == 0
-    assert rec["ok"] + rec["shed"] + rec["deadline"] + rec["errors"] \
-        == rec["requests"]
-    assert rec["ok"] > 0
-    assert rec["value"] == rec["p99_ms"] and rec["p99_ms"] > 0
-    assert 0.0 <= rec["shed_fraction"] <= 1.0
+    assert not [r for r in results if r.status == "error"
+                and "unresolved" in (r.error or "")]
+    assert by.get("ok", 0) + by.get("shed", 0) + by.get("deadline", 0) \
+        + by.get("error", 0) == len(results)
+    assert by.get("ok", 0) > 0
     # the killed engine was quarantined, restarted and rejoined
-    assert rec["engine_restarts"] >= 1
-    assert rec["recovery_s"] is not None and rec["recovery_s"] > 0
-    assert rec["recoveries_s"]
-    assert rec["ejected"] == 0
+    assert stats.get("fleet_restarts_total", 0) >= 1
+    assert stats["recoveries_s"] and max(stats["recoveries_s"]) > 0
+    assert stats.get("fleet_ejected_total", 0) == 0
     # the injected hang tripped the per-engine watchdog
-    assert rec["stalls"] >= 1
+    assert stats.get("fleet_stalls_total", 0) >= 1
     # the poisoned refresh was REFUSED, never applied
-    assert rec["refresh"] and rec["refresh"]["status"] == "refused"
+    assert refresh and refresh["status"] == "refused"
     # the fleet ended RESUMABLE: every engine back in serving state
-    assert rec["engines_serving_end"] == rec["engines"] == 2
-
-
-@pytest.mark.slow
-def test_bench_serve_chaos_partial_record_on_failure(tmp_path):
-    """A chaos leg that dies on the launchpad (missing artifact) still
-    files ONE parseable partial record under the chaos metric with the
-    flight-recorder fields (phase + spans tail)."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--smoke", "--serve", "--chaos",
-         "--artifact", str(tmp_path / "missing")],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 1
-    lines = [l for l in proc.stdout.splitlines() if l.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])
-    assert rec["metric"] == "serve_chaos_p99_ms"
-    assert rec["value"] is None
-    assert rec["error"]
-    assert "phase" in rec and "spans_tail" in rec
+    assert stats["serving"] == 2

@@ -16,18 +16,12 @@ shared superstep core (``run._superstep_fn``):
 * **population × Sebulba** — the vmapped learner in lockstep behind the
   device-resident queue ends on the classic population driver's train
   state (the solo lockstep anchor lifted to rank P: control state
-  bit-equal, floats at ULP scale — bitwise holds at the P=1 squeeze);
-* the ``--lattice`` bench matrix leg and the argparse composition gates.
+  bit-equal, floats at ULP scale — bitwise holds at the P=1 squeeze).
 
 The combo-rejection pins (which illegal lattice points raise, naming
 the blocking mechanism and the nearest legal alternative) live in
 tests/test_population.py::test_sanity_lattice_legal_and_gated_combos.
 """
-
-import json
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -283,71 +277,3 @@ def test_population_sebulba_lockstep_matches_population_classic(tmp_path):
     _assert_trees_ulp_close(h1.buffer, h2.buffer, msg="buffer ")
     _assert_trees_ulp_close(h1.runner, h2.runner, msg="runner ")
     _assert_trees_ulp_close(h1.episode, h2.episode, msg="episode ")
-
-
-# ------------------------------------------------------------- bench legs
-
-def test_bench_population_rejects_ab_kernels_with_alternative():
-    """--population --kernels ab is rejected NAMING the legal
-    single-mode alternatives (the lattice composition gate)."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__), "..",
-                                      "bench.py"),
-         "--population", "4", "--kernels", "ab", "--smoke"],
-        capture_output=True, text=True, timeout=120)
-    assert r.returncode == 2
-    assert "--kernels pallas or --kernels xla" in r.stderr
-    assert "--lattice" in r.stderr
-
-
-@pytest.mark.slow   # a full smoke pop x sebulba bench child (~3 min)
-def test_bench_population_sebulba_record_schema():
-    """--population P --sebulba emits one schema-1 record carrying the
-    lockstep headline, the serialized A/B and the population-classic
-    context ratio."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__), "..",
-                                      "bench.py"),
-         "--population", "2", "--sebulba", "--smoke", "--iters", "1"],
-        capture_output=True, text=True, timeout=900,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert r.returncode == 0, r.stdout + r.stderr
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "env_steps_per_sec"
-    assert rec["schema"] == 1
-    assert rec["population"] == 2
-    assert rec["sebulba"] == {"actor_devices": 1, "learner_devices": 1,
-                              "queue_slots": 1, "staleness": 0}
-    assert rec["value"] > 0
-    assert rec["serialized_env_steps_per_sec"] > 0
-    assert rec["overlap_speedup"] > 0
-    assert rec["population_classic_env_steps_per_sec"] > 0
-    assert rec["lockstep_vs_classic"] > 0
-    assert rec["serial_solo_env_steps_per_sec"] > 0
-    # the compounded population x overlap ratio over the pre-lattice
-    # serial-campaign baseline. Schema-presence only: the acceptance
-    # reading (>= 1) is taken from the RECORDED P=4 smoke
-    # (`bench.py --population 4 --sebulba`, docs/POPULATION.md) — a
-    # timing ratio asserted inside a unit test on a shared 1-core CI
-    # host measures the host's load, not the lattice.
-    assert rec["lockstep_vs_serial_solo"] > 0
-    assert rec["host_cores"] >= 1
-
-
-@pytest.mark.slow   # a pallas-mode smoke bench child (~3 min)
-def test_bench_population_kernels_record_schema():
-    """--population P --kernels pallas composes: the record carries the
-    kernel mode next to the population A/B."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__), "..",
-                                      "bench.py"),
-         "--population", "2", "--kernels", "pallas", "--smoke",
-         "--iters", "1"],
-        capture_output=True, text=True, timeout=900,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert r.returncode == 0, r.stdout + r.stderr
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "experiments_per_sec"
-    assert rec["population"] == 2
-    assert rec["kernels"] == "pallas"
-    assert rec["value"] > 0

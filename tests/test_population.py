@@ -23,8 +23,6 @@ Pins the contracts the ISSUE-15 acceptance criteria stand on:
 
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -877,24 +875,3 @@ def test_population_sight_keys_per_member(tmp_path):
         assert any("grad_norm" in k for k in keys), keys
         assert any("per_ess" in k for k in keys), keys
         assert any("attn_entropy" in k for k in keys), keys
-
-
-@pytest.mark.slow
-def test_bench_population_record_schema(tmp_path):
-    """The --population leg emits one schema-1 record with the
-    experiment-throughput metric and the serialized A/B."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__), "..",
-                                      "bench.py"),
-         "--population", "2", "--smoke", "--iters", "1"],
-        capture_output=True, text=True, timeout=900,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert r.returncode == 0, r.stdout + r.stderr
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "experiments_per_sec"
-    assert rec["schema"] == 1
-    assert rec["population"] == 2
-    assert rec["value"] > 0
-    assert rec["serialized_experiments_per_sec"] > 0
-    assert rec["population_speedup"] > 0
-    assert rec["train_gate_open"] is True

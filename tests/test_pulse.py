@@ -1,10 +1,9 @@
 """graftpulse live telemetry plane (``t2omca_tpu/obs/pulse.py``,
-``memwatch.py``, ``timeline.py``; docs/OBSERVABILITY.md §pulse):
+``memwatch.py``; docs/OBSERVABILITY.md §pulse):
 MetricsHub rendering/probes/health, the HTTP endpoint routes, the
 on-demand trace trigger, HBM memwatch high-water attribution, the
-torn-tail/degraded-input contracts of the post-mortem readers, the
-timeline CLI over every bench-record shape, and — slow-marked — the
-acceptance paths: a live CPU run scraped mid-flight (env-steps/s +
+torn-tail/degraded-input contracts of the post-mortem readers, and —
+slow-marked — the acceptance paths: a live CPU run scraped mid-flight (env-steps/s +
 watchdog heartbeat-age gauges, /healthz flipping to degraded on a
 chaos-injected hang)."""
 
@@ -12,8 +11,6 @@ import glob
 import json
 import os
 import socket
-import subprocess
-import sys
 import threading
 import time
 import urllib.error
@@ -182,22 +179,6 @@ def test_pulse_server_routes(tmp_path):
     assert {"pulse.scrape", "trace.trigger"} <= KNOWN_PHASES
 
 
-def test_pulse_server_trace_unsupported_says_so():
-    """An endpoint with no TraceController behind it (the jax-free
-    bench daemon) must refuse /trace instead of acking an arm nothing
-    will ever consume."""
-    hub = MetricsHub()
-    srv = PulseServer(hub, 0, trace_supported=False).start()
-    try:
-        with pytest.raises(urllib.error.HTTPError) as ei:
-            _get(f"http://127.0.0.1:{srv.port}/trace")
-        assert ei.value.code == 501
-        assert "no trace consumer" in ei.value.read().decode()
-        assert not hub.take_trace_request()     # nothing latched
-    finally:
-        srv.close()
-
-
 def test_memwatch_keeps_verdict_over_transient_device_failure():
     """A transient device-list failure after successful snapshots must
     not flip the report to 'unsupported' over its own populated rows."""
@@ -244,8 +225,7 @@ def test_pulse_config_sanity():
         sanity_check(TrainConfig(obs=ObsConfig(pulse_port=-1)))
     with pytest.raises(ValueError):
         sanity_check(TrainConfig(obs=ObsConfig(pulse_window=4)))
-    # memwatch without the master switch is a dead knob (program_trace
-    # policy); with it, valid
+    # memwatch without the master switch is a dead knob; with it, valid
     with pytest.raises(ValueError):
         sanity_check(TrainConfig(obs=ObsConfig(memwatch=True)))
     sanity_check(TrainConfig(obs=ObsConfig(enabled=True, memwatch=True)))
@@ -256,7 +236,7 @@ def test_pulse_config_sanity():
 # ---------------------------------------------------------------------------
 
 class _StubWindow:
-    def __init__(self, trace_dir, out_dir=None, n_iterations=3):
+    def __init__(self, trace_dir, n_iterations=3):
         self.trace_dir = trace_dir
         self.n_iterations = n_iterations
         self._active = None
@@ -280,8 +260,8 @@ def test_trace_controller_file_trigger(tmp_path):
     rec = SpanRecorder(ring_size=32)
     made = []
 
-    def factory(trace_dir, out_dir=None, n_iterations=3):
-        w = _StubWindow(trace_dir, out_dir, n_iterations)
+    def factory(trace_dir, n_iterations=3):
+        w = _StubWindow(trace_dir, n_iterations)
         made.append(w)
         return w
 
@@ -315,8 +295,8 @@ def test_trace_controller_endpoint_trigger(tmp_path):
     made = []
     trc = TraceController(
         str(tmp_path), hub=hub, n_iterations=1,
-        window_factory=lambda d, out_dir=None, n_iterations=3:
-            made.append(_StubWindow(d, out_dir, n_iterations)) or made[-1])
+        window_factory=lambda d, n_iterations=3:
+            made.append(_StubWindow(d, n_iterations)) or made[-1])
     hub.request_trace()
     trc.poll(48)
     assert len(made) == 1
@@ -489,11 +469,10 @@ def test_report_flight_recorder_only_run_dir(tmp_path, capsys):
     assert main(["report", str(empty)]) == 2
 
 
-def test_report_empty_metrics_and_missing_device_times(tmp_path,
-                                                       capsys):
-    """Degraded inputs: device_times.json absent (fine, wall source)
-    and an EMPTY metrics.jsonl — the per-slice table must state 'no
-    data', not crash (PR 11's table reads this file)."""
+def test_report_spans_with_empty_or_torn_metrics(tmp_path, capsys):
+    """A run dir with spans and metrics only is all the report reads.
+    Degraded input: an EMPTY metrics.jsonl — the per-slice table must
+    state 'no data', not crash (PR 11's table reads this file)."""
     from t2omca_tpu.obs.__main__ import main
     run_dir = tmp_path / "run"
     _seed_spans(run_dir)
@@ -505,121 +484,6 @@ def test_report_empty_metrics_and_missing_device_times(tmp_path,
     # a metrics.jsonl with ONLY a torn line: tolerated the same way
     (run_dir / "metrics.jsonl").write_text('{"key": "slice0_retu')
     assert main(["report", str(run_dir)]) == 0
-
-
-# ---------------------------------------------------------------------------
-# timeline CLI (satellite: BENCH schema heterogeneity)
-# ---------------------------------------------------------------------------
-
-def test_timeline_row_classification(tmp_path, capsys):
-    from t2omca_tpu.obs.__main__ import main
-    # bare (r01-style inner record, no wrapper)
-    (tmp_path / "BENCH_r08.json").write_text(json.dumps(
-        {"metric": "env_steps_per_sec", "value": 9000.5,
-         "unit": "env-steps/s/chip", "vs_baseline": 0.18,
-         "schema": 1, "platform": "tpu", "superstep": 4}))
-    # wrapper with parsed=null but a parseable tail line
-    (tmp_path / "BENCH_r09.json").write_text(json.dumps(
-        {"n": 9, "rc": 0, "parsed": None,
-         "tail": 'noise\n{"metric": "env_steps_per_sec", "value": 8.0, '
-                 '"unit": "u", "vs_baseline": null}\n'}))
-    # wrapper with nothing parseable
-    (tmp_path / "BENCH_r10.json").write_text(json.dumps(
-        {"n": 10, "rc": 1, "tail": "Traceback (most recent call last)"}))
-    # unreadable file
-    (tmp_path / "BENCH_r11.json").write_text("{not json")
-    # a failed partial: schema'd record whose value never landed
-    (tmp_path / "BENCH_r12.json").write_text(json.dumps(
-        {"metric": "env_steps_per_sec", "value": None,
-         "unit": "env-steps/s/chip", "vs_baseline": None, "schema": 1,
-         "phase": "bench.compile", "error": "RuntimeError: boom"}))
-    rc = main(["timeline", *sorted(str(p) for p in
-                                   tmp_path.glob("BENCH_r*.json")),
-               "--json"])
-    assert rc == 0
-    rows = {r["name"]: r for r in
-            json.loads(capsys.readouterr().out)["rows"]}
-    assert rows["BENCH_r08"]["status"] == "measured"
-    assert rows["BENCH_r08"]["platform"] == "tpu"
-    assert "superstep=4" in rows["BENCH_r08"]["note"]
-    assert rows["BENCH_r09"]["status"] == "measured"    # tail rescue
-    assert rows["BENCH_r09"]["value"] == 8.0
-    assert rows["BENCH_r10"]["status"] == "no-record"
-    assert rows["BENCH_r11"]["status"] == "unreadable"
-    assert rows["BENCH_r12"]["status"] == "failed"
-    assert "phase=bench.compile" in rows["BENCH_r12"]["note"]
-
-
-def test_timeline_parses_kernels_train_leg_record(tmp_path, capsys):
-    """PR 13 satellite: the new ``--kernels`` TRAIN-step record
-    (``train_iters_per_sec``, one per kernel mode, emitted via
-    ``_finalize``) renders as a measured timeline row with the kernel
-    mode in the note — and a torn copy of the same record (the tail a
-    killed daemon leg leaves) degrades to a no-record row instead of
-    raising, keeping the t1 timeline prelude green."""
-    from t2omca_tpu.obs.__main__ import main
-    rec = {"metric": "train_iters_per_sec", "value": 26.42,
-           "unit": "train-iters/s/chip", "vs_baseline": None,
-           "kernels": "pallas", "leg": "kernels-pallas-train",
-           "train_batch_episodes": 32, "config": 3,
-           "schema": 1, "platform": "tpu", "host": "h"}
-    (tmp_path / "BENCH_r08.json").write_text(json.dumps(rec))
-    # wrapper-with-tail shape (the daemon relay), torn mid-record
-    torn = json.dumps(rec)[: len(json.dumps(rec)) // 2]
-    (tmp_path / "BENCH_r09.json").write_text(json.dumps(
-        {"n": 9, "rc": 1, "parsed": None, "tail": "noise\n" + torn}))
-    rc = main(["timeline", *sorted(str(p) for p in
-                                   tmp_path.glob("BENCH_r*.json")),
-               "--json"])
-    assert rc == 0
-    rows = {r["name"]: r for r in
-            json.loads(capsys.readouterr().out)["rows"]}
-    row = rows["BENCH_r08"]
-    assert row["status"] == "measured"
-    assert row["metric"] == "train_iters_per_sec"
-    assert row["value"] == 26.42
-    assert "kernels=pallas" in row["note"]
-    assert "leg=kernels-pallas-train" in row["note"]
-    assert rows["BENCH_r09"]["status"] == "no-record"   # torn, not raised
-
-
-def test_timeline_run_rows_and_torn_metrics(tmp_path, capsys,
-                                            monkeypatch):
-    from t2omca_tpu.obs.__main__ import main
-    run_dir = tmp_path / "run1"
-    run_dir.mkdir()
-    with open(run_dir / "metrics.jsonl", "w") as f:
-        f.write(json.dumps({"key": "env_steps_per_sec", "value": 100.0,
-                            "t": 12}) + "\n")
-        f.write(json.dumps({"key": "env_steps_per_sec", "value": 250.0,
-                            "t": 24}) + "\n")
-        f.write("null\n")       # corrupt line parsing to a bare scalar
-        f.write('{"key": "env_steps_per_s')        # torn tail
-    rc = main(["timeline", "--runs", str(run_dir), "--json"])
-    cap = capsys.readouterr()
-    assert rc == 0
-    rows = json.loads(cap.out)["rows"]
-    assert rows[0]["status"] == "run" and rows[0]["value"] == 250.0
-    assert "torn tail" in cap.err               # warned, not raised
-    # a run dir without metrics.jsonl is a stated row, not a crash
-    empty = tmp_path / "run2"
-    empty.mkdir()
-    assert main(["timeline", "--runs", str(empty)]) == 0
-    # nothing at all is the usage error
-    monkeypatch.chdir(tmp_path / "run2")
-    assert main(["timeline"]) == 2
-
-
-@pytest.mark.slow   # subprocess import check (~2 s interpreter startup)
-def test_timeline_cli_is_jax_free():
-    """The trajectory question gets asked from hosts that cannot
-    initialize a backend — the timeline CLI must not import jax."""
-    proc = subprocess.run(
-        [sys.executable, "-c",
-         "import t2omca_tpu.obs.timeline, t2omca_tpu.obs.__main__, sys; "
-         "assert 'jax' not in sys.modules, 'timeline imports jax'"],
-        cwd=REPO, capture_output=True, text=True, timeout=120)
-    assert proc.returncode == 0, proc.stderr[-1000:]
 
 
 # ---------------------------------------------------------------------------
@@ -658,32 +522,6 @@ def test_serve_frontend_hub_metrics():
     out = hub.render_prometheus()
     assert "t2omca_serve_sessions 2" in out
     assert "t2omca_serve_session_lru_fill 0.5" in out
-
-
-# ---------------------------------------------------------------------------
-# bench schema meta (satellite) — unit level, no subprocess
-# ---------------------------------------------------------------------------
-
-def _load_bench_module():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_finalize_uniform_schema_meta():
-    bench = _load_bench_module()
-    rec = bench._finalize({"metric": "env_steps_per_sec", "value": 1.0,
-                           "unit": "u", "vs_baseline": None})
-    assert rec["schema"] == bench.BENCH_SCHEMA == 1
-    assert rec["host"] == socket.gethostname()
-    assert "platform" in rec and "spans" in rec
-    # an existing platform (the live backend) is never clobbered by
-    # the env-pin default
-    rec2 = bench._finalize({"metric": "m", "platform": "tpu"})
-    assert rec2["platform"] == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -832,8 +670,8 @@ def test_healthz_degrades_on_injected_hang(tmp_path):
 @pytest.mark.slow
 def test_trace_trigger_on_live_run(tmp_path):
     """On-demand capture: touching <run_dir>/PULSE_TRACE mid-run arms a
-    bounded ProgramTraceWindow without a restart; the capture directory
-    and refreshed device_times.json land in the run dir."""
+    bounded TraceWindow without a restart; the capture directory lands
+    in the run dir."""
     from t2omca_tpu.run import run
     from t2omca_tpu.utils import resilience
     from t2omca_tpu.utils.logging import Logger

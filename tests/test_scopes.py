@@ -245,7 +245,7 @@ def test_injected_annotate_nests_lifo_and_survives_an_exception(tmp_path):
         ("enter", "fetch.train_stats"), ("exit", "fetch.train_stats", None),
         ("enter", "dispatch.wait"), ("exit", "dispatch.wait", KeyError),
         ("exit", "dispatch.superstep", None)]
-    assert rec._open_ann == {} and rec.current_phase() is None
+    assert rec._open_ann == {} and rec._open == {}
     import json
     with open(path) as f:
         events = [json.loads(line) for line in f]

@@ -4,7 +4,7 @@ Two tiers, matching the tier-1 budget reality (the 870s gate is nearly
 full): the host-side batching logic — bucket pick, mask-correct
 padding, session carry, meta round-trip, CLI usage errors — runs
 in-gate with no jit; everything that compiles (export → load → serve
-round-trips, the bench leg, the DP sharded resume) is ``slow``-marked.
+round-trips, the CLI, the DP sharded resume) is ``slow``-marked.
 The serve PROGRAM itself is still statically gated on every t1 run:
 the graftprog prelude lowers+compiles ``serve_step`` and ratchets its
 FLOPs/bytes/fingerprint (analysis/programs.json).
@@ -519,52 +519,8 @@ def test_dp_resume_restores_sharded_without_single_device_copy(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench + CLI e2e (slow: subprocesses)
+# CLI e2e (slow: subprocesses)
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_bench_serve_record_schema(exported):
-    """``bench.py --serve`` emits the BENCH-style record: p50/p99
-    decision latency + decisions/s/chip + the serve span phases."""
-    cfg, exp, ts, art, meta = exported
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--smoke", "--serve",
-         "--artifact", art, "--iters", "2"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [l for l in proc.stdout.splitlines() if l.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])
-    assert rec["metric"] == "serve_decisions_per_sec"
-    assert rec["unit"] == "decisions/s/chip"
-    assert rec["value"] > 0
-    assert 0 < rec["p50_ms"] <= rec["p99_ms"]
-    assert rec["buckets"] == meta["buckets"]
-    assert 1 in rec["request_sizes"]             # batch=1 latency counted
-    for phase in ("serve.load", "serve.pad", "serve.dispatch",
-                  "serve.unpad"):
-        assert phase in rec["spans"], rec["spans"].keys()
-
-
-@pytest.mark.slow
-def test_bench_serve_partial_record_on_failure(tmp_path):
-    """A failing serve leg (bad artifact) still leaves ONE parseable
-    partial record filed under the serve metric — the training legs'
-    flight-recorder contract."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--smoke", "--serve",
-         "--artifact", str(tmp_path / "missing")],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 1
-    lines = [l for l in proc.stdout.splitlines() if l.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])
-    assert rec["metric"] == "serve_decisions_per_sec"
-    assert rec["value"] is None
-    assert rec["error"]
 
 
 @pytest.mark.slow
