@@ -249,6 +249,19 @@ class MultiAgvOffloadingEnv:
 
     # ------------------------------------------------------------------ helpers
 
+    def _mec_lookup(self, table: jnp.ndarray,
+                    mec_index: jnp.ndarray) -> jnp.ndarray:
+        """Each agent's row of a per-MEC ``table (n_mec, ...)`` → ``(A, ...)``,
+        as a one-hot select over the ``n_mec`` rows rather than a gather: a
+        TPU gather fetches its A rows one after another, the select is
+        element-wise work that fuses into its consumer. Exactly one term of the sum is the
+        table's entry and the rest are exact zeros, so the result carries
+        the table's bits. An index outside ``[0, n_mec)`` — a padded agent's
+        sentinel (``_pad_sentinel``) — matches no row and reads zeros."""
+        hit = mec_index[:, None] == jnp.arange(self.n_mec)            # (A, M)
+        hit = hit.reshape(hit.shape + (1,) * (table.ndim - 1))
+        return jnp.where(hit, table[None], 0).sum(axis=1)
+
     def _random_positions(self, key: jax.Array, mec_index: jnp.ndarray,
                           params: EnvParams) -> jnp.ndarray:
         """M13: uniform point inside the serving MEC's communication circle."""
@@ -258,7 +271,8 @@ class MultiAgvOffloadingEnv:
         theta = jax.random.uniform(k2, (a,), maxval=2 * np.pi)
         rad = self.cfg.communication_range_m * jnp.sqrt(u)
         offset = jnp.stack([rad * jnp.cos(theta), rad * jnp.sin(theta)], axis=1)
-        return self.mec_positions(params)[mec_index] + offset
+        return self._mec_lookup(self.mec_positions(params), mec_index) \
+            + offset
 
     def _local_delay(self, data: jnp.ndarray, decimals: int,
                      params: EnvParams) -> jnp.ndarray:
@@ -287,10 +301,14 @@ class MultiAgvOffloadingEnv:
         interference divides it by ``1 + I/N0`` (algebraically the lifted
         noise floor ``N0 + I``), MEC compute delay divides by the cap
         scale — so the default (1/1/0/1) path runs the reference ops
-        bit-identically (see the ``_local_delay`` lowering note)."""
+        bit-identically (see the ``_local_delay`` lowering note). The
+        serving MEC's position is a one-hot select (``_mec_lookup``): a
+        padded agent's sentinel reads the origin, a dead value behind the
+        ``has_job`` gate of every caller."""
         gain_lin = 10.0 ** (self.channel_gain_db / 10.0)
-        d = jnp.linalg.norm(pos - self.mec_positions(params)[mec_index],
-                            axis=-1)
+        d = jnp.linalg.norm(
+            pos - self._mec_lookup(self.mec_positions(params), mec_index),
+            axis=-1)
         pl_db = 128.1 + 37.6 * jnp.log10(d + 0.1)
         pl_lin = self.path_loss_base ** (-pl_db / 10.0)
         snr = (gain_lin * self.cfg.transmit_power_w * pl_lin
@@ -543,8 +561,10 @@ class MultiAgvOffloadingEnv:
         from the stored ``mec_index`` with no schema change), and the
         collision histogram's ``one_hot`` maps out-of-range indices to
         zero rows, so padded agents never occupy a channel or count
-        toward utilization. All-active (the default) selects the real
-        indices bit-identically."""
+        toward utilization — and every per-MEC table lookup is the same
+        one-hot select (``_mec_lookup``), which reads zeros for them where
+        a gather would wrap onto some real row. All-active (the default)
+        selects the real indices bit-identically."""
         a = self.n_agents
         return jnp.where(params.agent_mask(a), mec_index,
                          -1 - jnp.arange(a, dtype=mec_index.dtype))
@@ -671,12 +691,14 @@ class MultiAgvOffloadingEnv:
         params = self._p(params)
         k_mec, k_pos, k_gen = jax.random.split(key, 3)
         a, j = self.n_agents, self.max_jobs
-        mec_index = self._pad_sentinel(
-            jax.random.randint(k_mec, (a,), 0, self.n_mec), params)
+        # positions from the draw itself, the sentinel after (the order
+        # ``_update_users`` teleports in): a padded agent starts inside a
+        # real MEC's circle, not around the origin its sentinel selects
+        mec_draw = jax.random.randint(k_mec, (a,), 0, self.n_mec)
         state = EnvState(
             time_slot=jnp.zeros((), jnp.int32),
-            mec_index=mec_index,
-            pos=self._random_positions(k_pos, mec_index, params),
+            mec_index=self._pad_sentinel(mec_draw, params),
+            pos=self._random_positions(k_pos, mec_draw, params),
             job_data=jnp.zeros((a, j), jnp.float32),
             job_deadline=jnp.zeros((a, j), jnp.float32),
             job_valid=jnp.zeros((a, j), bool),
@@ -705,7 +727,9 @@ class MultiAgvOffloadingEnv:
         the current-step reward (``parallel_runner.py:247-256``); this fuses
         both into one call. ``params`` is the lane's scenario instance —
         constant through the episode, resampled at reset by the runner's
-        scenario distribution (graftworld)."""
+        scenario distribution (graftworld). No gather anywhere in it: the
+        lookups by serving MEC and by action are one-hot selects
+        (``_mec_lookup``; pinned by tests/test_env.py's lowering test)."""
         params = self._p(params)
         mask = params.agent_mask(self.n_agents)
         actions = actions.astype(jnp.int32)
@@ -723,12 +747,16 @@ class MultiAgvOffloadingEnv:
         # utilization sums ALL slots incl. action-0 (reference :327-329 quirk)
         utilization = masked.sum() / (self.cfg.num_channels * self.n_mec)
 
-        chosen = masked[state.mec_index, actions]
+        # the agent's own cell of the histogram: its MEC's row, then its
+        # action's column — both one-hot selects (``_mec_lookup``), no gather
+        chosen = jnp.where(act1h > 0,
+                           self._mec_lookup(masked, state.mec_index),
+                           0).sum(axis=1)
         # explicit int32: a weak-typed ack in the carried state would give
         # the rollout program weak output avals and force a second compile
         # when the driver chains the state back in. Padded agents are
-        # pinned to ack 0 — their sentinel mec_index wraps the histogram
-        # gather, so the raw lookup could read any row
+        # pinned to ack 0 — their sentinel mec_index selects no row of the
+        # histogram, so the raw lookup reads 0 and would say "collision"
         ack = jnp.where(actions == 0, 0,
                         jnp.where(chosen == 1, 1, -1)).astype(jnp.int32)
         ack = jnp.where(mask, ack, 0)

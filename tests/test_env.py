@@ -421,3 +421,115 @@ def test_fuzz_invariants_over_random_trajectories():
             assert np.isfinite(float(reward))
             assert np.isfinite(np.asarray(obs)).all()
             assert np.isfinite(np.asarray(gstate)).all()
+
+
+# --------------------------------------------------------------- lookups
+
+class _GatherEnv(MultiAgvOffloadingEnv):
+    """The env as it was before the per-MEC lookups became one-hot
+    selects: the old indexing, kept here as the oracle."""
+
+    def _mec_lookup(self, table, mec_index):
+        return table[mec_index]
+
+
+def _lookup_rollout(env, params_b, key, lanes=4, steps=150):
+    """reset, then ``steps`` vmapped steps under random actions (even
+    lanes: legal ones, as the selector gives; odd lanes: anything) →
+    every output of every step, the states before each step and the
+    actions taken."""
+    def rollout(key):
+        k_reset, k_scan = jax.random.split(key)
+        st, obs, gs, avail = jax.vmap(env.reset, in_axes=(0, None, 0))(
+            jax.random.split(k_reset, lanes), None, params_b)
+        legal_only = (jnp.arange(lanes) % 2 == 0)[:, None]
+
+        def body(carry, k):
+            st, avail = carry
+            ka, ks = jax.random.split(k)
+            act = jax.random.randint(ka, (lanes, env.n_agents), 0,
+                                     env.n_actions)
+            legal = jnp.take_along_axis(avail, act[..., None], 2)[..., 0] > 0
+            act = jnp.where(legal_only & ~legal, 0, act)
+            out = jax.vmap(env.step)(st, act, jax.random.split(ks, lanes),
+                                     params_b)
+            probes = (jax.vmap(env.get_state)(out[0], params_b),
+                      jax.vmap(env.get_avail_actions)(out[0], params_b))
+            return (out[0], out[6]), (st.mec_index, act, out, probes)
+
+        _, ys = jax.lax.scan(body, (st, avail),
+                             jax.random.split(k_scan, steps))
+        return (st, obs, gs, avail), ys
+    return jax.jit(rollout)(key)
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["full", "padded"])
+@pytest.mark.parametrize("agv_num,mec_num,channels,fast_norm",
+                         [(4, 2, 2, False), (16, 4, 4, True),
+                          (64, 8, 8, True)],
+                         ids=["agv4", "agv16", "agv64"])
+def test_onehot_lookups_equal_the_gather_form_bit_for_bit(
+        agv_num, mec_num, channels, fast_norm, padded):
+    """The per-MEC lookups as one-hot selects (``_mec_lookup``) change no
+    bit of anything the env hands out: every leaf of ``EnvState``, reward,
+    every ``StepInfo`` field, obs, global state and availability of a
+    150-step rollout from ``reset`` equal the gather form's, with padded
+    agents (whose sentinel the gather wrapped onto a real row and the
+    select reads as zeros) and a stretched MEC placement too."""
+    kw = dict(agv_num=agv_num, mec_num=mec_num, num_channels=channels,
+              episode_limit=150, fast_norm=fast_norm)
+    env = make_env(**kw)
+    oracle = _GatherEnv(env.cfg)
+    lanes = 4
+    p = env.default_params()
+    if padded:
+        p = p.replace(n_active=jnp.asarray(agv_num - agv_num // 4 - 1,
+                                           jnp.int32),
+                      mec_scale=jnp.asarray(1.37, jnp.float32),
+                      teleport_prob=jnp.asarray(0.6, jnp.float32))
+    params_b = jax.vmap(lambda _: p)(jnp.arange(lanes))
+    key = jax.random.PRNGKey(30 + agv_num)
+    got = _lookup_rollout(env, params_b, key, lanes)
+    want = _lookup_rollout(oracle, params_b, key, lanes)
+    paths = jax.tree_util.tree_flatten_with_path(got)[0]
+    for (path, g), w in zip(paths, jax.tree.leaves(want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape, path
+        assert g.tobytes() == w.tobytes(), jax.tree_util.keystr(path)
+
+    # the histogram's cell by the old 2-D indexing, in numpy: ACK 1 where
+    # the agent is alone on its (MEC, channel), -1 where it is not
+    mec, act, out, _ = jax.tree.map(np.asarray, got[1])
+    ack = np.asarray(out[0].last_ack)
+    n_active = int(p.n_active)
+    for t in range(0, 150, 7):
+        for lane in range(lanes):
+            counts = np.zeros((mec_num, channels + 1), np.int64)
+            np.add.at(counts, (mec[t, lane, :n_active],
+                               act[t, lane, :n_active]), 1)
+            masked = np.where(counts > 1, 0, counts)
+            chosen = masked[mec[t, lane, :n_active], act[t, lane, :n_active]]
+            a = act[t, lane, :n_active]
+            np.testing.assert_array_equal(
+                ack[t, lane, :n_active],
+                np.where(a == 0, 0, np.where(chosen == 1, 1, -1)))
+            assert (ack[t, lane, n_active:] == 0).all()
+    assert (ack == 1).any() and (ack == -1).any()
+
+
+@pytest.mark.parametrize("fast_norm", [False, True],
+                         ids=["sequential-norm", "fast-norm"])
+def test_env_lowers_without_gathers(fast_norm):
+    """``step`` and ``reset`` hold no gather in their StableHLO (needs no
+    chip; on a TPU a gather fetches its rows one after another, which is
+    why the lookups by serving MEC are selects)."""
+    env = make_env(agv_num=16, mec_num=4, num_channels=4,
+                   fast_norm=fast_norm)
+    keys = jax.random.split(KEY, 8)
+    st = jax.eval_shape(jax.vmap(env.reset), keys)[0]
+    actions = jnp.zeros((8, 16), jnp.int32)
+    for text in (
+            jax.jit(jax.vmap(env.step)).lower(st, actions, keys).as_text(),
+            jax.jit(jax.vmap(env.reset)).lower(keys).as_text()):
+        assert "stablehlo.add" in text
+        assert "gather" not in text

@@ -145,6 +145,38 @@ def test_entity_attention_lowers_to_mosaic(v5e, shape, dtype):
     assert text.count("tpu_custom_call") == 1
 
 
+def test_env_step_compiles_without_gathers(v5e):
+    """The rollout program of the north-star file at a small shape (8
+    lanes x 16 AGVs, 8 steps) holds no gather under an env scope once
+    the chip's compiler is through with it: a TPU gather fetches its
+    rows one after another (65,536 of them a step at the cell's size,
+    PERF.md section 5), so the env's lookups by serving MEC are one-hot
+    selects (``MultiAgvOffloadingEnv._mec_lookup``)."""
+    import re
+
+    from t2omca_tpu.config import load_config
+    from t2omca_tpu.run import Experiment
+
+    cfg = load_config(
+        os.path.join(REPO, "configs", "config3_tpu_northstar.yaml"),
+        ("batch_size_run=8", "batch_size=4", "replay.buffer_size=16",
+         "env_args.agv_num=16", "env_args.mec_num=4",
+         "env_args.num_channels=4", "env_args.episode_limit=8",
+         "model.emb=32", "model.mixer_emb=32", "obs.pulse_port=0"))
+    exp = Experiment.build(cfg)
+    ts = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+        jax.eval_shape(lambda: exp.init_train_state(0)))
+    rollout = exp.jitted_programs(donate=True)[0]
+    text = rollout.lower(ts.learner.params["agent"], ts.runner,
+                         test_mode=False).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any("/env.step/" in n for n in names)    # the scopes are there
+    gathers = sorted(n for n in names if n.endswith("gather")
+                     and ("env." in n or "rollout.reset" in n))
+    assert not gathers, gathers
+
+
 @pytest.mark.slow   # ~1 min: the whole fused program through the TPU compiler
 def test_config3_programs_fit_one_v5e_chip(v5e):
     """The committed north-star file's training programs, at full
