@@ -15,9 +15,10 @@ applies in ``args_sanity_check`` (``/root/reference/per_run.py:292-309``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from dataclasses import dataclass, field
-from typing import Any, Optional, Tuple
+from typing import Any, Optional, Tuple, Union
 
 
 @dataclass(frozen=True)
@@ -111,21 +112,96 @@ class EnvConfig:
 
 
 @dataclass(frozen=True)
-class TrunkConfig:
+class LayerSpec:
+    """What one held layer IS (``TrunkSpec.layers``)."""
+
+    rope: bool                # rotary positions on q and k
+    window: int               # keys this far back are masked; 0: none are
+    dense_width: int          # a dense feed-forward of this width; 0: routed
+
+
+@dataclass(frozen=True)
+class TrunkSpec:
+    """A trunk's layers as MECHANISMS — the one description
+    ``models/trunk.py`` reads, resolved from either family's published
+    keys (``TrunkConfig.spec``, ``AfmoeTrunkConfig.spec``). Nothing here
+    names a model: a layer function branches on these fields alone."""
+
+    hidden_size: int
+    head_dim: int
+    heads_held: int
+    kv_heads_held: int
+    rms_norm_eps: float
+    rope_theta: float
+    # residual form: pre-norm (a norm on each sublayer's input), or
+    # sandwich (a second norm on each sublayer's OUTPUT, before the add)
+    sandwich_norm: bool
+    qk_norm: bool             # RMSNorm over head_dim on q and k, pre-RoPE
+    attn_gate: bool           # sigmoid(u W_g) on the heads' output
+    # router: what it reads (the layer's un-normed input, or the normed
+    # feed-forward input), its scores (softmax | sigmoid), a bias added
+    # for the SELECTION only, renormalisation over the kept, a scale
+    router_reads_input: bool
+    router_scores: str
+    router_bias: bool
+    route_norm: bool
+    route_scale: float
+    experts: int              # the router's outputs
+    top_k: int
+    experts_held: int
+    expert_offset: int
+    expert_width: int
+    expert_act: str           # relu | silu, gating every feed-forward
+    shared_width: int         # a shared expert beside the routed; 0: none
+    layers: Tuple[LayerSpec, ...]
+
+    @property
+    def expert_layers(self) -> int:
+        return sum(not layer.dense_width for layer in self.layers)
+
+
+class _TrunkShare:
+    """Which part of every layer THIS chip holds, from the fields both
+    families carry (``num_attention_heads``, ``num_key_value_heads``,
+    ``heads_held``, ``experts_held``, ``share_index``)."""
+
+    @property
+    def attention_ways(self) -> int:
+        return self.num_attention_heads // self.heads_held
+
+    @property
+    def kv_heads_held(self) -> int:
+        return self.num_key_value_heads // self.attention_ways
+
+    @property
+    def expert_offset(self) -> int:
+        """The first expert this chip holds."""
+        return self.share_index * self.experts_held
+
+
+@dataclass(frozen=True)
+class TrunkConfig(_TrunkShare):
     """A decoder trunk from the public catalog as the transformer agent's
     token stack (``models/trunk.py``; ``model.trunk`` — absent, the agent
-    is the T2OMCA stack and nothing here is read). The keys carry the
-    names of the model's own published ``config.json``; the last three
-    say which part of each layer THIS chip holds: the deployment divides a
-    layer's experts ``moe_num_primary_experts / experts_held`` ways and
-    its attention ``num_attention_heads / heads_held`` ways (each
-    attention share goes with ``num_key_value_heads`` over that many ways
-    key/value heads), and ``share_index`` is this chip's place in the
-    expert group — expert block ``share_index``, attention share
-    ``share_index`` modulo the attention ways. The router keeps all
-    ``moe_num_primary_experts`` outputs and ``moe_num_active_primary_experts``
-    experts a token at every share. The layer runs without its exchange:
-    the partial sums of this share are what the next layer reads."""
+    is the T2OMCA stack and nothing here is read). Two families are
+    written down, each under the key names of its own published
+    ``config.json``: this class (no ``model_type`` key; SmallThinker's
+    names: pre-norm residuals, a softmax top-k router reading the layer's
+    input, ReGLU experts, ``rope_layout`` / ``sliding_window_layout``) and
+    ``AfmoeTrunkConfig`` (``model_type: afmoe``). Both resolve to one
+    ``TrunkSpec`` (``.spec``), which is all the layer function reads.
+
+    The last three keys say which part of each layer THIS chip holds: the
+    deployment divides a layer's experts ``moe_num_primary_experts /
+    experts_held`` ways and its attention ``num_attention_heads /
+    heads_held`` ways (each attention share goes with
+    ``num_key_value_heads`` over that many ways key/value heads), and
+    ``share_index`` is this chip's place in the expert group — expert
+    block ``share_index``, attention share ``share_index`` modulo the
+    attention ways. The router keeps all ``moe_num_primary_experts``
+    outputs and ``moe_num_active_primary_experts`` experts a token at
+    every share. The layer runs without its exchange: the partial sums of
+    this share are what the next layer reads."""
 
     hidden_size: int = 2560
     head_dim: int = 128
@@ -149,18 +225,151 @@ class TrunkConfig:
     heads_held: int = 7
     share_index: int = 0
 
-    @property
-    def attention_ways(self) -> int:
-        return self.num_attention_heads // self.heads_held
+    def check(self) -> None:
+        """The family's own refusals (``sanity_check`` holds the shared
+        ones)."""
+        if (len(self.rope_layout) < self.num_hidden_layers
+                or len(self.sliding_window_layout) < self.num_hidden_layers):
+            raise ValueError("model.trunk: rope_layout / "
+                             "sliding_window_layout need an entry per layer")
+        if (not self.moe_primary_router_apply_softmax
+                or not self.norm_topk_prob):
+            raise ValueError("model.trunk covers the softmax top-k router "
+                             "with renormalised weights")
 
-    @property
-    def kv_heads_held(self) -> int:
-        return self.num_key_value_heads // self.attention_ways
+    @functools.cached_property
+    def spec(self) -> TrunkSpec:
+        return TrunkSpec(
+            hidden_size=self.hidden_size, head_dim=self.head_dim,
+            heads_held=self.heads_held, kv_heads_held=self.kv_heads_held,
+            rms_norm_eps=self.rms_norm_eps, rope_theta=self.rope_theta,
+            sandwich_norm=False, qk_norm=False, attn_gate=False,
+            router_reads_input=True, router_scores="softmax",
+            router_bias=False, route_norm=True, route_scale=1.0,
+            experts=self.moe_num_primary_experts,
+            top_k=self.moe_num_active_primary_experts,
+            experts_held=self.experts_held,
+            expert_offset=self.expert_offset,
+            expert_width=self.moe_ffn_hidden_size, expert_act="relu",
+            shared_width=0,
+            layers=tuple(
+                LayerSpec(rope=bool(self.rope_layout[i]),
+                          window=(self.sliding_window_size
+                                  if self.sliding_window_layout[i] else 0),
+                          dense_width=0)
+                for i in range(self.num_hidden_layers)))
 
-    @property
-    def expert_offset(self) -> int:
-        """The first expert this chip holds."""
-        return self.share_index * self.experts_held
+
+@dataclass(frozen=True)
+class AfmoeTrunkConfig(_TrunkShare):
+    """``model.trunk`` with ``model_type: afmoe`` (Trinity's family; the
+    keys by the names of its published ``config.json``): sandwich norms
+    (a norm on each sublayer's input AND on its output), RMSNorm on q and
+    k per head, an output gate on attention, rotary positions and a
+    window on the ``sliding_attention`` layers only; the first
+    ``num_dense_layers`` layers a dense SwiGLU feed-forward of
+    ``intermediate_size``, the others ``num_experts`` routed SwiGLU
+    experts of ``moe_intermediate_size`` (``num_experts_per_tok`` a
+    token: sigmoid scores of the normed feed-forward input, a bias that
+    enters the selection only, the kept scores renormalised and scaled by
+    ``route_scale``) beside ``num_shared_experts`` shared ones.
+
+    The share is ``TrunkConfig``'s (``experts_held``, ``heads_held``,
+    ``share_index``), and ``first_layer`` besides: the held layers are the
+    published layers ``first_layer … first_layer + num_hidden_layers -
+    1``, and ``layer_types`` / ``num_dense_layers`` are read at those
+    published indices (``num_dense_layers`` keeps its published value).
+    The shared experts and a dense layer's feed-forward are held whole:
+    every chip of the group computes them alike. Not carried: the load
+    statistic's update of the selection bias (``load_balance_coeff``) —
+    the bias is a parameter the optimizer never moves — and muP's
+    embedding multiplier (the trunk holds no vocabulary)."""
+
+    model_type: str = "afmoe"
+    hidden_size: int = 2048
+    head_dim: int = 128
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    num_hidden_layers: int = 5
+    num_dense_layers: int = 2
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    num_shared_experts: int = 1
+    score_func: str = "sigmoid"
+    route_norm: bool = True
+    route_scale: float = 2.826
+    n_group: int = 1
+    topk_group: int = 1
+    hidden_act: str = "silu"
+    layer_types: Tuple[str, ...] = ("sliding_attention",) * 3 + (
+        "full_attention",) + ("sliding_attention",) * 2
+    sliding_window: int = 2048
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10_000.0
+    experts_held: int = 8
+    heads_held: int = 8
+    share_index: int = 0
+    first_layer: int = 1
+
+    def check(self) -> None:
+        last = self.first_layer + self.num_hidden_layers
+        if self.first_layer < 0 or len(self.layer_types) < last:
+            raise ValueError(
+                f"model.trunk: layer_types has {len(self.layer_types)} "
+                f"entries, the held layers are {self.first_layer} … "
+                f"{last - 1}")
+        kinds = {"sliding_attention", "full_attention"}
+        if set(self.layer_types) - kinds:
+            raise ValueError(f"model.trunk: layer_types holds "
+                             f"{sorted(set(self.layer_types) - kinds)}")
+        if not 0 <= self.num_dense_layers < last:
+            raise ValueError(
+                f"model.trunk: num_dense_layers={self.num_dense_layers} "
+                f"lies past the held layers ({self.first_layer} … "
+                f"{last - 1}): no held layer would route")
+        if self.n_group != 1 or self.topk_group != 1:
+            raise ValueError("model.trunk: group-limited routing "
+                             "(n_group / topk_group other than 1) is not "
+                             "written")
+        if (self.score_func != "sigmoid" or self.hidden_act != "silu"
+                or self.num_shared_experts < 0):
+            raise ValueError("model.trunk (afmoe) covers sigmoid scores "
+                             "and SiLU-gated feed-forwards")
+
+    @functools.cached_property
+    def spec(self) -> TrunkSpec:
+        held = range(self.first_layer,
+                     self.first_layer + self.num_hidden_layers)
+        return TrunkSpec(
+            hidden_size=self.hidden_size, head_dim=self.head_dim,
+            heads_held=self.heads_held, kv_heads_held=self.kv_heads_held,
+            rms_norm_eps=self.rms_norm_eps, rope_theta=self.rope_theta,
+            sandwich_norm=True, qk_norm=True, attn_gate=True,
+            router_reads_input=False, router_scores=self.score_func,
+            router_bias=True, route_norm=self.route_norm,
+            route_scale=self.route_scale, experts=self.num_experts,
+            top_k=self.num_experts_per_tok,
+            experts_held=self.experts_held,
+            expert_offset=self.expert_offset,
+            expert_width=self.moe_intermediate_size,
+            expert_act=self.hidden_act,
+            shared_width=self.num_shared_experts * self.moe_intermediate_size,
+            layers=tuple(
+                LayerSpec(
+                    rope=self.layer_types[i] == "sliding_attention",
+                    window=(self.sliding_window
+                            if self.layer_types[i] == "sliding_attention"
+                            else 0),
+                    dense_width=(self.intermediate_size
+                                 if i < self.num_dense_layers else 0))
+                for i in held))
+
+
+#: ``model.trunk``'s dataclass by its ``model_type`` key (absent: the
+#: family whose published config has none)
+TRUNK_FAMILIES = {None: TrunkConfig, "afmoe": AfmoeTrunkConfig}
 
 
 @dataclass(frozen=True)
@@ -222,7 +431,7 @@ class ModelConfig:
     # a catalog decoder trunk in place of the T2OMCA stack (TrunkConfig;
     # emb must equal its hidden_size and depth its num_hidden_layers —
     # heads / ff_hidden_mult / standard_heads are then the mixer's alone)
-    trunk: Optional[TrunkConfig] = None
+    trunk: Optional[Union[TrunkConfig, AfmoeTrunkConfig]] = None
 
 
 @dataclass(frozen=True)
@@ -1059,36 +1268,36 @@ def sanity_check(cfg: TrainConfig) -> TrainConfig:
                 f"num_hidden_layers (got emb={cfg.model.emb}/"
                 f"{tk.hidden_size}, depth={cfg.model.depth}/"
                 f"{tk.num_hidden_layers})")
-        if (tk.num_attention_heads % tk.heads_held
-                or tk.num_key_value_heads % tk.attention_ways
+        if (tk.heads_held < 1 or tk.experts_held < 1
+                or tk.num_attention_heads % tk.heads_held):
+            raise ValueError(
+                f"model.trunk: heads_held={tk.heads_held} must divide "
+                f"num_attention_heads={tk.num_attention_heads}")
+        tk.check()
+        sp = tk.spec
+        if (tk.num_key_value_heads % tk.attention_ways
                 or tk.heads_held % tk.kv_heads_held
-                or tk.moe_num_primary_experts % tk.experts_held):
+                or sp.experts % tk.experts_held):
             raise ValueError(
                 f"model.trunk: heads_held={tk.heads_held} / experts_held="
                 f"{tk.experts_held} must divide the published counts "
                 f"({tk.num_attention_heads} heads over "
                 f"{tk.num_key_value_heads} key/value heads, "
-                f"{tk.moe_num_primary_experts} experts) evenly")
-        ways = tk.moe_num_primary_experts // tk.experts_held
+                f"{sp.experts} experts) evenly")
+        ways = sp.experts // tk.experts_held
         if ways % tk.attention_ways or not 0 <= tk.share_index < ways:
             raise ValueError(
                 f"model.trunk: share_index={tk.share_index} must lie in "
                 f"[0, {ways}) and the {tk.attention_ways} attention shares "
                 f"must divide the {ways} expert shares")
-        if (len(tk.rope_layout) < tk.num_hidden_layers
-                or len(tk.sliding_window_layout) < tk.num_hidden_layers
-                or tk.head_dim % 2):
-            raise ValueError("model.trunk: rope_layout / "
-                             "sliding_window_layout need an entry per layer "
-                             "and head_dim must be even")
-        if (not tk.moe_primary_router_apply_softmax or not tk.norm_topk_prob
-                or cfg.model.dropout or cfg.action_selector == "noisy-new"
+        if tk.head_dim % 2:
+            raise ValueError("model.trunk: head_dim must be even")
+        if (cfg.model.dropout or cfg.action_selector == "noisy-new"
                 or not cfg.env_args.obs_entity_mode
                 or cfg.model.n_entities_obs):
             raise ValueError(
-                "model.trunk covers the softmax top-k router with "
-                "renormalised weights, entity observations, no dropout "
-                "and no noisy head")
+                "model.trunk covers entity observations, no dropout and "
+                "no noisy head")
     if cfg.mixer == "transformer" and cfg.model.mixer_emb != cfg.model.emb:
         raise ValueError(
             "mixer_emb must equal emb: the transformer mixer concatenates "
@@ -1129,19 +1338,28 @@ def _coerce_scenario(base: ScenarioConfig, kw: dict) -> ScenarioConfig:
     return dataclasses.replace(base, **kw)
 
 
-def _coerce_trunk(base, kw: dict) -> Optional[TrunkConfig]:
+def _coerce_trunk(base, kw: dict):
     """``model.trunk`` in any of its written forms onto the frozen
-    TrunkConfig (lists become tuples); ``None`` with no keys stays
-    ``None``."""
+    dataclass of its family (``TRUNK_FAMILIES``, by the ``model_type``
+    key; lists become tuples); ``None`` with no keys stays ``None``."""
     if base is None and not kw:
         return None
-    if isinstance(base, TrunkConfig):
+    if dataclasses.is_dataclass(base):
         base = dataclasses.asdict(base)
     kw = dict(base or {}, **kw)
+    family = kw.get("model_type")
+    if family not in TRUNK_FAMILIES:
+        raise ValueError(f"model.trunk: model_type={family!r} is not "
+                         f"written; known: {sorted(map(str, TRUNK_FAMILIES))}")
     for k in ("rope_layout", "sliding_window_layout"):
         if k in kw:
             kw[k] = tuple(int(v) for v in kw[k])
-    return TrunkConfig(**kw)
+    if "layer_types" in kw:
+        kw["layer_types"] = tuple(str(v) for v in kw["layer_types"])
+    try:
+        return TRUNK_FAMILIES[family](**kw)
+    except TypeError as e:
+        raise KeyError(f"unknown config key under model.trunk: {e}") from e
 
 
 def _merge_nested(cfg: TrainConfig, updates: dict) -> TrainConfig:

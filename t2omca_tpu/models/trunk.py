@@ -1,37 +1,57 @@
 """A catalog decoder trunk as the transformer agent's token stack.
 
-``model.trunk`` (``config.TrunkConfig``) replaces the T2OMCA blocks of
-``TransformerAgent`` with the decoder layers of a public language model at
-their published widths — here written for SmallThinker's layer: RMSNorm
-pre-norm residuals, grouped-query causal attention with a published
-``head_dim``, rotary positions and a sliding window on the layers the
-layouts mark (none and the whole prefix on the others), a softmax top-k
-router that reads the layer's *input*, and ReGLU experts. The language
-model's embedding table and output head have no counterpart here: tokens
-are the agent's ``A`` entity rows (``feat_embedding``) followed by the
-hidden token that carries memory, outputs are ``q_basic`` of that token.
+``model.trunk`` replaces the T2OMCA blocks of ``TransformerAgent`` with
+the decoder layers of a public language model at their published widths.
+Two families are written down in ``config.py`` under their own published
+key names (``TrunkConfig``: SmallThinker's; ``AfmoeTrunkConfig``:
+Trinity's, ``model_type: afmoe``); both resolve to one ``TrunkSpec``
+(``tk.spec``), and that is all this module reads: what a layer IS is a
+set of mechanisms, and no branch here tests a family or a model's name.
+The language model's embedding table and output head have no counterpart:
+tokens are the agent's ``A`` entity rows (``feat_embedding``) followed by
+the hidden token that carries memory, outputs are ``q_basic`` of that
+token.
 
 Per agent-step the sequence is ``x = [E(e_1) … E(e_A), h_{t-1}]`` at
 positions ``0 … A``; the hidden token is LAST so that causal attention
-lets it read every entity. For layer ``l`` with input ``h``:
+lets it read every entity. With ``N`` RMSNorm (float32 statistics), a
+layer with input ``h`` is
 
-    r = softmax_topk(W_r h)                 float32, before the input norm
-    a = h + W_o GQA(RMSNorm(h))             RoPE + window where the layouts
-                                            say so
-    y = a + sum_{e in topk} r_e W_down,e (relu(W_gate,e m) * W_up,e m),
-                                            m = RMSNorm(a)
+    u = N(h; input_norm)
+    q, k, v = u W_q, u W_k, u W_v           grouped-query heads of head_dim
+    q, k = N_head(q), N_head(k)             [qk_norm] over head_dim, pre-RoPE
+    q, k = RoPE(q), RoPE(k)                 [layer.rope]
+    o = softmax(q kᵀ/√d + causal [∧ i-j < layer.window]) v       float32
+    att = (o [⊙ σ(u W_g): attn_gate]) W_o
+    a = h + [N(att; attn_out_norm): sandwich_norm | att]
+    m = N(a; post_norm)
+    f = dense:  W_down (act(W_gate m) ⊙ W_up m)            [layer.dense_width]
+        routed: Σ_{e ∈ top-k} r_e Expert_e(m) [+ Shared(m): shared_width]
+    y = a + [N(f; ff_out_norm): sandwich_norm | f]
 
-and after the last layer ``h_t = RMSNorm(y)[last]`` in float32.
+and after the last layer ``h_t = N(y)[last]`` in float32. The router
+(float32, all experts) reads ``h`` un-normed [router_reads_input] or
+``m``; its weights are the softmax over the kept logits
+[router_scores softmax], or sigmoid scores with the top-k taken of
+``score + expert_bias`` [router_bias: the bias enters the SELECTION only,
+so its gradient is exactly zero], the kept scores divided by their sum
+[route_norm] and multiplied by ``route_scale``. ``act`` is ``expert_act``
+(relu | silu) in every feed-forward.
 
 **One chip's share.** The layer is told which experts and heads this chip
 holds (``experts_held``, ``heads_held``, ``share_index``): the router
 scores all experts and keeps its ``k`` a token, the chip computes the
 token-expert pairs whose expert it holds, and ``W_o`` contracts the heads
-it holds. There is no exchange here — the partial sums are what the next
-layer reads, in the program and in its reference alike
-(``benchmark/reference/trunk.py``) — and no code stands in for the absent
-chips. ``tests/test_trunk.py`` ties the shares to the model: over every
-share of a small deployment the partial sums add up to the uncut layer.
+it holds; a shared expert and a dense layer's feed-forward are held whole
+(every chip of the group computes them alike). There is no exchange here
+— the partial sums are what the next layer reads, in the program and in
+its references alike (``benchmark/reference/trunk.py``,
+``benchmark/reference/afmoe.py``) — and no code stands in for the absent
+chips. Under pre-norm residuals the shares' layer outputs add up to the
+uncut layer; under sandwich norms the output norms are not linear, so the
+shares add up at the two SUBLAYER sums (``att`` and ``f``, before their
+norms), and each share normalises its own partial sum
+(``tests/test_trunk.py``, ``tests/test_trunk_afmoe.py``).
 
 **Routing is dropless, and its time does not depend on the routing.**
 Every held expert runs over every token as one wide product, and each
@@ -41,10 +61,10 @@ experts. All shapes are static and no pair can be left out under any skew.
 A grouped product over the held pairs alone (``jax.lax.ragged_dot``) costs
 less when the load is even, but its time follows the load: the entity
 tokens are few distinct vectors (an entity of another MEC is a masked
-row), an untrained router sends like tokens to the same six experts, and
-how many of those six this chip holds is the seed's draw — the same
+row), an untrained router sends like tokens to the same experts, and
+how many of those this chip holds is the seed's draw — the same
 program ran 32 to 59 s a period by seed
-(PERF.md par.6). One path, one cost.
+(PERF.md par.6). One path, one cost, for every family.
 
 Everything is plain ``jax.numpy`` over the parameter tree (the
 ``ops/query_slice.py`` pattern); ``TrunkAgent`` is the flax face that
@@ -53,17 +73,20 @@ declares the tree and serves ``BasicMAC.forward``.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ..config import TrunkConfig
-
 #: parameter leaves that stay float32 at every compute dtype: the router
-#: (a rounded logit flips the top-k choice), the norms' scales, the Q head
-KEEP_F32 = ("router", "input_norm", "post_norm", "norm", "q_basic")
+#: and its selection bias (a rounded logit flips the top-k choice), the
+#: norms' scales, the Q head
+KEEP_F32 = ("router", "expert_bias", "input_norm", "post_norm", "norm",
+            "q_norm", "k_norm", "attn_out_norm", "ff_out_norm", "q_basic")
+
+#: ``TrunkSpec.expert_act`` → the gate's activation in every feed-forward
+_ACT = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 #: float32 contractions that must not fall to the chip's default
 #: (bfloat16-pass) precision
@@ -101,51 +124,74 @@ def attention_mask(n: int, window: int) -> jnp.ndarray:
     return ok & (i - j < window) if window > 0 else ok
 
 
-def attention_part(lp: dict, h: jnp.ndarray, tk: TrunkConfig, layer: int,
+def attention_part(lp: dict, h: jnp.ndarray, tk, layer: int,
                    dtype) -> jnp.ndarray:
-    """This share's ``W_o · GQA(RMSNorm(h))``: ``h (S, n, d)`` → float32
-    ``(S, n, d)``. Softmax in float32 at every dtype (``n`` is tens)."""
+    """This share's ``W_o · GQA(RMSNorm(h))`` — with the spec's extras:
+    RMSNorm on q and k per head, the output gate — ``h (S, n, d)`` →
+    float32 ``(S, n, d)``, before any output norm. Softmax in float32 at
+    every dtype (``n`` is tens)."""
     s, n, _ = h.shape
-    hq, hkv, d = tk.heads_held, tk.kv_heads_held, tk.head_dim
-    x = rms_norm(h, lp["input_norm"], tk.rms_norm_eps).astype(dtype)
+    sp = tk.spec
+    ls = sp.layers[layer]
+    hq, hkv, d = sp.heads_held, sp.kv_heads_held, sp.head_dim
+    x = rms_norm(h, lp["input_norm"], sp.rms_norm_eps).astype(dtype)
     proj = lambda w, heads: jnp.dot(                         # noqa: E731
         x, w.astype(dtype), preferred_element_type=jnp.float32
     ).astype(dtype).reshape(s, n, heads, d)
     q, k, v = proj(lp["wq"], hq), proj(lp["wk"], hkv), proj(lp["wv"], hkv)
-    if tk.rope_layout[layer]:
-        q, k = rope(q, tk.rope_theta), rope(k, tk.rope_theta)
-    window = tk.sliding_window_size if tk.sliding_window_layout[layer] else 0
+    if sp.qk_norm:
+        q = rms_norm(q, lp["q_norm"], sp.rms_norm_eps).astype(dtype)
+        k = rms_norm(k, lp["k_norm"], sp.rms_norm_eps).astype(dtype)
+    if ls.rope:
+        q, k = rope(q, sp.rope_theta), rope(k, sp.rope_theta)
     q = q.reshape(s, n, hkv, hq // hkv, d)
     logits = jnp.einsum("sqhgd,skhd->shgqk", q, k,
                         preferred_element_type=jnp.float32) * d ** -0.5
-    logits = jnp.where(attention_mask(n, window), logits, -jnp.inf)
+    logits = jnp.where(attention_mask(n, ls.window), logits, -jnp.inf)
     attn = jax.nn.softmax(logits, axis=-1).astype(dtype)
     out = jnp.einsum("shgqk,skhd->sqhgd", attn, v,
                      preferred_element_type=jnp.float32)
-    return jnp.dot(out.astype(dtype).reshape(s, n, hq * d),
-                   lp["wo"].astype(dtype),
+    out = out.astype(dtype).reshape(s, n, hq * d)
+    if sp.attn_gate:
+        gate = jax.nn.sigmoid(jnp.dot(x, lp["wg"].astype(dtype),
+                                      preferred_element_type=jnp.float32))
+        out = (out * gate).astype(dtype)
+    return jnp.dot(out, lp["wo"].astype(dtype),
                    preferred_element_type=jnp.float32)
 
 
-def route(w_router: jnp.ndarray, h: jnp.ndarray, tk: TrunkConfig
+def route(w_router: jnp.ndarray, h: jnp.ndarray, tk, bias=None
           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Top-k routing of ``h (N, d)`` (the layer's input, un-normed) over
-    ALL experts, float32 → (weights ``(N, k)``, expert ids ``(N, k)``).
-    Softmax over every expert then renormalised over the kept ones is the
-    softmax over the kept logits."""
+    """Top-k routing of ``h (N, d)`` over ALL experts, float32 → (weights
+    ``(N, k)``, expert ids ``(N, k)``). Softmax scores: softmax over
+    every expert then renormalised over the kept ones is the softmax over
+    the kept logits. Sigmoid scores: the top-k is taken of ``score +
+    bias`` (``bias (experts,)``, selection only — the weights are the
+    unbiased scores'), renormalised over the kept ones (``route_norm``)
+    and scaled."""
+    sp = tk.spec
     logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=_HI)
-    top, idx = jax.lax.top_k(logits, tk.moe_num_active_primary_experts)
-    return jax.nn.softmax(top, axis=-1), idx
+    if sp.router_scores == "softmax":
+        top, idx = jax.lax.top_k(logits, sp.top_k)
+        return jax.nn.softmax(top, axis=-1), idx
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(
+        scores if bias is None else scores + bias.astype(jnp.float32),
+        sp.top_k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    if sp.route_norm:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return weights * sp.route_scale, idx
 
 
-def held_weights(weights: jnp.ndarray, idx: jnp.ndarray, tk: TrunkConfig
-                 ) -> jnp.ndarray:
+def held_weights(weights: jnp.ndarray, idx: jnp.ndarray, tk) -> jnp.ndarray:
     """Routing ``weights, idx (N, k)`` → float32 ``(N, experts_held)``:
     each token's weight for every expert held here, zero for one it did
     not choose. A pair whose expert is held elsewhere matches no column."""
-    chosen = ((idx - tk.expert_offset)[:, :, None]
-              == jnp.arange(tk.experts_held))
+    sp = tk.spec
+    chosen = ((idx - sp.expert_offset)[:, :, None]
+              == jnp.arange(sp.experts_held))
     return jnp.where(chosen, weights[:, :, None], 0.0).sum(axis=1)
 
 
@@ -163,9 +209,9 @@ def wide_experts(lp: dict, dtype):
     return gate.astype(dtype), up.astype(dtype), down.astype(dtype)
 
 
-def experts_part(lp: dict, m: jnp.ndarray, per: jnp.ndarray, dtype
-                 ) -> jnp.ndarray:
-    """This share's ``sum_e r_e W_down,e (relu(W_gate,e m) * W_up,e m)``:
+def experts_part(lp: dict, m: jnp.ndarray, per: jnp.ndarray, dtype,
+                 act=jax.nn.relu) -> jnp.ndarray:
+    """This share's ``sum_e r_e W_down,e (act(W_gate,e m) * W_up,e m)``:
     ``m (N, d)``, ``per (N, experts_held)`` of ``held_weights`` → float32
     ``(N, d)``. Every held expert over every token; the routing weight
     scales the activations, and the down projection contracts experts and
@@ -174,35 +220,89 @@ def experts_part(lp: dict, m: jnp.ndarray, per: jnp.ndarray, dtype
     gate, up, down = wide_experts(lp, dtype)
     x = m.astype(dtype)
     n, e = per.shape
-    g = jax.nn.relu(jnp.dot(x, gate, preferred_element_type=dtype))
+    g = act(jnp.dot(x, gate, preferred_element_type=dtype))
     u = jnp.dot(x, up, preferred_element_type=jnp.float32)
     weight = jnp.broadcast_to(per[:, :, None], (n, e, gate.shape[1] // e))
-    act = (g * u * weight.reshape(n, -1)).astype(dtype)
-    return jnp.dot(act, down, preferred_element_type=jnp.float32)
+    hidden = (g * u * weight.reshape(n, -1)).astype(dtype)
+    return jnp.dot(hidden, down, preferred_element_type=jnp.float32)
 
 
-def trunk_layer(lp: dict, h: jnp.ndarray, tk: TrunkConfig, layer: int,
-                dtype):
-    """One decoder layer over ``h (S, n, d)`` → (``y (S, n, d)`` in
-    ``dtype``, aux ``{"load": (experts_held,) pairs that entered the
-    product per held expert, "held": pairs the router sent to an expert
-    id in this share's range}`` — two counts of the same pairs from the
-    expert ids, by the product's own mask and by the range)."""
-    s, n, d = h.shape
+def gated_ffn(lp: dict, prefix: str, m: jnp.ndarray, dtype, act
+              ) -> jnp.ndarray:
+    """A whole gated feed-forward ``W_down (act(W_gate m) * W_up m)`` of
+    the kernels ``<prefix>_gate / _up / _down`` (a shared expert, a dense
+    layer's feed-forward): ``m (N, d)`` → float32 ``(N, d)``, at the
+    roundings of ``experts_part``."""
+    x = m.astype(dtype)
+    g = act(jnp.dot(x, lp[prefix + "_gate"].astype(dtype),
+                    preferred_element_type=dtype))
+    u = jnp.dot(x, lp[prefix + "_up"].astype(dtype),
+                preferred_element_type=jnp.float32)
+    return jnp.dot((g * u).astype(dtype), lp[prefix + "_down"].astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
+def _routing(lp: dict, x: jnp.ndarray, tk):
+    """The router over ``x (N, d)`` → (``held_weights``, the layer's
+    aux)."""
+    sp = tk.spec
     with jax.named_scope("agent.router"):
-        weights, idx = route(lp["router"], h.reshape(s * n, d), tk)
+        # a router without a selection bias is called as it always was
+        kw = {"bias": lp["expert_bias"]} if sp.router_bias else {}
+        weights, idx = route(lp["router"], x, tk, **kw)
         per = held_weights(weights, idx, tk)
-        lo = tk.expert_offset
+        lo = sp.expert_offset
         aux = {"load": (per > 0).sum(axis=0).astype(jnp.int32),
-               "held": ((idx >= lo) & (idx < lo + tk.experts_held)
+               "held": ((idx >= lo) & (idx < lo + sp.experts_held)
                         & (weights > 0)).sum().astype(jnp.int32)}
+    return per, aux
+
+
+def trunk_layer(lp: dict, h: jnp.ndarray, tk, layer: int, dtype):
+    """One decoder layer over ``h (S, n, d)`` → (``y (S, n, d)`` in
+    ``dtype``, aux). A routed layer's aux is ``{"load": (experts_held,)
+    pairs that entered the product per held expert, "held": pairs the
+    router sent to an expert id in this share's range}`` — two counts of
+    the same pairs from the expert ids, by the product's own mask and by
+    the range; a dense layer's is ``None``."""
+    s, n, d = h.shape
+    sp = tk.spec
+    ls = sp.layers[layer]
+    eps, act = sp.rms_norm_eps, _ACT[sp.expert_act]
+    per = aux = None
+    if not ls.dense_width and sp.router_reads_input:
+        per, aux = _routing(lp, h.reshape(s * n, d), tk)
     with jax.named_scope("agent.attention"):
-        a = (h.astype(jnp.float32)
-             + attention_part(lp, h, tk, layer, dtype)).astype(dtype)
+        residual = h.astype(jnp.float32)
+        att = attention_part(lp, h, tk, layer, dtype)
+        if sp.sandwich_norm:
+            att = rms_norm(att, lp["attn_out_norm"], eps)
+        a = (residual + att).astype(dtype)
+    if ls.dense_width:
+        with jax.named_scope("agent.dense"):
+            m = rms_norm(a, lp["post_norm"], eps).reshape(s * n, d)
+            residual = a.astype(jnp.float32)
+            f = gated_ffn(lp, "dense", m, dtype, act)
+            if sp.sandwich_norm:
+                f = rms_norm(f, lp["ff_out_norm"], eps)
+            y = (residual + f.reshape(s, n, d)).astype(dtype)
+        return y, None
     with jax.named_scope("agent.experts"):
-        m = rms_norm(a, lp["post_norm"], tk.rms_norm_eps).reshape(s * n, d)
-        y = (a.astype(jnp.float32)
-             + experts_part(lp, m, per, dtype).reshape(s, n, d)).astype(dtype)
+        m = rms_norm(a, lp["post_norm"], eps).reshape(s * n, d)
+    if per is None:
+        per, aux = _routing(lp, m, tk)
+    shared = None
+    if sp.shared_width:
+        with jax.named_scope("agent.shared"):
+            shared = gated_ffn(lp, "shared", m, dtype, act)
+    with jax.named_scope("agent.experts"):
+        residual = a.astype(jnp.float32)
+        f = experts_part(lp, m, per, dtype, act)
+        if shared is not None:
+            f = f + shared
+        if sp.sandwich_norm:
+            f = rms_norm(f, lp["ff_out_norm"], eps)
+        y = (residual + f.reshape(s, n, d)).astype(dtype)
     return y, aux
 
 
@@ -235,7 +335,7 @@ def cast_weights(params: dict, dtype) -> dict:
     out = jax.tree_util.tree_map_with_path(cast, p)
     layers = {}
     for name, lp in out["transformer"].items():
-        if isinstance(lp, dict):
+        if isinstance(lp, dict) and "w_gate" in lp:
             gate, up, down = wide_experts(lp, dtype)
             lp = dict(lp, w_gate=gate, w_up=up, w_down=down)
         layers[name] = lp
@@ -253,7 +353,7 @@ def _embed(p: dict, obs: jnp.ndarray, dtype) -> jnp.ndarray:
                 + fe["bias"].astype(jnp.float32)).astype(dtype)
 
 
-def _head(p: dict, last: jnp.ndarray, tk: TrunkConfig, shape):
+def _head(p: dict, last: jnp.ndarray, tk, shape):
     """The final norm of the hidden token's output ``(S, d)`` and the Q
     head, float32 → (q, hidden') shaped ``shape + (·,)``."""
     with jax.named_scope("agent.head"):
@@ -265,13 +365,13 @@ def _head(p: dict, last: jnp.ndarray, tk: TrunkConfig, shape):
 
 
 def agent_forward_trunk(variables: dict, obs: jnp.ndarray,
-                        hidden: jnp.ndarray, *, tk: TrunkConfig, dtype):
+                        hidden: jnp.ndarray, *, tk, dtype):
     """``obs (B, A, A, 9)`` normalised entity tokens, ``hidden (B, A, d)``
     → (q ``(B, A, n_actions)`` float32, hidden' ``(B, A, d)`` float32,
-    aux) with ``aux`` the layers' (``trunk_layer``) stacked: ``{"load":
-    (layers, experts_held), "held": (layers,)}`` — the sources of the
-    ``moe_*`` counters (``moe_counters``). The one entry: acting calls it
-    a step, the learner scans it (``unroll``)."""
+    aux) with ``aux`` the ROUTED layers' (``trunk_layer``) stacked:
+    ``{"load": (routed layers, experts_held), "held": (routed layers,)}``
+    — the sources of the ``moe_*`` counters (``moe_counters``). The one
+    entry: acting calls it a step, the learner scans it (``unroll``)."""
     p = variables.get("params", variables)
     b, a = obs.shape[:2]
     h = jnp.concatenate(
@@ -281,13 +381,14 @@ def agent_forward_trunk(variables: dict, obs: jnp.ndarray,
     for layer in range(tk.num_hidden_layers):
         h, aux = trunk_layer(p["transformer"][f"layer_{layer}"], h, tk,
                              layer, dtype)
-        auxes.append(aux)
+        if aux is not None:
+            auxes.append(aux)
     aux = jax.tree.map(lambda *x: jnp.stack(x), *auxes)
     return _head(p, h[:, -1, :], tk, (b, a)) + (aux,)
 
 
 def unroll(variables: dict, obs_tm: jnp.ndarray, hidden: jnp.ndarray, *,
-           tk: TrunkConfig, dtype, wrap=lambda f: f):
+           tk, dtype, wrap=lambda f: f):
     """The agent over the steps of ``obs_tm (T, B, A, A, 9)``, its hidden
     token carried from ``hidden`` → (q ``(T, B, A, n_actions)``, hiddens
     ``(T, B, A, d)``, aux stacked over the steps): a scan of
@@ -302,11 +403,12 @@ def unroll(variables: dict, obs_tm: jnp.ndarray, hidden: jnp.ndarray, *,
     return out
 
 
-def moe_counters(aux: dict, tokens: int, tk: TrunkConfig) -> dict:
+def moe_counters(aux: dict, tokens: int, tk) -> dict:
     """The four counters of the training info rows and the rollout stats
     from the ``aux`` of the forwards they cover, stacked over any leading
     axes (a scan's steps) and summed here (``tokens``: the tokens those
-    forwards routed, a static count). ``moe_load_max``: per layer the
+    forwards routed, a static count); routed layers only — a dense layer
+    routes nothing. ``moe_load_max``: per layer the
     busiest held expert's pairs, summed over the layers, so that
     ``moe_load_max / moe_pairs_held`` is ``1 / experts_held`` under an
     even load. ``moe_dropped``: pairs the router sent to this share's
@@ -318,8 +420,8 @@ def moe_counters(aux: dict, tokens: int, tk: TrunkConfig) -> dict:
     return {
         "moe_pairs_held": load.sum(),
         "moe_pairs_routed": jnp.asarray(
-            float(tokens) * tk.moe_num_active_primary_experts
-            * tk.num_hidden_layers, jnp.float32),
+            float(tokens) * tk.spec.top_k * tk.spec.expert_layers,
+            jnp.float32),
         "moe_load_max": load.max(axis=-1).sum(),
         "moe_dropped": aux["held"].astype(jnp.float32).sum() - load.sum(),
     }
@@ -344,39 +446,70 @@ class _Dense(nn.Module):
 
 
 class _Layer(nn.Module):
-    """One layer's parameters: this chip's share."""
-    trunk: TrunkConfig
+    """One layer's parameters: this chip's share, by what the spec says
+    the layer has."""
+    trunk: Any
+    layer: int
 
     @nn.compact
     def __call__(self) -> dict:
-        tk = self.trunk
-        d, f, e = tk.hidden_size, tk.moe_ffn_hidden_size, tk.experts_held
-        hq, hkv = tk.heads_held * tk.head_dim, tk.kv_heads_held * tk.head_dim
+        sp = self.trunk.spec
+        ls = sp.layers[self.layer]
+        d, f, e = sp.hidden_size, sp.expert_width, sp.experts_held
+        hq, hkv = sp.heads_held * sp.head_dim, sp.kv_heads_held * sp.head_dim
         init = nn.initializers.lecun_normal()
         per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
         ones = nn.initializers.ones
-        return {
+        out = {
             "input_norm": self.param("input_norm", ones, (d,)),
             "wq": self.param("wq", init, (d, hq)),
             "wk": self.param("wk", init, (d, hkv)),
             "wv": self.param("wv", init, (d, hkv)),
             "wo": self.param("wo", init, (hq, d)),
-            "router": self.param("router", init,
-                                 (d, tk.moe_num_primary_experts)),
             "post_norm": self.param("post_norm", ones, (d,)),
+        }
+        if sp.qk_norm:
+            out["q_norm"] = self.param("q_norm", ones, (sp.head_dim,))
+            out["k_norm"] = self.param("k_norm", ones, (sp.head_dim,))
+        if sp.attn_gate:
+            out["wg"] = self.param("wg", init, (d, hq))
+        if sp.sandwich_norm:
+            out["attn_out_norm"] = self.param("attn_out_norm", ones, (d,))
+            out["ff_out_norm"] = self.param("ff_out_norm", ones, (d,))
+
+        def ffn(prefix, width):
+            out[prefix + "_gate"] = self.param(prefix + "_gate", init,
+                                               (d, width))
+            out[prefix + "_up"] = self.param(prefix + "_up", init,
+                                             (d, width))
+            out[prefix + "_down"] = self.param(prefix + "_down", init,
+                                               (width, d))
+        if ls.dense_width:
+            ffn("dense", ls.dense_width)
+            return out
+        out.update({
+            "router": self.param("router", init, (d, sp.experts)),
             "w_gate": self.param("w_gate", per_expert, (e, d, f)),
             "w_up": self.param("w_up", per_expert, (e, d, f)),
             "w_down": self.param("w_down", per_expert, (e, f, d)),
-        }
+        })
+        if sp.router_bias:
+            # a checkpoint's selection bias is not zero, and at zero a
+            # fault in how it enters would be invisible: N(0, 0.02^2)
+            out["expert_bias"] = self.param(
+                "expert_bias", nn.initializers.normal(0.02), (sp.experts,))
+        if sp.shared_width:
+            ffn("shared", sp.shared_width)
+        return out
 
 
 class _Stack(nn.Module):
-    trunk: TrunkConfig
+    trunk: Any
 
     @nn.compact
     def __call__(self) -> dict:
         tk = self.trunk
-        out = {f"layer_{i}": _Layer(tk, name=f"layer_{i}")()
+        out = {f"layer_{i}": _Layer(tk, i, name=f"layer_{i}")()
                for i in range(tk.num_hidden_layers)}
         out["norm"] = self.param("norm", nn.initializers.ones,
                                  (tk.hidden_size,))
@@ -394,7 +527,7 @@ class TrunkAgent(nn.Module):
     feat_dim: int
     emb: int
     n_actions: int
-    trunk: TrunkConfig
+    trunk: Any
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact

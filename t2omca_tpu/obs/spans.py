@@ -147,8 +147,12 @@ KNOWN_SCOPES = frozenset({
     # under learner.agent / learner.mixer / learner.target alike
     "agent.embed", "agent.attention", "agent.ff", "agent.head",
     # a catalog trunk's layers (models/trunk.py): the router product,
-    # top-k and the held experts' weights a token; the experts' products
-    "agent.router", "agent.experts",
+    # its scores, selection bias, top-k, renormalisation and the held
+    # experts' weights a token; the feed-forward's input norm, the routed
+    # experts' products and the output norm; a shared expert's products;
+    # a dense layer's feed-forward (not agent.ff: the mixer's block opens
+    # that one too)
+    "agent.router", "agent.experts", "agent.shared", "agent.dense",
     # replay ring (components/episode_buffer.py)
     "replay.insert", "replay.sample", "replay.priority",
     # learner (learners/qmix_learner.py)

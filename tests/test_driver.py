@@ -52,6 +52,10 @@ COMMITTED_PRESETS = {
     "config8_trunk_smallthinker": (32, 16, 0, lambda c:
                                    c.model.trunk is not None
                                    and c.model.trunk.experts_held == 8),
+    "config9_trunk_trinity": (8, 16, 0, lambda c:
+                              c.model.trunk.model_type == "afmoe"
+                              and c.superstep == 1
+                              and c.log_interval == 1200),
     "serve_smoke": (4, 4, 0, lambda c: c.env_args.episode_limit == 8),
 }
 

@@ -34,8 +34,10 @@ TRAIN = {"replay.sample", "replay.priority", "learner.agent",
          "learner.optimizer", "sight", "agent.embed", "agent.attention",
          "agent.ff", "agent.head"}
 #: opened by a catalog trunk's layers alone (models/trunk.py), which in
-#: turn have no ``agent.ff``
-TRUNK_ONLY = {"agent.router", "agent.experts"}
+#: turn have no ``agent.ff``: the first two by every trunk, the last two
+#: where the layers have a shared expert / a dense feed-forward
+ROUTED = {"agent.router", "agent.experts"}
+TRUNK_ONLY = ROUTED | {"agent.shared", "agent.dense"}
 CARRIES = {"_rollout": ROLLOUT, "_insert": {"replay.insert"},
            "_train_iter": TRAIN,
            "_superstep": set(KNOWN_SCOPES) - TRUNK_ONLY}
@@ -60,19 +62,33 @@ def tiny(**kw):
     return sanity_check(from_dict(d))
 
 
-def tiny_trunk():
+TRUNKS = {
+    "smallthinker": {"hidden_size": 16, "head_dim": 4,
+                     "num_attention_heads": 4, "num_key_value_heads": 2,
+                     "num_hidden_layers": 2, "moe_ffn_hidden_size": 8,
+                     "moe_num_primary_experts": 4,
+                     "moe_num_active_primary_experts": 2,
+                     "rope_layout": [0, 1],
+                     "sliding_window_layout": [0, 1],
+                     "sliding_window_size": 2, "experts_held": 2,
+                     "heads_held": 2},
+    # published layers 1-2 of 3: a dense and a routed layer
+    "afmoe": {"model_type": "afmoe", "hidden_size": 16, "head_dim": 4,
+              "num_attention_heads": 4, "num_key_value_heads": 2,
+              "num_hidden_layers": 2, "num_dense_layers": 2,
+              "intermediate_size": 12, "moe_intermediate_size": 8,
+              "num_experts": 4, "num_experts_per_tok": 2,
+              "layer_types": ["sliding_attention", "full_attention",
+                              "sliding_attention"],
+              "sliding_window": 2, "experts_held": 2, "heads_held": 2,
+              "first_layer": 1}}
+
+
+def tiny_trunk(family: str = "smallthinker"):
     """``tiny`` with a catalog trunk as the agent's stack."""
     model = {"emb": 16, "depth": 2, "mixer_emb": 16, "mixer_heads": 2,
              "mixer_depth": 2, "standard_heads": True, "remat": True,
-             "trunk": {"hidden_size": 16, "head_dim": 4,
-                       "num_attention_heads": 4, "num_key_value_heads": 2,
-                       "num_hidden_layers": 2, "moe_ffn_hidden_size": 8,
-                       "moe_num_primary_experts": 4,
-                       "moe_num_active_primary_experts": 2,
-                       "rope_layout": [0, 1],
-                       "sliding_window_layout": [0, 1],
-                       "sliding_window_size": 2, "experts_held": 2,
-                       "heads_held": 2}}
+             "trunk": TRUNKS[family]}
     return tiny(model=model)
 
 
@@ -177,19 +193,23 @@ def test_program_carries_its_scopes(texts, program):
     assert not any(token(s).search(debug) for s in TRUNK_ONLY)
 
 
+@pytest.mark.parametrize("family", sorted(TRUNKS))
 @pytest.mark.parametrize("program,scopes", [
-    ("_rollout", (ROLLOUT | TRUNK_ONLY) - {"agent.ff"}),
-    ("_train_iter", TRAIN | TRUNK_ONLY),
-    ("_superstep", set(KNOWN_SCOPES))],
+    ("_rollout", ROLLOUT - {"agent.ff"}),
+    ("_train_iter", TRAIN),
+    ("_superstep", set(KNOWN_SCOPES) - TRUNK_ONLY)],
     ids=["_rollout", "_train_iter", "_superstep"])
-def test_trunk_program_carries_the_trunk_scopes(program, scopes):
+def test_trunk_program_carries_the_trunk_scopes(program, scopes, family):
     """A ``model.trunk`` configuration opens ``agent.router`` and
     ``agent.experts`` beside the other ``agent.*`` — under ``act.forward``
     and under the learner's scopes (``checkpoint`` bodies, forward and
     backward) — and no ``agent.ff`` outside the mixer's blocks (the
-    rollout has no mixer)."""
-    debug, _ = _trunk_texts()[program]
-    assert {s for s in scopes if not token(s).search(debug)} == set()
+    rollout has no mixer); ``agent.shared`` and ``agent.dense`` exactly
+    where the layers have a shared expert and a dense feed-forward."""
+    debug, _ = _trunk_texts(family)[program]
+    mine = TRUNK_ONLY if family == "afmoe" else ROUTED
+    assert {s for s in scopes | mine if not token(s).search(debug)} == set()
+    assert not any(token(s).search(debug) for s in TRUNK_ONLY - mine)
     if program == "_rollout":
         assert not token("agent.ff").search(debug)
     else:
@@ -197,13 +217,13 @@ def test_trunk_program_carries_the_trunk_scopes(program, scopes):
         assert re.search(r"rematted_computation/agent\.experts", debug)
 
 
-_TRUNK_TEXTS = []
+_TRUNK_TEXTS = {}
 
 
-def _trunk_texts():
-    if not _TRUNK_TEXTS:
-        _TRUNK_TEXTS.append(_lowered_texts(tiny_trunk()))
-    return _TRUNK_TEXTS[0]
+def _trunk_texts(family):
+    if family not in _TRUNK_TEXTS:
+        _TRUNK_TEXTS[family] = _lowered_texts(tiny_trunk(family))
+    return _TRUNK_TEXTS[family]
 
 
 @pytest.mark.parametrize("program", sorted(CARRIES))
