@@ -35,7 +35,10 @@ and after the last layer ``h_t = N(y)[last]`` in float32. The router
 [router_scores softmax], or sigmoid scores with the top-k taken of
 ``score + expert_bias`` [router_bias: the bias enters the SELECTION only,
 so its gradient is exactly zero], the kept scores divided by their sum
-[route_norm] and multiplied by ``route_scale``. ``act`` is ``expert_act``
+[route_norm] and multiplied by ``route_scale``. The top-k is taken one
+maximum at a time, as one-hot planes over the experts, and read through
+them (``route``): ``jax.lax.top_k``'s ids in its order, with no sort,
+gather or scatter in the compiled program. ``act`` is ``expert_act``
 (relu | silu) in every feed-forward.
 
 **One chip's share.** The layer is told which experts and heads this chip
@@ -163,23 +166,40 @@ def attention_part(lp: dict, h: jnp.ndarray, tk, layer: int,
 def route(w_router: jnp.ndarray, h: jnp.ndarray, tk, bias=None
           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Top-k routing of ``h (N, d)`` over ALL experts, float32 → (weights
-    ``(N, k)``, expert ids ``(N, k)``). Softmax scores: softmax over
-    every expert then renormalised over the kept ones is the softmax over
-    the kept logits. Sigmoid scores: the top-k is taken of ``score +
-    bias`` (``bias (experts,)``, selection only — the weights are the
-    unbiased scores'), renormalised over the kept ones (``route_norm``)
-    and scaled."""
+    ``(N, k)``, expert ids ``(N, k)``), ids and their order those of
+    ``jax.lax.top_k`` (largest first; equal values: lower id first).
+    Softmax scores: softmax over every expert then renormalised over the
+    kept ones is the softmax over the kept logits. Sigmoid scores: the
+    top-k is taken of ``score + bias`` (``bias (experts,)``, selection
+    only — the weights are the unbiased scores'), renormalised over the
+    kept ones (``route_norm``) and scaled.
+
+    The selection is ``k`` unrolled passes over the ``(N, E)`` plane —
+    the row's first maximum as a one-hot over the lanes, that lane masked
+    to ``-inf`` for the next pass — and every value that follows is read
+    through those one-hots as a masked sum, whose transpose is a masked
+    broadcast: no sort, gather or scatter. On a TPU a sort orders all
+    ``E`` lanes to keep ``k``, and a gather or scatter moves its
+    elements one after another (PERF.md par.5)."""
     sp = tk.spec
     logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=_HI)
-    if sp.router_scores == "softmax":
-        top, idx = jax.lax.top_k(logits, sp.top_k)
-        return jax.nn.softmax(top, axis=-1), idx
-    scores = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(
-        scores if bias is None else scores + bias.astype(jnp.float32),
-        sp.top_k)
-    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    softmax = sp.router_scores == "softmax"
+    values = logits if softmax else jax.nn.sigmoid(logits)
+    # what is ranked carries no gradient: the ids are integers
+    ranked = jax.lax.stop_gradient(
+        values if bias is None else values + bias.astype(jnp.float32))
+    lanes = jax.lax.broadcasted_iota(jnp.int32, ranked.shape, 1)
+    kept, idx = [], []
+    for _ in range(sp.top_k):
+        first = jnp.argmax(ranked, axis=-1)     # ties: the lower lane
+        hot = lanes == first[:, None]
+        ranked = jnp.where(hot, -jnp.inf, ranked)
+        kept.append(jnp.where(hot, values, 0.0).sum(axis=-1))
+        idx.append(first)
+    weights, idx = jnp.stack(kept, axis=-1), jnp.stack(idx, axis=-1)
+    if softmax:
+        return jax.nn.softmax(weights, axis=-1), idx
     if sp.route_norm:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
     return weights * sp.route_scale, idx

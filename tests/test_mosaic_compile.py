@@ -14,6 +14,7 @@ separate processes abort on libtpu's lock file (/tmp/libtpu_lockfile).
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
 
@@ -46,6 +47,12 @@ def v5e():
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+def _place(tree, sharding):
+    """``tree``'s shapes and dtypes on the described device."""
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding), tree)
 
 
 # (B, H, Tq, Tk, D) as configs/config3_tpu_northstar.yaml traces them
@@ -152,8 +159,6 @@ def test_env_step_compiles_without_gathers(v5e):
     rows one after another (65,536 of them a step at the cell's size,
     PERF.md section 5), so the env's lookups by serving MEC are one-hot
     selects (``MultiAgvOffloadingEnv._mec_lookup``)."""
-    import re
-
     from t2omca_tpu.config import load_config
     from t2omca_tpu.run import Experiment
 
@@ -164,9 +169,7 @@ def test_env_step_compiles_without_gathers(v5e):
          "env_args.num_channels=4", "env_args.episode_limit=8",
          "model.emb=32", "model.mixer_emb=32", "obs.pulse_port=0"))
     exp = Experiment.build(cfg)
-    ts = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
-        jax.eval_shape(lambda: exp.init_train_state(0)))
+    ts = _place(jax.eval_shape(lambda: exp.init_train_state(0)), v5e)
     rollout = exp.jitted_programs(donate=True)[0]
     text = rollout.lower(ts.learner.params["agent"], ts.runner,
                          test_mode=False).compile().as_text()
@@ -175,6 +178,52 @@ def test_env_step_compiles_without_gathers(v5e):
     gathers = sorted(n for n in names if n.endswith("gather")
                      and ("env." in n or "rollout.reset" in n))
     assert not gathers, gathers
+
+
+#: HLO opcodes (and the custom call XLA may put in ``top_k``'s place) that
+#: the router must not compile to
+_SERIAL = re.compile(r" (sort|gather|scatter)\(|custom_call_target=\"TopK")
+
+
+@pytest.mark.parametrize("config", ["config8_trunk_smallthinker.yaml",
+                                    "config9_trunk_trinity.yaml"])
+@pytest.mark.parametrize("program", ["acting", "loss-gradient"])
+def test_router_compiles_without_sort_gather_scatter(v5e, config, program):
+    """Both shipped trunks at their published widths, cut to 2 lanes, 2
+    episodes, 4 steps and their first 2 layers (Trinity's: the dense
+    layer and a routed one): once the chip's compiler is through with the
+    acting forward and with the learner's loss gradient, no instruction
+    under ``agent.router`` is a sort (``lax.top_k`` orders all 64 / 128
+    experts to keep 6 / 8), a gather (``take_along_axis`` fetches one
+    element a kept pair) or a scatter (its transpose): ``trunk.route``
+    selects and reads through one-hot planes (PERF.md section 6, PR 32)."""
+    from t2omca_tpu.config import load_config
+    from t2omca_tpu.run import Experiment
+
+    cfg = load_config(
+        os.path.join(REPO, "configs", config),
+        ("batch_size_run=2", "batch_size=2", "replay.buffer_size=4",
+         "env_args.episode_limit=4", "obs.pulse_port=0", "model.depth=2",
+         "model.trunk.num_hidden_layers=2"))
+    exp = Experiment.build(cfg)
+    ts = _place(jax.eval_shape(lambda: exp.init_train_state(0)), v5e)
+    agent = ts.learner.params["agent"]
+    if program == "acting":
+        lowered = exp.jitted_programs(donate=True)[0].lower(
+            agent, ts.runner, test_mode=False)
+    else:
+        batch = _place(jax.eval_shape(
+            lambda p, r: exp.runner.run(p, r), agent, ts.runner)[1], v5e)
+        w = jax.ShapeDtypeStruct((batch.reward.shape[0],), jnp.float32,
+                                 sharding=v5e)
+        lowered = jax.jit(jax.grad(
+            lambda p, t, b, w: exp.learner._loss(p, t, b, w)[0])).lower(
+                ts.learner.params, ts.learner.target_params, batch, w)
+    routed = [line for line in lowered.compile().as_text().splitlines()
+              if "agent.router" in line]
+    assert routed                                   # the scope is there
+    serial = [line.strip()[:200] for line in routed if _SERIAL.search(line)]
+    assert not serial, serial
 
 
 @pytest.mark.slow   # ~1 min: the whole fused program through the TPU compiler
@@ -191,8 +240,7 @@ def test_config3_programs_fit_one_v5e_chip(v5e):
     exp = Experiment.build(cfg)
 
     def place(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=v5e), tree)
+        return _place(tree, v5e)
 
     ts = place(jax.eval_shape(lambda: exp.init_train_state(0)))
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))

@@ -4,9 +4,12 @@
 size: forward, loss and every gradient leaf through the dense and the
 compact-rows path; the shares of a small deployment add up to the uncut
 layer; the window and the NoPE/RoPE layout where they bind; routing under
-skew; the parted storage / sliced-forward predicates."""
+skew; the router's one-hot selection against its ``lax.top_k`` form under
+every mechanism either family gives it; the parted storage /
+sliced-forward predicates."""
 
 import dataclasses
+import types
 
 import jax
 import jax.numpy as jnp
@@ -341,6 +344,110 @@ def test_routing_under_skew_loses_nothing(to, held_share):
     assert int(sizes.sum()) == int(aux["held"]) == round(held_share * 3 * n)
     if held_share == 1 / 3:
         assert sizes.tolist() == [n, 0]        # one expert takes them all
+
+
+# ------------------------- (e) the router against its ``lax.top_k`` form
+
+def route_by_top_k(w_router, h, tk, bias=None):
+    """``trunk.route`` as it stood through PR 31: ``jax.lax.top_k`` (a
+    sort of all experts on the chip), ``take_along_axis`` (a gather, and
+    a scatter in its transpose). What the one-hot form must return."""
+    sp = tk.spec
+    logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    values = (logits if sp.router_scores == "softmax"
+              else jax.nn.sigmoid(logits))
+    _, idx = jax.lax.top_k(values if bias is None else values + bias,
+                           sp.top_k)
+    kept = jnp.take_along_axis(values, idx, axis=-1)
+    if sp.router_scores == "softmax":
+        return jax.nn.softmax(kept, axis=-1), idx
+    if sp.route_norm:
+        kept = kept / (kept.sum(axis=-1, keepdims=True) + 1e-20)
+    return kept * sp.route_scale, idx
+
+
+def tied_router_case(e: int, biased: bool):
+    """(router kernel ``(D, e)``, tokens ``(96, D)``, bias or None) with
+    ties planted: every token twice; experts 5 and 9 with one kernel
+    column (equal logits in every row); experts 2, 3 and 11 so far up
+    that their sigmoids read exactly 1.0 in float32 on the rows where
+    the first feature is positive; and, with a bias, two experts of
+    score 0.5 (zero columns) lifted by the same bias to the top."""
+    k = jax.random.split(jax.random.PRNGKey(40 + e), 3)
+    w = 0.3 * jax.random.normal(k[0], (D, e))
+    w = w.at[:, 9].set(w[:, 5])
+    w = w.at[:, jnp.asarray([2, 3, 11])].set(0.0).at[
+        0, jnp.asarray([2, 3, 11])].set(40.0)
+    m = jax.random.normal(k[1], (48, D))
+    m = jnp.concatenate([m, m])
+    bias = None
+    if biased:
+        w = w.at[:, jnp.asarray([20, 33])].set(0.0)
+        bias = 0.02 * jax.random.normal(k[2], (e,))
+        bias = bias.at[jnp.asarray([20, 33])].set(3.0)
+    return w, m, bias
+
+
+@pytest.mark.parametrize("share", [0, 3], ids=["offset-0", "offset-24"])
+@pytest.mark.parametrize("k", [6, 8], ids=["top-6", "top-8"])
+@pytest.mark.parametrize("e", [64, 128], ids=["of-64", "of-128"])
+@pytest.mark.parametrize("biased", [False, True], ids=["plain", "bias"])
+@pytest.mark.parametrize("scores", ["softmax", "sigmoid"])
+def test_route_is_lax_top_k_by_one_hot_planes(monkeypatch, scores, biased,
+                                              e, k, share):
+    """Over every mechanism ``TrunkSpec`` gives the router, at both
+    cells' expert counts: the ids are ``lax.top_k``'s, in its order, ties
+    and all; the weights, the held plane and the gradients agree to
+    float32 rounding; a selection bias has a gradient of exactly zero;
+    the layer's two counts are equal."""
+    base = TrunkConfig(**dict(TK, moe_num_primary_experts=e,
+                              moe_num_active_primary_experts=k,
+                              experts_held=8, share_index=share))
+    spec = dataclasses.replace(base.spec, router_scores=scores,
+                               router_bias=biased, route_scale=2.5)
+    tk = types.SimpleNamespace(spec=spec)     # all the router reads
+    w, m, bias = tied_router_case(e, biased)
+    want_w, want_idx = route_by_top_k(w, m, tk, bias=bias)
+    got_w, got_idx = tr.route(w, m, tk, bias=bias)
+    # the ties are there: equal ranked values inside the kept prefix
+    ranked = np.take_along_axis(
+        np.asarray((m @ w if scores == "softmax" else jax.nn.sigmoid(m @ w))
+                   + (0 if bias is None else bias)), np.asarray(want_idx), -1)
+    assert (np.diff(ranked, axis=-1) == 0).any(-1).mean() > 0.4
+    assert got_idx.dtype == want_idx.dtype
+    np.testing.assert_array_equal(got_idx, want_idx)
+    np.testing.assert_allclose(got_w, want_w, rtol=1e-6, atol=0)
+    np.testing.assert_allclose(tr.held_weights(got_w, got_idx, tk),
+                               tr.held_weights(want_w, want_idx, tk),
+                               rtol=1e-6, atol=0)
+
+    # gradients, through held_weights as the layer uses the router
+    g = jax.random.normal(jax.random.PRNGKey(41), (m.shape[0], 8))
+
+    def loss(route):
+        def f(w, m, bias):
+            weights, idx = route(w, m, tk, bias=bias)
+            return (tr.held_weights(weights, idx, tk) * g).sum() + (
+                weights ** 2).sum()
+        return jax.grad(f, argnums=(0, 1, 2) if biased else (0, 1))(
+            w, m, bias)
+    got, want = loss(tr.route), loss(route_by_top_k)
+    for a, b in zip(got[:2], want[:2]):
+        assert float(jnp.abs(b).max()) > 1e-2
+        np.testing.assert_allclose(a, b, rtol=1e-5,
+                                   atol=1e-5 * float(jnp.abs(b).max()))
+    if biased:
+        assert not np.asarray(got[2]).any()    # selection only: exactly 0
+
+    # the layer's aux: the product's mask and the range count the same
+    lp = {"router": w, "expert_bias": bias}
+    per, aux = tr._routing(lp, m, tk)
+    monkeypatch.setattr(tr, "route", route_by_top_k)
+    per_old, aux_old = tr._routing(lp, m, tk)
+    np.testing.assert_allclose(per, per_old, rtol=1e-6, atol=0)
+    assert aux["load"].tolist() == aux_old["load"].tolist()
+    assert int(aux["held"]) == int(aux_old["held"]) == int(aux["load"].sum())
 
 
 def test_experts_gradient_matches_the_reference():

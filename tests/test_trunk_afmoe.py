@@ -585,26 +585,40 @@ def test_train_step_and_skip_branch_carry_the_same_info(exp, params):
                                  - before[name]["router"])).max() > 0
 
 
-# -------------------------- SmallThinker's programs lower as they did
+# ------------------ both families' programs lower as they did at the pin
 
 #: sha256[:16] of the StableHLO (locations stripped) of the acting forward
-#: and of the learner's loss gradient under
-#: configs/config8_trunk_smallthinker.yaml, taken on the parent commit of
-#: the PR that wrote the second family (5c570de) under this suite's
-#: conftest (``highest`` matmul precision; a plain process reads
-#: bc775b518feae738 / 13c6af2af61aec10, on both trees too): the one layer
-#: function must give the first family the program it had
-SMALLTHINKER_LOWERING = {"forward": "26fd06c24a83505a",
-                         "loss": "3b34cde7cb7ad5f4"}
+#: and of the learner's loss gradient under each family's shipped file,
+#: under this suite's conftest (``highest`` matmul precision): the one
+#: layer function serves both, and a PR that means to change one
+#: family's program must not change the other's unseen. SmallThinker's
+#: stood at 26fd06c24a83505a / 3b34cde7cb7ad5f4 from the commit before the
+#: second family (5c570de) through PR 31. Both families' digests below
+#: were taken on the tree of PR 32 (parent 8d77ba7), whose ONE intended
+#: difference is the router (``trunk.route``: the top-k by one-hot planes
+#: in the place of ``lax.top_k`` + ``take_along_axis``; it changed all
+#: four programs, SmallThinker's for the first time since PR 27)
+SMALLTHINKER_LOWERING = {"forward": "cf288d23d13fce15",
+                         "loss": "0c86cf19a893321f"}
+TRINITY_LOWERING = {"forward": "297cc799394e8ff6",
+                    "loss": "17f96004210a39ee"}
+
+
+def _shipped(name):
+    cfg = load_config(os.path.join(HERE, "configs", name))
+    exp = Experiment.build(cfg)
+    ts = jax.eval_shape(lambda: exp.init_train_state(0))
+    return cfg, exp, ts
 
 
 @pytest.fixture(scope="module")
 def smallthinker():
-    cfg = load_config(os.path.join(HERE, "configs",
-                                   "config8_trunk_smallthinker.yaml"))
-    exp = Experiment.build(cfg)
-    ts = jax.eval_shape(lambda: exp.init_train_state(0))
-    return cfg, exp, ts
+    return _shipped("config8_trunk_smallthinker.yaml")
+
+
+@pytest.fixture(scope="module")
+def trinity():
+    return _shipped("config9_trunk_trinity.yaml")
 
 
 def _digest(lowered) -> str:
@@ -614,11 +628,10 @@ def _digest(lowered) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-@pytest.mark.parametrize("program", ["forward", "loss"])
-def test_smallthinker_lowering_is_unchanged(smallthinker, program):
-    """Shapes only (``eval_shape``): nothing of the 290 M parameters is
+def _lowered(shipped, program):
+    """Shapes only (``eval_shape``): nothing of the parameters is
     allocated."""
-    cfg, exp, ts = smallthinker
+    cfg, exp, ts = shipped
     agent = ts.learner.params["agent"]
     if program == "forward":
         a = cfg.env_args.agv_num
@@ -626,13 +639,21 @@ def test_smallthinker_lowering_is_unchanged(smallthinker, program):
         hid = jax.ShapeDtypeStruct((2, a, cfg.model.emb), jnp.float32)
         fwd = jax.jit(lambda p, o, h: tr.agent_forward_trunk(
             p, o, h, tk=cfg.model.trunk, dtype=jnp.bfloat16))
-        lowered = fwd.lower(agent, obs, hid)
-    else:
-        batch = jax.eval_shape(lambda p, r: exp.runner.run(p, r), agent,
-                               ts.runner)[1]
-        w = jax.ShapeDtypeStruct((batch.reward.shape[0],), jnp.float32)
-        loss = jax.jit(jax.grad(
-            lambda p, t, b, w: exp.learner._loss(p, t, b, w)[0]))
-        lowered = loss.lower(ts.learner.params, ts.learner.target_params,
-                             batch, w)
-    assert _digest(lowered) == SMALLTHINKER_LOWERING[program]
+        return fwd.lower(agent, obs, hid)
+    batch = jax.eval_shape(lambda p, r: exp.runner.run(p, r), agent,
+                           ts.runner)[1]
+    w = jax.ShapeDtypeStruct((batch.reward.shape[0],), jnp.float32)
+    loss = jax.jit(jax.grad(
+        lambda p, t, b, w: exp.learner._loss(p, t, b, w)[0]))
+    return loss.lower(ts.learner.params, ts.learner.target_params, batch, w)
+
+
+@pytest.mark.parametrize("program", ["forward", "loss"])
+def test_smallthinker_lowering_is_unchanged(smallthinker, program):
+    assert _digest(_lowered(smallthinker, program)) == \
+        SMALLTHINKER_LOWERING[program]
+
+
+@pytest.mark.parametrize("program", ["forward", "loss"])
+def test_trinity_lowering_is_unchanged(trinity, program):
+    assert _digest(_lowered(trinity, program)) == TRINITY_LOWERING[program]
