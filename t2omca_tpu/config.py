@@ -123,12 +123,13 @@ class LayerSpec:
 @dataclass(frozen=True)
 class TrunkSpec:
     """A trunk's layers as MECHANISMS — the one description
-    ``models/trunk.py`` reads, resolved from either family's published
-    keys (``TrunkConfig.spec``, ``AfmoeTrunkConfig.spec``). Nothing here
-    names a model: a layer function branches on these fields alone."""
+    ``models/trunk.py`` reads, resolved from any of the three families'
+    published keys (``TrunkConfig.spec``, ``AfmoeTrunkConfig.spec``,
+    ``DeepseekV3TrunkConfig.spec``). Nothing here names a model: a layer
+    function branches on these fields alone."""
 
     hidden_size: int
-    head_dim: int
+    head_dim: int             # width of a query / key head
     heads_held: int
     kv_heads_held: int
     rms_norm_eps: float
@@ -154,10 +155,28 @@ class TrunkSpec:
     expert_act: str           # relu | silu, gating every feed-forward
     shared_width: int         # a shared expert beside the routed; 0: none
     layers: Tuple[LayerSpec, ...]
+    # the attention KIND. kv_latent 0: grouped-query — every key/value
+    # head its own W_k / W_v of head_dim, RoPE over the whole head.
+    # kv_latent > 0: latent — ONE down-projection a token to a latent of
+    # this width (RMS-normed, then up-projected to every held head's
+    # no-position key of qk_nope_dim and value of v_head_dim) and to ONE
+    # rotary key of qk_rope_dim that all heads read; a query head is
+    # [qk_nope_dim | qk_rope_dim] = head_dim wide and RoPE turns its
+    # qk_rope_dim alone (no q/k norm, no kv_heads_held in this kind)
+    kv_latent: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0       # width of a value head; 0: head_dim
+    # RoPE's pairing: (2i, 2i + 1) turn together, or (i, i + D/2)
+    rope_interleave: bool = False
 
     @property
     def expert_layers(self) -> int:
         return sum(not layer.dense_width for layer in self.layers)
+
+    @property
+    def value_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
 
 
 class _TrunkShare:
@@ -183,13 +202,15 @@ class _TrunkShare:
 class TrunkConfig(_TrunkShare):
     """A decoder trunk from the public catalog as the transformer agent's
     token stack (``models/trunk.py``; ``model.trunk`` — absent, the agent
-    is the T2OMCA stack and nothing here is read). Two families are
+    is the T2OMCA stack and nothing here is read). Three families are
     written down, each under the key names of its own published
     ``config.json``: this class (no ``model_type`` key; SmallThinker's
     names: pre-norm residuals, a softmax top-k router reading the layer's
-    input, ReGLU experts, ``rope_layout`` / ``sliding_window_layout``) and
-    ``AfmoeTrunkConfig`` (``model_type: afmoe``). Both resolve to one
-    ``TrunkSpec`` (``.spec``), which is all the layer function reads.
+    input, ReGLU experts, ``rope_layout`` / ``sliding_window_layout``),
+    ``AfmoeTrunkConfig`` (``model_type: afmoe``) and
+    ``DeepseekV3TrunkConfig`` (``model_type: deepseek_v3``: latent
+    attention). All resolve to one ``TrunkSpec`` (``.spec``), which is
+    all the layer function reads.
 
     The last three keys say which part of each layer THIS chip holds: the
     deployment divides a layer's experts ``moe_num_primary_experts /
@@ -367,9 +388,145 @@ class AfmoeTrunkConfig(_TrunkShare):
                 for i in held))
 
 
+@dataclass(frozen=True)
+class DeepseekV3TrunkConfig(_TrunkShare):
+    """``model.trunk`` with ``model_type: deepseek_v3`` (kanana-2's
+    family; the keys by the names of its published ``config.json``):
+    pre-norm residuals and multi-head LATENT attention — keys and values
+    of every head come from one ``kv_lora_rank``-wide latent a token
+    (RMS-normed) and one ``qk_rope_head_dim``-wide rotary key that all
+    heads share; a query/key head is ``qk_nope_head_dim +
+    qk_rope_head_dim`` wide with RoPE (``rope_interleave``: pairs
+    ``(2i, 2i + 1)``) on the rotary part only, a value head
+    ``v_head_dim``; the softmax scale is ``qk_head_dim ** -0.5``. The
+    first ``first_k_dense_replace`` layers have a dense SwiGLU
+    feed-forward of ``intermediate_size``, the others ``n_routed_experts``
+    routed SwiGLU experts of ``moe_intermediate_size``
+    (``num_experts_per_tok`` a token: sigmoid scores of the normed
+    feed-forward input, ``topk_method: noaux_tc`` — a bias,
+    ``e_score_correction_bias``, that enters the selection only — the
+    kept scores renormalised under ``norm_topk_prob`` and scaled by
+    ``routed_scaling_factor``) beside ``n_shared_experts`` shared ones,
+    held as ONE feed-forward of ``n_shared_experts *
+    moe_intermediate_size``.
+
+    The share is ``AfmoeTrunkConfig``'s (``experts_held``, ``heads_held``,
+    ``share_index``, ``first_layer``: ``first_k_dense_replace`` is read at
+    the published layer indices and keeps its published value). Latent
+    attention has no key/value heads to split: ``heads_held`` of the
+    ``num_attention_heads`` columns of ``W_q`` and ``W_kv_b`` and rows of
+    ``W_o`` are held, and the latent's down-projection with its norm
+    WHOLE — every attention share computes them alike, as the shared
+    experts and a dense layer's feed-forward. ``head_dim`` is carried as
+    published (transformers sets it to the rotary width) and not read.
+    Not written, and refused: a query latent (``q_lora_rank``), a RoPE
+    scaling, attention biases, group-limited selection, ``moe_layer_freq``
+    other than 1. Not carried: the selection bias's update from the load
+    — the bias is a parameter the optimizer never moves."""
+
+    model_type: str = "deepseek_v3"
+    hidden_size: int = 2048
+    head_dim: int = 64
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 32
+    num_hidden_layers: int = 5
+    first_k_dense_replace: int = 1
+    moe_layer_freq: int = 1
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 768
+    n_routed_experts: int = 128
+    num_experts_per_tok: int = 6
+    n_shared_experts: int = 2
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.448
+    n_group: int = 1
+    topk_group: int = 1
+    hidden_act: str = "silu"
+    attention_bias: bool = False
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    qk_head_dim: int = 192
+    v_head_dim: int = 128
+    rope_interleave: bool = True
+    rope_scaling: Optional[dict] = None
+    rope_theta: float = 1_000_000.0
+    rms_norm_eps: float = 1e-6
+    experts_held: int = 8
+    heads_held: int = 16
+    share_index: int = 0
+    first_layer: int = 0
+
+    def check(self) -> None:
+        last = self.first_layer + self.num_hidden_layers
+        if self.first_layer < 0 or not 0 <= self.first_k_dense_replace < last:
+            raise ValueError(
+                f"model.trunk: first_k_dense_replace="
+                f"{self.first_k_dense_replace} lies past the held layers "
+                f"({self.first_layer} … {last - 1}): no held layer would "
+                f"route")
+        if self.n_group != 1 or self.topk_group != 1:
+            raise ValueError("model.trunk: group-limited routing "
+                             "(n_group / topk_group other than 1) is not "
+                             "written")
+        if self.q_lora_rank is not None or self.rope_scaling is not None:
+            raise ValueError("model.trunk (deepseek_v3): a query latent "
+                             "(q_lora_rank) and a RoPE scaling are not "
+                             "written")
+        if (self.scoring_func != "sigmoid" or self.topk_method != "noaux_tc"
+                or self.hidden_act != "silu" or self.attention_bias
+                or self.moe_layer_freq != 1 or self.n_shared_experts < 0):
+            raise ValueError(
+                "model.trunk (deepseek_v3) covers sigmoid scores with a "
+                "selection bias (noaux_tc), SiLU-gated feed-forwards in "
+                "every layer past the dense ones, and no attention bias")
+        if (self.kv_lora_rank < 1 or self.qk_rope_head_dim % 2
+                or min(self.qk_nope_head_dim, self.qk_rope_head_dim,
+                       self.v_head_dim) < 1
+                or self.qk_head_dim != (self.qk_nope_head_dim
+                                        + self.qk_rope_head_dim)):
+            raise ValueError(
+                "model.trunk (deepseek_v3): qk_head_dim must be "
+                "qk_nope_head_dim + qk_rope_head_dim, the rotary width "
+                "even, the latent and the head widths positive")
+
+    @functools.cached_property
+    def spec(self) -> TrunkSpec:
+        held = range(self.first_layer,
+                     self.first_layer + self.num_hidden_layers)
+        return TrunkSpec(
+            hidden_size=self.hidden_size, head_dim=self.qk_head_dim,
+            heads_held=self.heads_held, kv_heads_held=self.heads_held,
+            rms_norm_eps=self.rms_norm_eps, rope_theta=self.rope_theta,
+            sandwich_norm=False, qk_norm=False, attn_gate=False,
+            router_reads_input=False, router_scores=self.scoring_func,
+            router_bias=True, route_norm=self.norm_topk_prob,
+            route_scale=self.routed_scaling_factor,
+            experts=self.n_routed_experts, top_k=self.num_experts_per_tok,
+            experts_held=self.experts_held,
+            expert_offset=self.expert_offset,
+            expert_width=self.moe_intermediate_size,
+            expert_act=self.hidden_act,
+            shared_width=self.n_shared_experts * self.moe_intermediate_size,
+            layers=tuple(
+                LayerSpec(rope=True, window=0,
+                          dense_width=(self.intermediate_size
+                                       if i < self.first_k_dense_replace
+                                       else 0))
+                for i in held),
+            kv_latent=self.kv_lora_rank, qk_nope_dim=self.qk_nope_head_dim,
+            qk_rope_dim=self.qk_rope_head_dim, v_head_dim=self.v_head_dim,
+            rope_interleave=self.rope_interleave)
+
+
 #: ``model.trunk``'s dataclass by its ``model_type`` key (absent: the
-#: family whose published config has none)
-TRUNK_FAMILIES = {None: TrunkConfig, "afmoe": AfmoeTrunkConfig}
+#: family whose published config has none) — three families, two
+#: attention kinds (grouped-query: the first two; latent: the third)
+TRUNK_FAMILIES = {None: TrunkConfig, "afmoe": AfmoeTrunkConfig,
+                  "deepseek_v3": DeepseekV3TrunkConfig}
 
 
 @dataclass(frozen=True)
@@ -431,7 +588,8 @@ class ModelConfig:
     # a catalog decoder trunk in place of the T2OMCA stack (TrunkConfig;
     # emb must equal its hidden_size and depth its num_hidden_layers —
     # heads / ff_hidden_mult / standard_heads are then the mixer's alone)
-    trunk: Optional[Union[TrunkConfig, AfmoeTrunkConfig]] = None
+    trunk: Optional[Union[TrunkConfig, AfmoeTrunkConfig,
+                          DeepseekV3TrunkConfig]] = None
 
 
 @dataclass(frozen=True)

@@ -2,11 +2,14 @@
 
 ``model.trunk`` replaces the T2OMCA blocks of ``TransformerAgent`` with
 the decoder layers of a public language model at their published widths.
-Two families are written down in ``config.py`` under their own published
-key names (``TrunkConfig``: SmallThinker's; ``AfmoeTrunkConfig``:
-Trinity's, ``model_type: afmoe``); both resolve to one ``TrunkSpec``
-(``tk.spec``), and that is all this module reads: what a layer IS is a
-set of mechanisms, and no branch here tests a family or a model's name.
+Three families are written down in ``config.py`` under their own
+published key names (``TrunkConfig``: SmallThinker's;
+``AfmoeTrunkConfig``: Trinity's, ``model_type: afmoe``;
+``DeepseekV3TrunkConfig``: kanana-2's, ``model_type: deepseek_v3``); all
+resolve to one ``TrunkSpec`` (``tk.spec``), and that is all this module
+reads: what a layer IS is a set of mechanisms — two attention kinds among
+them, grouped-query and latent — and no branch here tests a family or a
+model's name.
 The language model's embedding table and output head have no counterpart:
 tokens are the agent's ``A`` entity rows (``feat_embedding``) followed by
 the hidden token that carries memory, outputs are ``q_basic`` of that
@@ -18,9 +21,18 @@ lets it read every entity. With ``N`` RMSNorm (float32 statistics), a
 layer with input ``h`` is
 
     u = N(h; input_norm)
-    q, k, v = u W_q, u W_k, u W_v           grouped-query heads of head_dim
-    q, k = N_head(q), N_head(k)             [qk_norm] over head_dim, pre-RoPE
-    q, k = RoPE(q), RoPE(k)                 [layer.rope]
+    grouped-query [kv_latent 0]:
+      q, k, v = u W_q, u W_k, u W_v         grouped-query heads of head_dim
+      q, k = N_head(q), N_head(k)           [qk_norm] over head_dim, pre-RoPE
+      q, k = RoPE(q), RoPE(k)               [layer.rope]
+    latent [kv_latent > 0]:
+      q = u W_q                           heads of [qk_nope_dim | qk_rope_dim]
+      c, k_r = split(u W_kva)               ONE latent and ONE rotary key a
+                                            token, read by every head
+      k_n, v = split(N(c; kv_norm) W_kvb)   heads of [qk_nope_dim | v_head_dim]
+      q_r, k_r = RoPE(q_r), RoPE(k_r)       [layer.rope] the rotary part only,
+                                            pairs (2i, 2i+1) [rope_interleave]
+      q kᵀ = q_n k_nᵀ + q_r k_rᵀ            d = qk_nope_dim + qk_rope_dim
     o = softmax(q kᵀ/√d + causal [∧ i-j < layer.window]) v       float32
     att = (o [⊙ σ(u W_g): attn_gate]) W_o
     a = h + [N(att; attn_out_norm): sandwich_norm | att]
@@ -45,15 +57,17 @@ gather or scatter in the compiled program. ``act`` is ``expert_act``
 holds (``experts_held``, ``heads_held``, ``share_index``): the router
 scores all experts and keeps its ``k`` a token, the chip computes the
 token-expert pairs whose expert it holds, and ``W_o`` contracts the heads
-it holds; a shared expert and a dense layer's feed-forward are held whole
-(every chip of the group computes them alike). There is no exchange here
+it holds; a shared expert, a dense layer's feed-forward and latent
+attention's down-projection with its norm are held whole (every chip of
+the group computes them alike). There is no exchange here
 — the partial sums are what the next layer reads, in the program and in
 its references alike (``benchmark/reference/trunk.py``,
-``benchmark/reference/afmoe.py``) — and no code stands in for the absent
-chips. Under pre-norm residuals the shares' layer outputs add up to the
-uncut layer; under sandwich norms the output norms are not linear, so the
-shares add up at the two SUBLAYER sums (``att`` and ``f``, before their
-norms), and each share normalises its own partial sum
+``benchmark/reference/afmoe.py``, ``benchmark/reference/dsv3.py``) — and
+no code stands in for the absent chips. Under pre-norm residuals the
+shares' layer outputs add up to the uncut layer; under sandwich norms the
+output norms are not linear, so the shares add up at the two SUBLAYER
+sums (``att`` and ``f``, before their norms), and each share normalises
+its own partial sum
 (``tests/test_trunk.py``, ``tests/test_trunk_afmoe.py``).
 
 **Routing is dropless, and its time does not depend on the routing.**
@@ -86,7 +100,8 @@ from flax import linen as nn
 #: and its selection bias (a rounded logit flips the top-k choice), the
 #: norms' scales, the Q head
 KEEP_F32 = ("router", "expert_bias", "input_norm", "post_norm", "norm",
-            "q_norm", "k_norm", "attn_out_norm", "ff_out_norm", "q_basic")
+            "q_norm", "k_norm", "attn_out_norm", "ff_out_norm", "kv_norm",
+            "q_basic")
 
 #: ``TrunkSpec.expert_act`` → the gate's activation in every feed-forward
 _ACT = {"relu": jax.nn.relu, "silu": jax.nn.silu}
@@ -118,6 +133,23 @@ def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
                            axis=-1).astype(x.dtype)
 
 
+def rope_pairs(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """``rope`` in the interleaved convention: the pairs ``(2i, 2i + 1)``
+    rotate together, by ``position · theta^(-2i/D)``. Each lane's partner
+    is its neighbour, read by a shift of the lanes — no ``(D/2, 2)``
+    reshape, whose minor axis of 2 a TPU pads to a whole tile."""
+    n, d = x.shape[1], x.shape[-1]
+    lane = jnp.arange(d)
+    inv = theta ** (-(lane - lane % 2).astype(jnp.float32) / d)
+    ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    partner = jnp.where(lane % 2 == 0, -jnp.roll(x32, -1, axis=-1),
+                        jnp.roll(x32, 1, axis=-1))
+    return (x32 * cos + partner * sin).astype(x.dtype)
+
+
 def attention_mask(n: int, window: int) -> jnp.ndarray:
     """``(n, n)`` bool: query ``i`` reads key ``j`` iff ``j <= i`` and,
     with a window, ``i - j < window`` (``window <= 0``: the whole
@@ -127,17 +159,13 @@ def attention_mask(n: int, window: int) -> jnp.ndarray:
     return ok & (i - j < window) if window > 0 else ok
 
 
-def attention_part(lp: dict, h: jnp.ndarray, tk, layer: int,
-                   dtype) -> jnp.ndarray:
-    """This share's ``W_o · GQA(RMSNorm(h))`` — with the spec's extras:
-    RMSNorm on q and k per head, the output gate — ``h (S, n, d)`` →
-    float32 ``(S, n, d)``, before any output norm. Softmax in float32 at
-    every dtype (``n`` is tens)."""
-    s, n, _ = h.shape
-    sp = tk.spec
-    ls = sp.layers[layer]
+def grouped_scores(lp: dict, x: jnp.ndarray, sp, ls, dtype):
+    """Grouped-query attention's scaled logits and values of the normed
+    ``x (S, n, d)`` → (float32 ``(S, kv heads, group, n, n)``, ``v (S, n,
+    kv heads, head_dim)``): every key/value head its own ``W_k`` / ``W_v``;
+    RMSNorm on q and k per head [qk_norm]; RoPE over the whole head."""
+    s, n, _ = x.shape
     hq, hkv, d = sp.heads_held, sp.kv_heads_held, sp.head_dim
-    x = rms_norm(h, lp["input_norm"], sp.rms_norm_eps).astype(dtype)
     proj = lambda w, heads: jnp.dot(                         # noqa: E731
         x, w.astype(dtype), preferred_element_type=jnp.float32
     ).astype(dtype).reshape(s, n, heads, d)
@@ -150,11 +178,71 @@ def attention_part(lp: dict, h: jnp.ndarray, tk, layer: int,
     q = q.reshape(s, n, hkv, hq // hkv, d)
     logits = jnp.einsum("sqhgd,skhd->shgqk", q, k,
                         preferred_element_type=jnp.float32) * d ** -0.5
+    return logits, v
+
+
+def latent_kv(lp: dict, x: jnp.ndarray, sp, dtype):
+    """What latent attention does that grouped-query attention does not:
+    the normed ``x (S, n, d)`` down to ONE latent and ONE rotary key a
+    token (``W_kva``, whole in every share), the latent's RMSNorm
+    (float32 statistics) and its up-projection to the held heads'
+    no-position keys and values → (``k_nope (S, n, H, qk_nope_dim)``,
+    ``k_rope (S, n, qk_rope_dim)`` un-rotated, ``v (S, n, H,
+    v_head_dim)``)."""
+    s, n, _ = x.shape
+    with jax.named_scope("agent.latent"):
+        down = jnp.dot(x, lp["wkv_a"].astype(dtype),
+                       preferred_element_type=jnp.float32).astype(dtype)
+        c = rms_norm(down[..., :sp.kv_latent], lp["kv_norm"],
+                     sp.rms_norm_eps).astype(dtype)
+        kv = jnp.dot(c, lp["wkv_b"].astype(dtype),
+                     preferred_element_type=jnp.float32).astype(dtype)
+        kv = kv.reshape(s, n, sp.heads_held, sp.qk_nope_dim + sp.value_dim)
+    return (kv[..., :sp.qk_nope_dim], down[..., sp.kv_latent:],
+            kv[..., sp.qk_nope_dim:])
+
+
+def latent_scores(lp: dict, x: jnp.ndarray, sp, ls, dtype):
+    """Latent attention's scaled logits and values of the normed ``x`` —
+    shaped as ``grouped_scores``' with a group of one: a query head is
+    ``[no-position | rotary]``, its key the head's own no-position key
+    beside the ONE rotary key every head reads; RoPE (the interleaved
+    pairing under ``rope_interleave``) turns the rotary parts alone."""
+    s, n, _ = x.shape
+    hq, nope = sp.heads_held, sp.qk_nope_dim
+    q = jnp.dot(x, lp["wq"].astype(dtype), preferred_element_type=jnp.float32
+                ).astype(dtype).reshape(s, n, hq, sp.head_dim)
+    q_nope, q_rope = q[..., :nope], q[..., nope:]
+    k_nope, k_rope, v = latent_kv(lp, x, sp, dtype)
+    if ls.rope:
+        turn = rope_pairs if sp.rope_interleave else rope
+        q_rope = turn(q_rope, sp.rope_theta)
+        k_rope = turn(k_rope[:, :, None, :], sp.rope_theta)[:, :, 0, :]
+    logits = (jnp.einsum("sqhd,skhd->shqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("sqhd,skd->shqk", q_rope, k_rope,
+                           preferred_element_type=jnp.float32))
+    return (logits * sp.head_dim ** -0.5)[:, :, None], v
+
+
+def attention_part(lp: dict, h: jnp.ndarray, tk, layer: int,
+                   dtype) -> jnp.ndarray:
+    """This share's ``W_o · attention(RMSNorm(h))`` of the spec's kind —
+    grouped-query (``grouped_scores``) or latent (``latent_scores``) —
+    with the spec's extras (the output gate) — ``h (S, n, d)`` → float32
+    ``(S, n, d)``, before any output norm. Softmax in float32 at every
+    dtype (``n`` is tens)."""
+    s, n, _ = h.shape
+    sp = tk.spec
+    ls = sp.layers[layer]
+    x = rms_norm(h, lp["input_norm"], sp.rms_norm_eps).astype(dtype)
+    scores = latent_scores if sp.kv_latent else grouped_scores
+    logits, v = scores(lp, x, sp, ls, dtype)
     logits = jnp.where(attention_mask(n, ls.window), logits, -jnp.inf)
     attn = jax.nn.softmax(logits, axis=-1).astype(dtype)
     out = jnp.einsum("shgqk,skhd->sqhgd", attn, v,
                      preferred_element_type=jnp.float32)
-    out = out.astype(dtype).reshape(s, n, hq * d)
+    out = out.astype(dtype).reshape(s, n, sp.heads_held * sp.value_dim)
     if sp.attn_gate:
         gate = jax.nn.sigmoid(jnp.dot(x, lp["wg"].astype(dtype),
                                       preferred_element_type=jnp.float32))
@@ -483,11 +571,22 @@ class _Layer(nn.Module):
         out = {
             "input_norm": self.param("input_norm", ones, (d,)),
             "wq": self.param("wq", init, (d, hq)),
-            "wk": self.param("wk", init, (d, hkv)),
-            "wv": self.param("wv", init, (d, hkv)),
-            "wo": self.param("wo", init, (hq, d)),
-            "post_norm": self.param("post_norm", ones, (d,)),
         }
+        if sp.kv_latent:
+            # the down-projection and its norm whole; the held heads'
+            # columns of the up-projection, [no-position key | value] a head
+            rank, up = sp.kv_latent, sp.qk_nope_dim + sp.value_dim
+            out["wkv_a"] = self.param("wkv_a", init,
+                                      (d, rank + sp.qk_rope_dim))
+            out["kv_norm"] = self.param("kv_norm", ones, (rank,))
+            out["wkv_b"] = self.param("wkv_b", init,
+                                      (rank, sp.heads_held * up))
+        else:
+            out["wk"] = self.param("wk", init, (d, hkv))
+            out["wv"] = self.param("wv", init, (d, hkv))
+        out["wo"] = self.param("wo", init,
+                               (sp.heads_held * sp.value_dim, d))
+        out["post_norm"] = self.param("post_norm", ones, (d,))
         if sp.qk_norm:
             out["q_norm"] = self.param("q_norm", ones, (sp.head_dim,))
             out["k_norm"] = self.param("k_norm", ones, (sp.head_dim,))

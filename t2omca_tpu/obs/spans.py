@@ -151,8 +151,12 @@ KNOWN_SCOPES = frozenset({
     # experts' weights a token; the feed-forward's input norm, the routed
     # experts' products and the output norm; a shared expert's products;
     # a dense layer's feed-forward (not agent.ff: the mixer's block opens
-    # that one too)
+    # that one too); inside agent.attention, what latent attention does
+    # that grouped-query attention does not: the down-projection to the
+    # latent and the shared rotary key, the latent's norm, the
+    # up-projection to the held heads' no-position keys and values
     "agent.router", "agent.experts", "agent.shared", "agent.dense",
+    "agent.latent",
     # replay ring (components/episode_buffer.py)
     "replay.insert", "replay.sample", "replay.priority",
     # learner (learners/qmix_learner.py)

@@ -56,6 +56,11 @@ COMMITTED_PRESETS = {
                               c.model.trunk.model_type == "afmoe"
                               and c.superstep == 1
                               and c.log_interval == 1200),
+    "config10_trunk_kanana": (8, 16, 0, lambda c:
+                              c.model.trunk.model_type == "deepseek_v3"
+                              and c.model.trunk.spec.kv_latent == 512
+                              and c.superstep == 1
+                              and c.log_interval == 1200),
     "serve_smoke": (4, 4, 0, lambda c: c.env_args.episode_limit == 8),
 }
 

@@ -186,15 +186,16 @@ _SERIAL = re.compile(r" (sort|gather|scatter)\(|custom_call_target=\"TopK")
 
 
 @pytest.mark.parametrize("config", ["config8_trunk_smallthinker.yaml",
-                                    "config9_trunk_trinity.yaml"])
+                                    "config9_trunk_trinity.yaml",
+                                    "config10_trunk_kanana.yaml"])
 @pytest.mark.parametrize("program", ["acting", "loss-gradient"])
 def test_router_compiles_without_sort_gather_scatter(v5e, config, program):
-    """Both shipped trunks at their published widths, cut to 2 lanes, 2
-    episodes, 4 steps and their first 2 layers (Trinity's: the dense
-    layer and a routed one): once the chip's compiler is through with the
-    acting forward and with the learner's loss gradient, no instruction
-    under ``agent.router`` is a sort (``lax.top_k`` orders all 64 / 128
-    experts to keep 6 / 8), a gather (``take_along_axis`` fetches one
+    """The shipped trunks at their published widths, cut to 2 lanes, 2
+    episodes, 4 steps and their first 2 layers (Trinity's and kanana's:
+    the dense layer and a routed one): once the chip's compiler is
+    through with the acting forward and with the learner's loss gradient,
+    no instruction under ``agent.router`` is a sort (``lax.top_k`` orders
+    all 64 / 128 experts to keep 6 / 8), a gather (``take_along_axis`` fetches one
     element a kept pair) or a scatter (its transpose): ``trunk.route``
     selects and reads through one-hot planes (PERF.md section 6, PR 32)."""
     from t2omca_tpu.config import load_config

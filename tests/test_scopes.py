@@ -34,10 +34,14 @@ TRAIN = {"replay.sample", "replay.priority", "learner.agent",
          "learner.optimizer", "sight", "agent.embed", "agent.attention",
          "agent.ff", "agent.head"}
 #: opened by a catalog trunk's layers alone (models/trunk.py), which in
-#: turn have no ``agent.ff``: the first two by every trunk, the last two
-#: where the layers have a shared expert / a dense feed-forward
+#: turn have no ``agent.ff``: the first two by every trunk, the next two
+#: where the layers have a shared expert / a dense feed-forward, the last
+#: where attention is latent
 ROUTED = {"agent.router", "agent.experts"}
-TRUNK_ONLY = ROUTED | {"agent.shared", "agent.dense"}
+SHARED = ROUTED | {"agent.shared", "agent.dense"}
+TRUNK_ONLY = SHARED | {"agent.latent"}
+#: what each family of ``TRUNKS`` opens of them
+OPENS = {"smallthinker": ROUTED, "afmoe": SHARED, "deepseek_v3": TRUNK_ONLY}
 CARRIES = {"_rollout": ROLLOUT, "_insert": {"replay.insert"},
            "_train_iter": TRAIN,
            "_superstep": set(KNOWN_SCOPES) - TRUNK_ONLY}
@@ -81,7 +85,16 @@ TRUNKS = {
               "layer_types": ["sliding_attention", "full_attention",
                               "sliding_attention"],
               "sliding_window": 2, "experts_held": 2, "heads_held": 2,
-              "first_layer": 1}}
+              "first_layer": 1},
+    # published layers 0-1 of 2: a dense and a routed layer
+    "deepseek_v3": {"model_type": "deepseek_v3", "hidden_size": 16,
+                    "head_dim": 2, "num_attention_heads": 4,
+                    "num_key_value_heads": 4, "num_hidden_layers": 2,
+                    "intermediate_size": 12, "moe_intermediate_size": 8,
+                    "n_routed_experts": 4, "num_experts_per_tok": 2,
+                    "kv_lora_rank": 6, "qk_nope_head_dim": 4,
+                    "qk_rope_head_dim": 2, "qk_head_dim": 6,
+                    "v_head_dim": 4, "experts_held": 2, "heads_held": 2}}
 
 
 def tiny_trunk(family: str = "smallthinker"):
@@ -205,9 +218,11 @@ def test_trunk_program_carries_the_trunk_scopes(program, scopes, family):
     and under the learner's scopes (``checkpoint`` bodies, forward and
     backward) — and no ``agent.ff`` outside the mixer's blocks (the
     rollout has no mixer); ``agent.shared`` and ``agent.dense`` exactly
-    where the layers have a shared expert and a dense feed-forward."""
+    where the layers have a shared expert and a dense feed-forward,
+    ``agent.latent`` — inside ``agent.attention`` — where attention is
+    latent."""
     debug, _ = _trunk_texts(family)[program]
-    mine = TRUNK_ONLY if family == "afmoe" else ROUTED
+    mine = OPENS[family]
     assert {s for s in scopes | mine if not token(s).search(debug)} == set()
     assert not any(token(s).search(debug) for s in TRUNK_ONLY - mine)
     if program == "_rollout":
@@ -215,6 +230,8 @@ def test_trunk_program_carries_the_trunk_scopes(program, scopes, family):
     else:
         assert re.search(r"transpose\(jvp\(learner\.agent\)\)", debug)
         assert re.search(r"rematted_computation/agent\.experts", debug)
+    if "agent.latent" in mine:
+        assert re.search(r"agent\.attention/agent\.latent/", debug)
 
 
 _TRUNK_TEXTS = {}
