@@ -56,36 +56,6 @@ GRAD_TOL = {"float32": (1e-4, 1e-5), "bfloat16": (0.05, 0.02)}
 DP_LOSS_RTOL = 2e-4
 
 
-class CompileLedger:
-    """Backend compiles seen by this process, by program name: seconds
-    each (a persistent-cache hit loads in a fraction of the cold time)
-    and the cache's hit count — read from ``jax.monitoring``."""
-
-    def __init__(self) -> None:
-        self.seconds: dict = {}
-        self.cache_hits = 0
-
-    def install(self) -> "CompileLedger":
-        import jax.monitoring
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-        return self
-
-    def _duration(self, event: str, secs: float, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds.setdefault(kw.get("fun_name", "?"), []).append(
-                round(secs, 2))
-
-    def _event(self, event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def of(self, *names: str) -> dict:
-        """{program: [seconds, ...]} of the named jitted functions."""
-        return {n: self.seconds[f"jit({n})"] for n in names
-                if f"jit({n})" in self.seconds}
-
-
 @contextlib.contextmanager
 def phase(name: str):
     """One timed phase: the body fills the yielded dict, which is
@@ -101,8 +71,9 @@ def phase(name: str):
 
 # ---------------------------------------------------------------- train
 
-def phase_train(cfg, workdir: str, ledger: CompileLedger, save: bool = True):
-    """The normal entry point, ``run.run``, for as many driver
+def phase_train(cfg, workdir: str, ledger, save: bool = True):
+    """(``ledger``: an installed ``obs.compiles.CompileListener``.)
+    The normal entry point, ``run.run``, for as many driver
     dispatches as make three train iterations (two at least) ->
     (facts, final TrainState, checkpoint dir). Every cadence (test,
     log, save) fires at every dispatch, so each program the driver owns
@@ -350,7 +321,7 @@ def dp_config(cfg, n: int):
             cfg.replay, buffer_size=cfg.replay.buffer_size * n))
 
 
-def phase_dp(cfg, workdir: str, ledger: CompileLedger, n: int = 4):
+def phase_dp(cfg, workdir: str, ledger, n: int = 4):
     """``run.run`` of ``dp_config(cfg, n)`` (no checkpoints: the
     gathered state is n rings), then on its final state:
     (a) every chip holds a shard of the ring and of the env lanes,
@@ -432,9 +403,13 @@ def main(argv=None) -> int:
     devices = require_tpu(args.chips)
 
     from t2omca_tpu.config import load_config
+    from t2omca_tpu.obs.compiles import CompileListener
     from t2omca_tpu.utils.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
-    ledger = CompileLedger().install()
+    # compile seconds by program and the cache's hits, process-wide (the
+    # package's listener without a recorder; run.run installs its own,
+    # bound to its spans, where a configuration has telemetry on)
+    ledger = CompileListener().install()
     cfg = load_config(CONFIG3)
     # one endpoint per process is the config's; a smoke run needs none
     cfg = cfg.replace(obs=dataclasses.replace(cfg.obs, pulse_port=0))

@@ -1,6 +1,6 @@
 """graftscope — runtime observability for the training and serving stack.
 
-Three pieces (docs/OBSERVABILITY.md):
+Four pieces (docs/OBSERVABILITY.md):
 
 * **span tracing** (``spans.py``) — a low-overhead host-side span
   recorder threaded through every device-facing boundary the watchdog
@@ -8,9 +8,14 @@ Three pieces (docs/OBSERVABILITY.md):
 * **flight recorder** (``spans.py``) — a bounded ring of recent
   events persisted atomically on stall/crash/non-finite/SIGTERM and
   merged into the watchdog's ``stall_diagnosis.json``;
+* **compile and cache counters** (``compiles.py``) — one listener on
+  ``jax.monitoring``, installed by ``run.run`` with telemetry on, that
+  books every compilation to the span it happens in and marks the long
+  ones by program name;
 * **report CLI** (``python -m t2omca_tpu.obs report <run_dir>``) —
   joins the runtime telemetry against graftprog's FLOPs/bytes budgets
-  into a per-program breakdown of wall time per dispatch.
+  into a per-program breakdown of wall time per dispatch, and prints
+  where set-up went, stage by stage.
 
 Plus the **graftpulse live plane** (docs/OBSERVABILITY.md §pulse):
 ``pulse.py`` (Prometheus-text ``/metrics`` + ``/healthz`` + on-demand

@@ -34,6 +34,7 @@ import sys
 from typing import Dict, List, Optional
 
 from ..utils.ioutil import read_jsonl_tolerant
+from .spans import COUNTER_FIELDS
 
 #: span phase -> graftprog program name (analysis/programs.json key).
 #: ``dispatch.test`` dispatches the same compiled rollout program as
@@ -208,6 +209,111 @@ def phase_summary(events: List[dict]) -> Dict[str, dict]:
     return out
 
 
+def setup_summary(events: List[dict]) -> Optional[dict]:
+    """Where set-up went, from the spans and ``compile`` marks alone.
+
+    Set-up is everything up to the end of the last ``first: true`` span
+    of a dispatch or fetch phase (the compile-inclusive first calls of
+    the loop's programs). → ``{"rows": [...], "outside": {...},
+    "total": {...}, "longest": [...]}``: one row per phase in order of
+    first appearance — every span before the ``run`` mark, and after it
+    the first dispatches and fetches and whatever else carries a
+    compilation — with its wall, its
+    SELF time (wall less the spans nested in it, by ``parent``) and its
+    counters; what was compiled outside every span before the ``run``
+    mark (that mark's process-wide counters less the spans'); and the
+    five longest ``compile`` marks. ``None`` for a run recorded without
+    the ``setup.*`` spans."""
+    spans = [e for e in events if e.get("event") == "span"
+             and not e.get("open")
+             and isinstance(e.get("wall_ms"), (int, float))]
+    if not any(str(e.get("phase", "")).startswith("setup.") for e in spans):
+        return None
+    header = run_header(events)
+    t_run = header["t0"] if header else float("inf")
+
+    def first_call(e):
+        return e.get("first") and str(e["phase"]).startswith(
+            ("dispatch.", "fetch."))
+    t_end = max((e["t0"] + e["wall_ms"] / 1e3 for e in spans
+                 if first_call(e)), default=t_run)
+    children: Dict[int, float] = {}
+    for e in spans:
+        if "parent" in e:
+            children[e["parent"]] = children.get(e["parent"], 0.0) \
+                + e["wall_ms"]
+    rows: Dict[str, dict] = {}
+    in_spans = dict.fromkeys(COUNTER_FIELDS, 0.0)
+    for e in sorted(spans, key=lambda e: e["t0"]):
+        if header is None or e["seq"] < header["seq"]:   # began before it
+            for c in COUNTER_FIELDS:
+                in_spans[c] += e.get(c, 0)
+        elif not (e["t0"] < t_end and (e.get("compile_n")
+                                       or first_call(e))):
+            continue
+        r = rows.setdefault(e["phase"], dict.fromkeys(
+            ("n", "wall_ms", "self_ms") + COUNTER_FIELDS, 0))
+        r["n"] += 1
+        r["wall_ms"] += e["wall_ms"]
+        r["self_ms"] += e["wall_ms"] - children.get(e.get("seq"), 0.0)
+        for c in COUNTER_FIELDS:
+            r[c] += e.get(c, 0)
+    total = {c: (header or {}).get(c, 0) for c in COUNTER_FIELDS}
+    marks = [e for e in events if e.get("event") == "mark"
+             and e.get("kind") == "compile" and e.get("t0", 0) <= t_end]
+    return {"rows": [dict(r, phase=ph) for ph, r in rows.items()],
+            "outside": {c: max(total[c] - in_spans[c], 0)
+                        for c in COUNTER_FIELDS},
+            "total": total,
+            "first_t0": min((e["t0"] for e in spans), default=None),
+            "t_run": t_run if header else None, "t_end": t_end,
+            "longest": sorted(marks, key=lambda e: -e.get("secs", 0))[:5]}
+
+
+def render_setup(events: List[dict]) -> List[str]:
+    """The set-up table of :func:`setup_summary`; ``[]`` without one."""
+    su = setup_summary(events)
+    if su is None:
+        return []
+    lines = ["", "set-up: where the time before the loop's steady state "
+                 "went (self = wall less nested spans; trace s counts a "
+                 "jitted function inside its caller's too)"]
+    hdr = (f"{'phase':<22}{'n':>4}{'wall s':>10}{'self s':>10}"
+           f"{'compiles':>10}{'trace s':>9}{'lower s':>9}"
+           f"{'compile s':>11}{'load s':>9}{'hits':>6}{'misses':>8}")
+    lines.append(hdr)
+    lines.append("-" * len(hdr))
+
+    def row(name, n, wall, self_, c):
+        return (f"{name:<22}{n:>4}{_fmt(wall, 3):>10}{_fmt(self_, 3):>10}"
+                f"{int(c['compile_n']):>10}"
+                f"{_fmt(c['trace_ms'] / 1e3, 3):>9}"
+                f"{_fmt(c['lower_ms'] / 1e3, 3):>9}"
+                f"{_fmt(c['compile_ms'] / 1e3, 3):>11}"
+                f"{_fmt(c['cache_load_ms'] / 1e3, 3):>9}"
+                f"{int(c['cache_hits']):>6}{int(c['cache_misses']):>8}")
+    for r in su["rows"]:
+        lines.append(row(r["phase"], r["n"], r["wall_ms"] / 1e3,
+                         r["self_ms"] / 1e3, r))
+    if any(su["outside"].values()):
+        lines.append(row("(in no span)", "", None, None, su["outside"]))
+    lines.append(row("(process, at run mark)", "", None, None, su["total"]))
+    if su["t_run"] is not None and su["first_t0"] is not None:
+        lines.append(
+            f"first span -> run mark {su['t_run'] - su['first_t0']:,.3f} s;"
+            f" run mark -> end of the last first dispatch/fetch "
+            f"{su['t_end'] - su['t_run']:,.3f} s")
+    if su["longest"]:
+        lines.append("longest compilations (marks of 0.5 s or more):")
+        for m in su["longest"]:
+            lines.append(
+                f"  {_fmt(float(m.get('secs', 0)), 3):>9} s  "
+                f"{m.get('fun_name', '?'):<34}"
+                f"{'cache hit' if m.get('cache_hit') else 'compiled':<10}"
+                f"in {m.get('phase') or 'no span'}")
+    return lines
+
+
 def _audit_shapes() -> dict:
     """The frozen audit-config shapes the budgets were measured at
     (jax-free: ``registry.audit_config`` only builds dataclasses)."""
@@ -328,6 +434,7 @@ def render(run_dir: str, events: List[dict], rows: List[dict],
                 f"{ph:<22}{a['n']:>6}{_fmt(a['first_ms']):>10}"
                 f"{_fmt(mean):>10}{_fmt(a['max_ms']):>10}"
                 f"{_fmt(a['total_ms']):>11}{a['errors']:>7}")
+    lines.extend(render_setup(events))
     seb = sebulba_utilization(events, phases)
     if seb:
         lines.append("")

@@ -34,17 +34,25 @@ Event schema (docs/OBSERVABILITY.md): every line is one JSON object.
 
 ``{"event": "span", "seq": N, "phase": str, "t_env": int, "t0":
 <epoch s>, "wall_ms": float, "outcome": "ok" | "error:<Type>",
-"depth": <nesting>, ["first": true,] ...meta}``
-    one completed span; ``first`` marks the first completion of the
-    phase (it includes the XLA compile — the watchdog's compile
-    exemption made measurable, so compile-vs-stall is distinguishable
-    post-mortem). ``meta`` carries call-site context (``attempt``,
-    ``k``, ...).
+"depth": <nesting>, ["parent": <seq>,] ["first": true,]
+[<counters>,] ...meta}``
+    one completed span; ``parent`` is the ``seq`` of the span it is
+    nested in on its own thread (left out at depth 0), so a phase's SELF
+    time is its wall minus its children's; ``first`` marks the first
+    completion of the phase (it includes the XLA compile — the
+    watchdog's compile exemption made measurable, so compile-vs-stall
+    is distinguishable post-mortem). ``<counters>`` are what
+    :meth:`SpanRecorder.count` added while the span was the innermost
+    one open on the counting thread — the compile listener's
+    (``obs/compiles.py``, :data:`COUNTER_FIELDS`), each left out when
+    zero. ``meta`` carries call-site context (``attempt``, ``k``, ...).
 ``{"event": "mark", "seq": N, "kind": str, "t0": <epoch s>, ...meta}``
     one point event (run header, ladder action, non-finite trip,
-    shutdown). The ``kind == "run"`` mark is the run header the report
-    CLI (``python -m t2omca_tpu.obs report``) uses to scale graftprog's
-    audit-config FLOPs/bytes budgets to the run's shapes.
+    shutdown, a long compilation). The ``kind == "run"`` mark is the run
+    header the report CLI (``python -m t2omca_tpu.obs report``) uses to
+    scale graftprog's audit-config FLOPs/bytes budgets to the run's
+    shapes; it is also where set-up ends and the loop starts, and
+    carries the process-wide counters so far.
 
 Everything here is stdlib-only and jit-free — the report CLI and the
 tests must not pay jax import/backend startup for it.
@@ -101,6 +109,18 @@ KNOWN_PHASES = frozenset({
     # when a peer died mid-preemption)
     "checkpoint.save", "collective.gather", "backend.init",
     "checkpoint.elastic", "preempt.barrier", "checkpoint.shard_save",
+    # set-up, stage by stage (run.run, run_sequential, run_sebulba), so
+    # that no second between the recorder's creation and the ``run``
+    # mark is in no span: backend.init is the backend's start and
+    # nothing else; setup.build is Experiment.build; setup.telemetry
+    # the pulse / memwatch / trace-trigger / sight objects;
+    # setup.init_state whichever call makes the train state (or its
+    # abstract template) and the driver's key; setup.restore checkpoint
+    # discovery and load; setup.programs the jitted-program wrappers
+    # (DP / population wrappers too) — building them traces nothing,
+    # and the span's compile counters say so
+    "setup.build", "setup.telemetry", "setup.init_state",
+    "setup.restore", "setup.programs",
     # graftserve boundaries (serve/export.py, serve/frontend.py): the
     # exporter's lower/compile/export pass, artifact load, and the
     # three per-request front-end stages — `obs report` reads a
@@ -165,6 +185,16 @@ KNOWN_SCOPES = frozenset({
     # in-graph telemetry reduces (obs/sight.py)
     "sight",
 })
+
+#: The counters a span (and the ``run`` mark) may carry: what the compile
+#: listener (``obs/compiles.py``) hands :meth:`SpanRecorder.count`. Counts
+#: are integers, ``*_ms`` sums of milliseconds; ``compile_ms`` is backend
+#: compilation proper and ``cache_load_ms`` the persistent cache's
+#: retrievals, so the two add up to the time spent getting executables.
+COUNTER_FIELDS = ("compile_n", "compile_ms", "trace_ms", "lower_ms",
+                  "cache_hits", "cache_misses", "cache_load_ms")
+
+_MS_FIELDS = tuple(n for n in COUNTER_FIELDS if n.endswith("_ms"))
 
 _NOOP = contextlib.nullcontext()
 
@@ -249,7 +279,11 @@ class SpanRecorder:
         self._open_pc: Dict[int, float] = {}         # seq -> perf_counter at begin
         self._seq = 0
         self._first_pending: set = set()             # phases never completed
-        self._depth = threading.local()
+        # per thread: the spans it has open, outermost first — a span's
+        # depth and parent, and the span a count() on that thread goes to
+        self._tl = threading.local()
+        # process-wide sums of everything count() was handed
+        self.counters: Dict[str, float] = {}
         self._file = None
         self._unflushed = 0
         # per-phase aggregation for summary() — O(1) per span, no event
@@ -278,9 +312,12 @@ class SpanRecorder:
         return _Span(self, ev)
 
     def _begin(self, ev: Dict[str, Any]) -> float:
-        d = getattr(self._depth, "n", 0)
-        self._depth.n = d + 1
-        ev["depth"] = d
+        stack = getattr(self._tl, "stack", None)
+        if stack is None:
+            stack = self._tl.stack = []
+        ev["depth"] = len(stack)
+        if stack:
+            ev["parent"] = stack[-1]["seq"]
         ann = None
         if self._annotate is not None:
             # outermost: the annotation covers the bookkeeping too
@@ -295,11 +332,12 @@ class SpanRecorder:
                 self._open_ann[ev["seq"]] = ann
             pc0 = time.perf_counter()
             self._open_pc[ev["seq"]] = pc0
+        stack.append(ev)
         return pc0
 
     def _end(self, ev: Dict[str, Any], pc0: float, exc_type) -> None:
         wall_ms = (time.perf_counter() - pc0) * 1000.0
-        self._depth.n = getattr(self._depth, "n", 1) - 1
+        self._tl.stack.pop()        # spans close LIFO on their own thread
         phase = ev["phase"]
         with self._lock:
             # ev is still registered in _open until the pop below, and
@@ -309,6 +347,9 @@ class SpanRecorder:
             ev["wall_ms"] = round(wall_ms, 3)
             ev["outcome"] = ("ok" if exc_type is None
                              else f"error:{exc_type.__name__}")
+            for name in _MS_FIELDS:
+                if name in ev:
+                    ev[name] = round(ev[name], 3)
             self._open.pop(ev["seq"], None)
             self._open_pc.pop(ev["seq"], None)
             ann = self._open_ann.pop(ev["seq"], None)
@@ -342,6 +383,31 @@ class SpanRecorder:
             ev["seq"] = self._seq
             self._ring.append(ev)
             self._sink(ev)
+
+    def count(self, **amounts) -> Optional[str]:
+        """Add ``amounts`` (:data:`COUNTER_FIELDS`) to the process-wide
+        counters and to the innermost span open on the CALLING thread —
+        work is counted where it happens, and a span of another thread
+        never sees it. → that span's phase, ``None`` outside every span.
+        Zero amounts are dropped, so a field a span never counted is
+        left out of its event."""
+        stack = getattr(self._tl, "stack", None)
+        ev = stack[-1] if stack else None
+        with self._lock:            # tail() copies open spans under it
+            for name, amount in amounts.items():
+                if amount:
+                    self.counters[name] = self.counters.get(name, 0) + amount
+                    if ev is not None:
+                        ev[name] = ev.get(name, 0) + amount
+        return ev["phase"] if ev is not None else None
+
+    def totals(self) -> Dict[str, float]:
+        """The process-wide counters so far (the ``run`` mark's; a
+        listener puts every field there when it is installed, so the
+        mark of a run that compiled nothing carries zeros)."""
+        with self._lock:
+            return {name: round(v, 3) if isinstance(v, float) else v
+                    for name, v in self.counters.items()}
 
     # -- sink ------------------------------------------------------------
 

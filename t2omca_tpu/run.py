@@ -37,6 +37,7 @@ from .learners.qmix_learner import LEARNER_REGISTRY, LearnerState
 from .runners import RUNNER_REGISTRY
 from .runners.episode_runner import EpisodeRunner
 from .runners.parallel_runner import ParallelRunner, RunnerState
+from .obs import compiles as obs_compiles
 from .obs import memwatch as obs_memwatch
 from .obs import pulse as obs_pulse
 from .obs import sight as obs_sight
@@ -942,15 +943,24 @@ def run(cfg: TrainConfig, logger: Optional[Logger] = None) -> TrainState:
     # profiler session is running)
     rec = obs_spans.make_recorder(cfg.obs, results_dir,
                                   annotate=jax.profiler.TraceAnnotation)
-    # the first jax computation in the build triggers backend init
-    with rec.span("backend.init"):
-        exp = Experiment.build(cfg)
-    # reference dispatch (per_run.py:192): save_animation alone does NOT
-    # divert to evaluation — it enables the in-training animation cadence
-    if cfg.evaluate or cfg.save_replay:
-        rec.close()             # eval path records no further spans
-        return evaluate_sequential(exp, logger, results_dir)
-    return run_sequential(exp, logger, results_dir, rec=rec)
+    # the program's own compile and cache counters (obs/compiles.py):
+    # every compilation is booked to the span it happens in, for this
+    # run only, and nothing listens with telemetry off
+    with obs_compiles.listening(rec):
+        # the backend's start and nothing else (a process that has
+        # started it — benchmark/run.py looks for its chips first —
+        # passes through)
+        with rec.span("backend.init"):
+            jax.devices()
+        with rec.span("setup.build"):
+            exp = Experiment.build(cfg)
+        # reference dispatch (per_run.py:192): save_animation alone does
+        # NOT divert to evaluation — it enables the in-training animation
+        # cadence
+        if not (cfg.evaluate or cfg.save_replay):
+            return run_sequential(exp, logger, results_dir, rec=rec)
+    rec.close()                 # eval path records no further spans
+    return evaluate_sequential(exp, logger, results_dir)
 
 
 def run_sequential(exp: Experiment, logger: Logger,
@@ -989,22 +999,24 @@ def run_sequential(exp: Experiment, logger: Logger,
     # ---- graftpulse live telemetry plane (docs/OBSERVABILITY.md §pulse)
     # obs.pulse_port unset (default) leaves all three as no-op/None —
     # the loop below is byte-identical to a build without the plane
-    pulse = obs_pulse.make_pulse(cfg.obs, rec=rec, log=log)
-    mw = obs_memwatch.make_memwatch(cfg.obs, rec=rec)
-    mw.snapshot("startup", t_env=0)
-    trc = (obs_pulse.TraceController(
-               results_dir, rec=rec,
-               hub=pulse.hub if pulse is not None else None,
-               n_iterations=cfg.profile_iterations)
-           if (rec.enabled or pulse is not None) else None)
-    # graftsight learning-health monitor (docs/OBSERVABILITY.md §6):
-    # None when obs.sight is off — the loop below is byte-identical.
-    # The in-graph half already rode the train programs; this is the
-    # host detector pass over the log-cadence fetch. Under a population
-    # the detectors run PER MEMBER over the (P,)-leading fetched leaves
-    # and the /healthz verdicts name pop<i> (sight.PopulationSightMonitor).
-    sight_mon = obs_sight.make_monitor(cfg.obs, logger=logger, rec=rec,
-                                       population=P)
+    with rec.span("setup.telemetry"):
+        pulse = obs_pulse.make_pulse(cfg.obs, rec=rec, log=log)
+        mw = obs_memwatch.make_memwatch(cfg.obs, rec=rec)
+        mw.snapshot("startup", t_env=0)
+        trc = (obs_pulse.TraceController(
+                   results_dir, rec=rec,
+                   hub=pulse.hub if pulse is not None else None,
+                   n_iterations=cfg.profile_iterations)
+               if (rec.enabled or pulse is not None) else None)
+        # graftsight learning-health monitor (docs/OBSERVABILITY.md §6):
+        # None when obs.sight is off — the loop below is byte-identical.
+        # The in-graph half already rode the train programs; this is the
+        # host detector pass over the log-cadence fetch. Under a
+        # population the detectors run PER MEMBER over the (P,)-leading
+        # fetched leaves and the /healthz verdicts name pop<i>
+        # (sight.PopulationSightMonitor).
+        sight_mon = obs_sight.make_monitor(cfg.obs, logger=logger, rec=rec,
+                                           population=P)
 
     # ---- data parallelism (SURVEY.md §7.2(6)) --------------------------
     # dp_devices > 0 swaps in the mesh-sharded program triple; the loop
@@ -1021,13 +1033,15 @@ def run_sequential(exp: Experiment, logger: Logger,
         # state is device_put with population_shardings below and the
         # unchanged vmapped programs propagate the member axis.
         from .parallel import make_mesh, population_shardings
-        pop_mesh = make_mesh(cfg.dp_devices)
+        with rec.span("setup.programs"):
+            pop_mesh = make_mesh(cfg.dp_devices)
         log.info(f"population-over-dp: {P} members sharded over "
                  f"{cfg.dp_devices} devices (mesh axis 'data', "
                  f"{P // cfg.dp_devices} members per device)")
     elif cfg.dp_devices:
         from .parallel import DataParallel, make_mesh
-        dp = DataParallel(exp, make_mesh(cfg.dp_devices))
+        with rec.span("setup.programs"):
+            dp = DataParallel(exp, make_mesh(cfg.dp_devices))
         log.info(f"data-parallel over {cfg.dp_devices} devices "
                  f"(mesh axis 'data')")
     # resolve the resume target FIRST: a checkpoint_path pointing at an
@@ -1035,45 +1049,58 @@ def run_sequential(exp: Experiment, logger: Logger,
     # fresh start and must take the born-sharded init below
     found = None
     if cfg.checkpoint_path:
-        found = find_checkpoint(cfg.checkpoint_path, cfg.load_step)
+        with rec.span("setup.restore", stage="find"):
+            found = find_checkpoint(cfg.checkpoint_path, cfg.load_step)
         if found is None:
             log.info(f"no checkpoint found in {cfg.checkpoint_path}")
-    if dp is not None and found is None:
-        # fresh DP start: build the state BORN sharded (out_shardings) —
-        # the single-device-then-reshard path holds a full extra copy of
-        # the replay ring at startup, an OOM at config-5 ring sizes
-        ts = dp.init_sharded(cfg.seed)
-    elif dp is not None:
-        # DP resume: restore each leaf straight onto the mesh — the
-        # classic init → load → shard sequence re-creates the same
-        # single-device ring transient the born-sharded init exists to
-        # avoid (ADVICE r5). elastic.resume_state keeps the rigid
-        # load_checkpoint_sharded path when the topology stamp matches
-        # and routes population/topology changes through restore_elastic
-        # (docs/RESILIENCE.md §6).
-        shapes = jax.eval_shape(lambda: exp.init_train_state(cfg.seed))
-        ts, _ = elastic.resume_state(found[0], shapes,
-                                     dp.state_shardings(shapes),
-                                     verify=False,
-                                     topology={"loop": "classic"})
-    elif P and found is None:
-        # population init: P explicit solo inits stacked — member i's
-        # leaves are bit-identical to a solo init at seed_i
-        ts, spec = graftpop.init_population(exp, cfg)
-    elif P:
-        # population RESUME: an abstract template only — P concrete
-        # inits here would materialize P replay rings just to be
-        # discarded by the load below (the ADVICE-r5 init-then-load
-        # transient, ×P). The spec stays concrete: a single-member
-        # (v4) checkpoint lifting into this template takes its spec
-        # from HERE (the config's grids), not from zero-filled avals.
-        ts = jax.eval_shape(lambda: graftpop.init_population(exp, cfg))[0]
-        spec = graftpop.build_spec(cfg)
-    else:
-        ts = exp.init_train_state(cfg.seed)
+    with rec.span("setup.init_state"):
+        if dp is not None and found is None:
+            # fresh DP start: build the state BORN sharded
+            # (out_shardings) — the single-device-then-reshard path
+            # holds a full extra copy of the replay ring at startup, an
+            # OOM at config-5 ring sizes
+            ts = dp.init_sharded(cfg.seed)
+        elif dp is not None:
+            # DP resume: restore each leaf straight onto the mesh — the
+            # classic init → load → shard sequence re-creates the same
+            # single-device ring transient the born-sharded init exists
+            # to avoid (ADVICE r5). elastic.resume_state keeps the rigid
+            # load_checkpoint_sharded path when the topology stamp
+            # matches and routes population/topology changes through
+            # restore_elastic (docs/RESILIENCE.md §6).
+            shapes = jax.eval_shape(
+                lambda: exp.init_train_state(cfg.seed))
+            with rec.span("setup.restore", stage="load"):
+                ts, _ = elastic.resume_state(found[0], shapes,
+                                             dp.state_shardings(shapes),
+                                             verify=False,
+                                             topology={"loop": "classic"})
+        elif P and found is None:
+            # population init: P explicit solo inits stacked — member
+            # i's leaves are bit-identical to a solo init at seed_i
+            ts, spec = graftpop.init_population(exp, cfg)
+        elif P:
+            # population RESUME: an abstract template only — P concrete
+            # inits here would materialize P replay rings just to be
+            # discarded by the load below (the ADVICE-r5 init-then-load
+            # transient, ×P). The spec stays concrete: a single-member
+            # (v4) checkpoint lifting into this template takes its spec
+            # from HERE (the config's grids), not from zero-filled avals.
+            ts = jax.eval_shape(
+                lambda: graftpop.init_population(exp, cfg))[0]
+            spec = graftpop.build_spec(cfg)
+        else:
+            ts = exp.init_train_state(cfg.seed)
+        # per-member driver key streams under a population (each
+        # member's stream splits exactly like the classic loop's single
+        # one)
+        key = graftpop.member_keys(cfg) if P else jax.random.PRNGKey(
+            cfg.seed + 1)
     # the driver loop replaces its state right after every call, so the
     # replay ring / train state can be donated (in-place on device)
-    rollout, insert, train_iter = (dp or exp).jitted_programs(donate=True)
+    with rec.span("setup.programs"):
+        rollout, insert, train_iter = (dp or exp).jitted_programs(
+            donate=True)
 
     # fused superstep (config.superstep, docs/SPEC.md §8): K > 1 swaps the
     # three-program iteration for ONE donated program scanning K rollout→
@@ -1089,25 +1116,23 @@ def run_sequential(exp: Experiment, logger: Logger,
 
     K = cfg.superstep if superstep_eligible(cfg) else 1
     pop_test = None
-    if P:
-        K = max(cfg.superstep, 1)
-        superstep = _build_superstep(K)
-        pop_test = exp.population_rollout_program()
-        log.info(f"population superstep: {P} members x {K} iterations "
-                 f"per dispatch")
-    else:
-        superstep = _build_superstep(K) if K > 1 else None
-    if cfg.superstep > 1 and K == 1:
-        log.info("superstep requested but ineligible (buffer_cpu_only "
-                 "keeps the three-program path)")
-    elif K > 1 and not P:
-        log.info(f"fused superstep: {K} iterations per dispatch")
-    log.info(exp.mac.describe_acting(cfg.batch_size_run,
-                                     jax.default_backend()))
-    # per-member driver key streams under a population (each member's
-    # stream splits exactly like the classic loop's single one)
-    key = graftpop.member_keys(cfg) if P else jax.random.PRNGKey(
-        cfg.seed + 1)
+    with rec.span("setup.programs"):
+        if P:
+            K = max(cfg.superstep, 1)
+            superstep = _build_superstep(K)
+            pop_test = exp.population_rollout_program()
+            log.info(f"population superstep: {P} members x {K} "
+                     f"iterations per dispatch")
+        else:
+            superstep = _build_superstep(K) if K > 1 else None
+        if cfg.superstep > 1 and K == 1:
+            log.info("superstep requested but ineligible (buffer_cpu_only "
+                     "keeps the three-program path)")
+        elif K > 1 and not P:
+            log.info(f"fused superstep: {K} iterations per dispatch")
+        # (imports the kernel modules the first trace will need)
+        log.info(exp.mac.describe_acting(cfg.batch_size_run,
+                                         jax.default_backend()))
 
     def _ckpt_state():
         """What checkpoints hold: the bare TrainState classically, the
@@ -1125,15 +1150,17 @@ def run_sequential(exp: Experiment, logger: Logger,
             # P=stacked — utils/checkpoint._migrate_raw). A stamped
             # P-mismatch (grow/shrink since the save) routes through
             # restore_elastic via elastic.resume_state.
-            ps, _ = elastic.resume_state(dirname, _ckpt_state(),
-                                         verify=False,
-                                         topology={"loop": "classic"})
+            with rec.span("setup.restore", stage="load"):
+                ps, _ = elastic.resume_state(dirname, _ckpt_state(),
+                                             verify=False,
+                                             topology={"loop": "classic"})
             ts, spec = ps.ts, ps.spec
         elif dp is None:
             # find_checkpoint already hashed this candidate — skip
             # re-verify (the DP path restored sharded above)
-            ts, _ = elastic.resume_state(dirname, ts, verify=False,
-                                         topology={"loop": "classic"})
+            with rec.span("setup.restore", stage="load"):
+                ts, _ = elastic.resume_state(dirname, ts, verify=False,
+                                             topology={"loop": "classic"})
         t_env = step
         new_t = (jnp.full((P,), step, jnp.int32) if P
                  else jnp.asarray(step, jnp.int32))
@@ -1286,7 +1313,9 @@ def run_sequential(exp: Experiment, logger: Logger,
     tracer = TraceWindow(cfg.profile_dir, cfg.profile_start,
                          cfg.profile_iterations)
     # run header for the report CLI: the shapes that scale graftprog's
-    # audit-config budgets to this run (obs/report.py)
+    # audit-config budgets to this run (obs/report.py). Set-up ends and
+    # the loop starts here: the mark carries what the process has
+    # compiled so far (obs/compiles.py)
     if rec.enabled:
         from .envs.registry import scenario_config
         rec.mark("run", t_env=t_env, backend=jax.default_backend(),
@@ -1294,7 +1323,8 @@ def run_sequential(exp: Experiment, logger: Logger,
                  episode_limit=cfg.env_args.episode_limit,
                  batch_size=cfg.batch_size, superstep=K,
                  host_buffer=exp.host_buffer, population=P,
-                 scenario=scenario_config(cfg.env_args).kind)
+                 scenario=scenario_config(cfg.env_args).kind,
+                 **rec.totals())
     # per-stage barriers for honest attribution; tracing implies them
     # (an un-synced trace window would capture dispatch, not execution)
     sync_stages = cfg.profile_stages or bool(cfg.profile_dir)
@@ -2202,26 +2232,28 @@ def run_sebulba(exp: Experiment, logger: Logger, results_dir: str,
     # graftpulse plane (same off-state contract as the classic loop);
     # the decoupled layout is the one Podracer says lives or dies on
     # utilization you can see live — queue depth, staleness, idle time
-    pulse = obs_pulse.make_pulse(cfg.obs, rec=rec, log=log)
-    mw = obs_memwatch.make_memwatch(cfg.obs, rec=rec)
-    mw.snapshot("startup", t_env=0)
-    # on-demand trace trigger, driven from the learner (main) thread —
-    # the profiler window captures whole-process device activity, so
-    # one driver is enough and the /trace route works on decoupled
-    # runs exactly like classic ones
-    trc = (obs_pulse.TraceController(
-               results_dir, rec=rec,
-               hub=pulse.hub if pulse is not None else None,
-               n_iterations=cfg.profile_iterations)
-           if (rec.enabled or pulse is not None) else None)
+    with rec.span("setup.telemetry"):
+        pulse = obs_pulse.make_pulse(cfg.obs, rec=rec, log=log)
+        mw = obs_memwatch.make_memwatch(cfg.obs, rec=rec)
+        mw.snapshot("startup", t_env=0)
+        # on-demand trace trigger, driven from the learner (main)
+        # thread — the profiler window captures whole-process device
+        # activity, so one driver is enough and the /trace route works
+        # on decoupled runs exactly like classic ones
+        trc = (obs_pulse.TraceController(
+                   results_dir, rec=rec,
+                   hub=pulse.hub if pulse is not None else None,
+                   n_iterations=cfg.profile_iterations)
+               if (rec.enabled or pulse is not None) else None)
 
-    # graftsight monitor (learner-thread cadence pass; same off-state
-    # contract as the classic loop)
-    sight_mon = obs_sight.make_monitor(cfg.obs, logger=logger, rec=rec,
-                                       population=P)
+        # graftsight monitor (learner-thread cadence pass; same
+        # off-state contract as the classic loop)
+        sight_mon = obs_sight.make_monitor(cfg.obs, logger=logger,
+                                           rec=rec, population=P)
 
     from .parallel.sebulba import make_sebulba
-    seb = make_sebulba(exp)
+    with rec.span("setup.programs"):
+        seb = make_sebulba(exp)
     spec = seb.spec
     lockstep = sb.queue_slots == 1 and sb.staleness == 0
     log.info(f"sebulba decoupled loop: {sb.actor_devices} actor + "
@@ -2245,7 +2277,8 @@ def run_sebulba(exp: Experiment, logger: Logger, results_dir: str,
     test_quota = n_test_runs * cfg.batch_size_run * max(P, 1)
     buffer_capacity = exp.buffer.capacity
 
-    actor_step, queue_put, queue_get, learner_step = seb.programs()
+    with rec.span("setup.programs"):
+        actor_step, queue_put, queue_get, learner_step = seb.programs()
 
     # ---- cross-thread cells (all access under `cond` unless noted) ----
     cond = threading.Condition()
@@ -2289,7 +2322,8 @@ def run_sebulba(exp: Experiment, logger: Logger, results_dir: str,
     # ---- resume target ------------------------------------------------
     found = None
     if cfg.checkpoint_path:
-        found = find_checkpoint(cfg.checkpoint_path, cfg.load_step)
+        with rec.span("setup.restore", stage="find"):
+            found = find_checkpoint(cfg.checkpoint_path, cfg.load_step)
         if found is None:
             log.info(f"no checkpoint found in {cfg.checkpoint_path}")
 
@@ -2481,10 +2515,6 @@ def run_sebulba(exp: Experiment, logger: Logger, results_dir: str,
                 cond.notify_all()    # wake a learner waiting on the queue
 
     # ---- state init / resume ------------------------------------------
-    # per-member driver key streams under a population (each member's
-    # stream splits exactly like the classic loop's single one)
-    key = graftpop.member_keys(cfg) if P else jax.random.PRNGKey(
-        cfg.seed + 1)
     t_env = 0
 
     def _ckpt_state(ts_):
@@ -2555,7 +2585,16 @@ def run_sebulba(exp: Experiment, logger: Logger, results_dir: str,
         log.info(f"resumed from {dirname} at t_env={step}")
         return rs, ls, step
 
-    rs0, ls, t_env = _place(found)
+    # fresh: both halves initialized on their meshes; resume: the
+    # abstract templates and the load straight onto the meshes
+    with rec.span("setup.init_state" if found is None
+                  else "setup.restore", stage="place"):
+        # per-member driver key streams under a population (each
+        # member's stream splits exactly like the classic loop's single
+        # one)
+        key = graftpop.member_keys(cfg) if P else jax.random.PRNGKey(
+            cfg.seed + 1)
+        rs0, ls, t_env = _place(found)
 
     if rec.enabled:
         rec.mark("run", t_env=t_env, backend=jax.default_backend(),
@@ -2565,7 +2604,8 @@ def run_sebulba(exp: Experiment, logger: Logger, results_dir: str,
                  host_buffer=False, sebulba=True, population=P,
                  actor_devices=sb.actor_devices,
                  learner_devices=sb.learner_devices,
-                 queue_slots=sb.queue_slots, staleness=sb.staleness)
+                 queue_slots=sb.queue_slots, staleness=sb.staleness,
+                 **rec.totals())
 
     last_log_t = t_env
     last_save_t = t_env if t_env else -cfg.save_model_interval - 1

@@ -15,6 +15,7 @@ import pytest
 
 from t2omca_tpu.config import (EnvConfig, ModelConfig, ReplayConfig,
                                TrainConfig, load_config, sanity_check)
+from t2omca_tpu.obs.compiles import CompileListener
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, REPO)
@@ -38,8 +39,12 @@ def trained(tmp_path_factory):
     """train -> (facts, state, checkpoint dir), shared by the phases
     that follow it in the chip run."""
     work = str(tmp_path_factory.mktemp("chip_smoke"))
-    ledger = chip_smoke.CompileLedger().install()
-    return (work, ledger) + chip_smoke.phase_train(tiny_cfg(), work, ledger)
+    ledger = CompileListener().install()
+    try:
+        yield (work, ledger) + chip_smoke.phase_train(tiny_cfg(), work,
+                                                     ledger)
+    finally:
+        ledger.uninstall()
 
 
 def test_train_phase_trains_checkpoints_and_compiles_once(trained):
@@ -88,8 +93,11 @@ def test_dp_phase_on_four_virtual_devices(tmp_path):
     """guide §2.2: the four-chip path on four of the CPU's virtual
     devices — shards everywhere, params identical, loss parity."""
     assert len(jax.devices()) >= 4
-    ledger = chip_smoke.CompileLedger().install()
-    out = chip_smoke.phase_dp(tiny_cfg(), str(tmp_path), ledger, n=4)
+    ledger = CompileListener().install()
+    try:
+        out = chip_smoke.phase_dp(tiny_cfg(), str(tmp_path), ledger, n=4)
+    finally:
+        ledger.uninstall()
     assert out["dp_devices"] == 4
     assert out["env_lanes_per_chip"] == 4 and out["ring_episodes_per_chip"] == 8
     assert out["loss_rel_diff"] <= chip_smoke.DP_LOSS_RTOL
