@@ -102,17 +102,19 @@ def check_supported(cfg, k: int) -> None:
 # ------------------------------------------------ what the program produced
 
 def gather_program(cfg, k: int, snap: dict, infos: list, ts) -> dict:
-    """Everything the comparison takes of the program, small enough to
-    outlive the TrainState: host copies of scalars and vectors, device
-    copies of the learner's trees, and a *reference* to the ring (the
-    batch is gathered from it before the state is dropped)."""
+    """Everything the comparison takes of the program's state after the
+    followed dispatch, as host copies, so that nothing of it stays on the
+    chip once the caller drops the TrainState (the batch and the acting
+    sample are gathered from its ring before that)."""
     import jax
     if infos is None or len(infos) != k:
         raise RuntimeError(f"expected {k} info rows of the followed "
                            f"dispatch, got {None if infos is None else len(infos)}")
     keys = ("loss", "grad_norm", "td_errors_abs", "all_finite")
-    rows = jax.device_get([{n: i[n] for n in keys} for i in infos])
     adam = _find_adam(ts.learner.opt_state)
+    rows, after = jax.device_get((
+        [{n: i[n] for n in keys} for i in infos],
+        {"params": ts.learner.params, "mu": adam.mu, "nu": adam.nu}))
     return {
         "infos": rows,
         "priorities": np.asarray(ts.buffer.priorities),
@@ -125,8 +127,8 @@ def gather_program(cfg, k: int, snap: dict, infos: list, ts) -> dict:
             "t_env": int(ts.runner.t_env),
             "adam_count": int(adam.count),
         },
-        "params_after": ts.learner.params,
-        "mu_after": adam.mu, "nu_after": adam.nu,
+        "params_after": after["params"],
+        "mu_after": after["mu"], "nu_after": after["nu"],
     }
 
 
@@ -327,29 +329,38 @@ def optimizer_numbers(cfg, snap: dict, prog: dict) -> dict:
     before. ``clip_gap``: its global norm against the clip (or the
     gradient's own norm, where that is smaller). ``adam_gap``: the
     reference's Adam step on that gradient, from the state before the
-    update, against the change the program made, by the worst leaf."""
+    update, against the change the program made, by the worst leaf.
+
+    One program a leaf, so that the chip holds five leaves of the state
+    at a time and not five copies of it; the leaves' squared sums, their
+    norms, and the median and worst leaf are then combined as one
+    program over all of them would combine them."""
     import jax
     import jax.numpy as jnp
     from benchmark.reference import qmix
     adam0 = _find_adam(snap["learner"].opt_state)
-    before = _strip(snap["learner"].params)
+    count = jnp.asarray(adam0.count)
 
-    def f(before, after, mu0, nu0, mu1):
-        g = jax.tree.map(lambda m1, m0: (m1 - B1 * m0) / (1 - B1), mu1, mu0)
-        new, _, _ = qmix.adam_step(before, g, mu0, nu0, adam0.count,
-                                   lr=cfg.lr, b1=B1, b2=B2,
-                                   eps=cfg.optim_eps)
+    @jax.jit
+    def leaf(before, after, mu0, nu0, mu1):
+        g = (mu1 - B1 * mu0) / (1 - B1)
+        new, _, _ = qmix.adam_step(before, g, mu0, nu0, count, lr=cfg.lr,
+                                   b1=B1, b2=B2, eps=cfg.optim_eps)
+        norm = lambda x: jnp.sqrt(jnp.sum(x ** 2))          # noqa: E731
+        return (jnp.sum(g.astype(jnp.float32) ** 2), norm(new - before),
+                norm(after - new))
+
+    @jax.jit
+    def combine(squares, ref, off):
         # the norm of (program's change - reference's), by the worst leaf,
         # against the reference's norm of that leaf or of the median leaf
-        norms = lambda t: jnp.stack([jnp.sqrt(jnp.sum(x ** 2))  # noqa: E731
-                                     for x in jax.tree.leaves(t)])
-        ref = norms(jax.tree.map(lambda n, b: n - b, new, before))
-        off = norms(jax.tree.map(lambda a, n: a - n, after, new))
-        return (qmix.global_norm(g),
+        ref, off = jnp.stack(ref), jnp.stack(off)
+        return (jnp.sqrt(sum(squares)),
                 (off / jnp.maximum(ref, jnp.median(ref))).max())
-    g_norm, adam_gap = jax.jit(f)(
-        before, _strip(prog["params_after"]), _strip(adam0.mu),
-        _strip(adam0.nu), _strip(prog["mu_after"]))
+    trees = [_strip(t) for t in (snap["learner"].params, prog["params_after"],
+                                 adam0.mu, adam0.nu, prog["mu_after"])]
+    per_leaf = [leaf(*xs) for xs in zip(*map(jax.tree.leaves, trees))]
+    g_norm, adam_gap = combine(*map(list, zip(*per_leaf)))
     want = min(float(cfg.grad_norm_clip),
                float(prog["infos"][-1]["grad_norm"]))
     return {"clip_gap": abs(float(g_norm) - want) / want,
@@ -443,6 +454,7 @@ def agent_qs(ref, agent_params, acting: dict, prec="f32"):
     import jax
     import jax.numpy as jnp
     f = jax.jit(lambda p, b: ref.agent_qs(p, b, prec=prec))
+    agent_params = jax.device_put(agent_params)     # once, not per block
     n = acting["mec"].shape[1]
     obs = {x: acting[x] for x in ("rows", "mec", "mean", "std")}
     return jnp.concatenate(
@@ -525,7 +537,9 @@ class Comparison:
     """One run's comparison. Built while the TrainState lives (it gathers
     the batch and the acting sample from the program's ring); ``finish``
     runs the reference and is called once the caller has dropped the
-    state."""
+    state. The learner's trees, before the followed dispatch (``snap``,
+    ``harness.snapshot``) and after it, are host copies: each reference
+    program takes to the chip what it computes with, and no more."""
 
     def __init__(self, cfg, k: int, snap: dict, infos: list, ts, ref):
         check_supported(cfg, k)
@@ -537,9 +551,6 @@ class Comparison:
         self.td = {}
         if not self.prog_out["all_finite"]:
             return              # five dispatches in a row ended non-finite
-        self.before = params_before(cfg, k, snap, prog)
-        self.target = (self.before if k > 1
-                       else snap["learner"].target_params)
         self.batch = gather_batch(ts, self.idx)
         self.acting = gather_acting(cfg, ts, newest)
 
@@ -558,6 +569,10 @@ class Comparison:
             numbers["last_update_skipped"] = 1.0
             return numbers
         numbers["last_update_skipped"] = 0.0
+        # K > 1 takes the parameters back on the chip: not beside the state
+        self.before = params_before(self.cfg, self.k, self.snap, self.prog)
+        self.target = (self.before if self.k > 1
+                       else self.snap["learner"].target_params)
         ref_out = self.ref_out = self.reference()
         numbers.update(learner_numbers(self.prog_out, ref_out))
         # the per-episode |TD| themselves, for the record

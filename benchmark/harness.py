@@ -11,8 +11,8 @@ The window drives ``t2omca_tpu.run.run`` — the entry ``python -m
 t2omca_tpu train`` calls — with the configuration's own ``superstep`` and
 cadences. The harness sits on the program's fault-injection hooks
 (``utils/resilience.register_fault``): ``driver.iteration`` fires at every
-dispatch boundary of the driver loop; ``guard.request`` from it ends the
-loop at that boundary.
+dispatch boundary of the driver loop with the loop's ``ts``, ``key`` and
+``train_infos``; ``guard.request`` from it ends the loop at that boundary.
 """
 
 from __future__ import annotations
@@ -21,7 +21,6 @@ import dataclasses
 import importlib.util
 import json
 import os
-import sys
 import time
 from typing import Any, Optional
 
@@ -126,20 +125,6 @@ class CompileLedger:
 
 # ------------------------------------------------------------- the window
 
-def _driver_locals() -> dict:
-    """The driver loop's local variables, read (never written) from the
-    frame that fired the hook. The program hands its hooks ``t_env`` and
-    the guard only; the train state, the driver's key stream and the
-    pending info rows are locals of ``run_sequential`` (PERF.md, open
-    questions: the hook should hand them over)."""
-    f = sys._getframe(1)
-    while f is not None and f.f_code.co_name != "run_sequential":
-        f = f.f_back
-    if f is None:
-        raise RuntimeError("hook fired outside run_sequential")
-    return f.f_locals
-
-
 class Window:
     """Opens, closes and follows the measured window from the driver's
     hooks. One *period* = ``period_iterations`` training iterations and
@@ -176,10 +161,17 @@ class Window:
         self.followed_from = None
         self.refollowed = 0
         self.memory = {}
+        self.snapshot_s = None
+        self.host_available = {}         # bytes, around the last snapshot
 
     # -- hooks ---------------------------------------------------------
-    def on_boundary(self, t_env=None, guard=None, **_):
+    def on_boundary(self, t_env=None, guard=None, ts=None, key=None, **_):
         import jax
+        if ts is None or key is None:
+            raise RuntimeError(
+                "benchmark: driver.iteration fired without ts= and key= "
+                "(only run_sequential's loop hands them over; the harness "
+                "drives no other loop)")
         it = t_env // self.spi
         now = time.perf_counter()
         if self.phase == "warmup":
@@ -210,7 +202,7 @@ class Window:
             self.it_close = it
             self.compiles_close = self.ledger.events
             self.memory = dict(jax.devices()[0].memory_stats() or {})
-            self.snap = snapshot(_driver_locals())
+            self._snapshot(ts, key, t_env)
             self.followed_from = it
             self.phase = "check"
         elif self.phase == "check":
@@ -223,18 +215,29 @@ class Window:
                 self.infos[-1]["all_finite"])))
             if not ok and self.refollowed < 4:
                 self.refollowed += 1
-                self.snap = snapshot(_driver_locals())
+                self._snapshot(ts, key, t_env)
                 self.infos = None
                 self.followed_from = it
                 return
             guard.request("benchmark: window closed")
             self.phase = "done"
 
-    def on_infos(self, **_):
+    def on_infos(self, train_infos=None, **_):
         """``fetch.train_infos``: the info rows of the dispatch the
         comparison follows, before the driver drops them."""
         if self.phase == "check":
-            self.infos = list(_driver_locals()["train_infos"])
+            if train_infos is None:
+                raise RuntimeError("benchmark: fetch.train_infos fired "
+                                   "without train_infos=")
+            self.infos = list(train_infos)
+
+    def _snapshot(self, ts, key, t_env):
+        before = host_available_bytes()
+        t0 = time.perf_counter()
+        self.snap = snapshot(ts, key, t_env)
+        self.snapshot_s = time.perf_counter() - t0
+        self.host_available = {"before": before,
+                               "after": host_available_bytes()}
 
     # -- readings ------------------------------------------------------
     @property
@@ -258,25 +261,42 @@ class Window:
         return self.compiles_close - self.compiles_open
 
 
-def snapshot(loc: dict) -> dict:
-    """Device copies (the next dispatch donates the state) of what the
-    comparison needs of the state at a boundary: the learner's state, the
-    ring's priorities and counters, the driver's key and the runner's."""
+def snapshot(ts, key, t_env) -> dict:
+    """Host copies of what the comparison needs of the state at a
+    boundary: the learner's state, the ring's priorities and counters, the
+    driver's key and the runner's (raw ``uint32`` keys), as numpy leaves in
+    the state's own tree structure. ``device_get`` waits for the state and
+    returns before the next dispatch donates it; the chip holds no second
+    copy of the learner state while the comparison is followed."""
     import jax
-    import jax.numpy as jnp
-    ts = loc["ts"]
-    cp = lambda t: jax.tree.map(jnp.copy, t)            # noqa: E731
-    return {
-        "learner": cp(ts.learner),
-        "priorities": jnp.copy(ts.buffer.priorities),
-        "max_priority": jnp.copy(ts.buffer.max_priority),
-        "insert_pos": jnp.copy(ts.buffer.insert_pos),
-        "episodes_in_buffer": jnp.copy(ts.buffer.episodes_in_buffer),
-        "episode": jnp.copy(ts.episode),
-        "key": jnp.copy(loc["key"]),
-        "runner_key": jnp.copy(ts.runner.key),
-        "t_env": int(loc["t_env"]),
-    }
+    import numpy as np
+    host = jax.device_get({
+        "learner": ts.learner,
+        "priorities": ts.buffer.priorities,
+        "max_priority": ts.buffer.max_priority,
+        "insert_pos": ts.buffer.insert_pos,
+        "episodes_in_buffer": ts.buffer.episodes_in_buffer,
+        "episode": ts.episode,
+        "key": key,
+        "runner_key": ts.runner.key,
+    })
+    if jax.default_backend() == "cpu":
+        # there ``device_get`` hands back views of the device buffers
+        host = jax.tree.map(np.array, host)
+    host["t_env"] = int(t_env)
+    return host
+
+
+def host_available_bytes() -> Optional[int]:
+    """``MemAvailable`` of ``/proc/meminfo`` (None where there is none)."""
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    return int(line.split()[1]) * 1024
+    except OSError:
+        pass
+    return None
 
 
 def drive(cfg, window: Window):

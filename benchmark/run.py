@@ -76,7 +76,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, workdir: str,
     ok, compared = check.verdict(
         numbers, check.load_limits(cell.config_name, bench_dir))
     numbers = plain(numbers)
+    # prints, not metrics: the chip's peak once the reference has run,
+    # and the host copy of the state taken at the window's close
     say({"phase": "compare", "seconds": time.perf_counter() - t0,
+         "check_peak_bytes": (devices[0].memory_stats() or {}).get(
+             "peak_bytes_in_use"),
+         "snapshot_s": window.snapshot_s,
+         "host_available_bytes": window.host_available,
          "numbers": numbers})
     if extra is not None:
         extra(comparison, window)

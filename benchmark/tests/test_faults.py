@@ -25,10 +25,10 @@ from benchmark import check
 from benchmark.tests import tiny
 
 
-def _run(k=2, **kw):
+def _run(k=2, seconds=0.5, **kw):
     root = tiny.make(k=k, **kw)
     try:
-        return tiny.run(root)
+        return tiny.run(root, seconds=seconds)
     finally:
         shutil.rmtree(root, ignore_errors=True)
 
@@ -128,8 +128,11 @@ def test_control_one_precision_down_is_not_correct():
     first lies further below the reference's best than the cells' limit
     allows, and far further than bfloat16's does (PERF.md gives the
     chip's readings at the cells' sizes). The env at bfloat16 for
-    float32: the reward."""
-    result, kept = _run(1, lanes=64, agents=6)
+    float32: the reward. The window closes at its first period boundary:
+    where it closes by the host's clock, the state it leaves depends on
+    the machine's load, and on some such states float8 ranks every greedy
+    first step of the small sample as float32 does."""
+    result, kept = _run(1, seconds=0.0, lanes=64, agents=6)
     assert result["correct"] is True
     c = kept["comparison"]
     avail = c.acting["avail"]
@@ -147,9 +150,11 @@ def test_control_one_precision_down_is_not_correct():
         assert sound["greedy_regret"] < limits["greedy_regret"]
 
 
-def test_calibration_reads_seeds_in_one_process(monkeypatch, tmp_path):
+@pytest.mark.parametrize("k", [2, 1])
+def test_calibration_reads_seeds_in_one_process(monkeypatch, tmp_path, k):
     """``calibrate.py`` past the look for a chip: two seeds in one
-    process, the control's and the planted fault's readings on the first."""
+    process, the control's and the planted fault's readings on the first
+    (with ``k`` 1 from the host snapshot's parameters themselves)."""
     import json
     import os
 
@@ -157,7 +162,7 @@ def test_calibration_reads_seeds_in_one_process(monkeypatch, tmp_path):
 
     from benchmark import calibrate, harness
     monkeypatch.setattr(harness, "require_chips", lambda n: jax.devices())
-    root = tiny.make(k=2, lanes=64, agents=6)
+    root = tiny.make(k=k, lanes=64, agents=6)
     out = tmp_path / "calib.jsonl"
     try:
         rc = calibrate.main(["--workload", "tiny.train", "--seeds", "5,6",
@@ -171,4 +176,6 @@ def test_calibration_reads_seeds_in_one_process(monkeypatch, tmp_path):
     assert first["acting_neighbour"]["greedy_regret"] > 5 * tiny.LIMITS[
         "greedy_regret"]
     assert first["env_bf16"]["reward_gap"] > 1e-3
+    assert {"acting_bf16", "acting_fp8", "learner_fp8"} <= set(first)
+    assert first["learner_fp8"]["td_rms_gap"] > 0
     print(json.dumps(first))

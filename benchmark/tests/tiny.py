@@ -100,7 +100,8 @@ def make(k: int = 2, dtype: str = "float32", lanes: int = 8,
     return root
 
 
-def run(root: str, seconds: float = 0.5, seed: int = 2 ** 31 + 5):
+def run(root: str, seconds: float = 0.5, seed: int = 2 ** 31 + 5,
+        window_cls=None):
     """Everything of a run after the look for a chip, on the CPU →
     (result, window facts kept by the comparison hook)."""
     from benchmark import harness
@@ -114,7 +115,8 @@ def run(root: str, seconds: float = 0.5, seed: int = 2 ** 31 + 5):
         result = brun.run_cell(
             cell, seed, seconds, False, work, ledger, jax.devices()[:1],
             bench_dir=bd, t_process=time.perf_counter(),
-            extra=lambda c, w: kept.update(comparison=c, window=w))
+            extra=lambda c, w: kept.update(comparison=c, window=w),
+            window_cls=window_cls)
     finally:
         shutil.rmtree(work, ignore_errors=True)
     kept["cell"] = cell
