@@ -74,6 +74,16 @@ def superstep_eligible(cfg: TrainConfig) -> bool:
     return cfg.superstep > 1 and not cfg.replay.buffer_cpu_only
 
 
+def unstack_rows(tree, axis: int = 0) -> tuple:
+    """``tree`` split along ``axis`` into a tuple of trees, one per index
+    — ``x[i]`` (``axis=0``) or ``x[:, i]`` (``axis=1``) of every leaf.
+    Jitted, all rows come out of ONE device program."""
+    n = jax.tree.leaves(tree)[0].shape[axis]
+    return tuple(jax.tree.map(
+        lambda x, i=i: jax.lax.index_in_dim(x, i, axis, keepdims=False),
+        tree) for i in range(n))
+
+
 def sebulba_eligible(cfg: TrainConfig) -> bool:
     """Whether the Sebulba decoupled actor/learner loop serves this
     config (``parallel/sebulba.py``; the ``superstep_eligible``
@@ -1135,6 +1145,13 @@ def run_sequential(exp: Experiment, logger: Logger,
             return exp.population_superstep_program(k, donate=True)
         return (dp or exp).superstep_program(k, donate=True)
 
+    # a fused dispatch's stacked info rows → its K per-iteration rows, in
+    # one program (the scan's (K,) axis follows a population's leading
+    # (P,) member axis): an eager slice per leaf is ~45 one-operation
+    # programs a row, which queue behind the running superstep and then
+    # run one at a time on an idle chip
+    unstack = jax.jit(functools.partial(unstack_rows, axis=1 if P else 0))
+
     K = cfg.superstep if superstep_eligible(cfg) else 1
     pop_test = None
     with rec.span("setup.programs"):
@@ -1628,17 +1645,15 @@ def run_sequential(exp: Experiment, logger: Logger,
                 except watchdog.DispatchFailed as df:
                     _dispatch_ladder(df)
                     continue
-                with rec.span("driver.account", t_env=t_env):
+                # every row is unstacked whatever the gate pattern: one
+                # signature, compiled at the first dispatch
+                with rec.span("driver.account", t_env=t_env,
+                              rows=sum(gated), programs=1):
                     key, episode, buffer_filled = key2, ep2, fill2
                     t_env += K * steps_per_rollout
-                    for i, g in enumerate(gated):
-                        if g:
-                            # population infos carry the leading (P,)
-                            # member axis; the scan's (K,) axis is the
-                            # next one
-                            train_infos.append(jax.tree.map(
-                                (lambda x, i=i: x[:, i]) if P
-                                else (lambda x, i=i: x[i]), infos))
+                    rows = unstack(infos)
+                    train_infos.extend(
+                        r for r, g in zip(rows, gated) if g)
             else:
                 # ------------ rollout (no grad by construction) -------------
                 def _roll(ts=ts):

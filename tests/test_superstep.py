@@ -9,6 +9,7 @@ the resilience interplay (ShutdownGuard at a dispatch boundary,
 non-finite guard inside the scan)."""
 
 import glob
+import json
 import os
 import signal
 
@@ -19,7 +20,8 @@ import pytest
 
 from t2omca_tpu.analysis import (CompileBudgetExceeded, compile_budget,
                                  no_transfer)
-from t2omca_tpu.config import (EnvConfig, ModelConfig, ReplayConfig,
+from t2omca_tpu.config import (EnvConfig, ModelConfig, ObsConfig,
+                               PopulationConfig, ReplayConfig,
                                ResilienceConfig, TrainConfig, sanity_check)
 from t2omca_tpu.run import Experiment, run, superstep_eligible
 from t2omca_tpu.utils import resilience
@@ -59,9 +61,11 @@ def tiny_cfg(tmp_path=None, **kw):
     return sanity_check(TrainConfig(**defaults))
 
 
-def _three_program_loop(exp, n_iters, accumulated=0):
+def _three_program_loop(exp, n_iters, accumulated=0, test_after=()):
     """The classic driver train path, verbatim (run.run_sequential K=1):
-    host-gated train, conditional key split."""
+    host-gated train, conditional key split. ``test_after`` lists the
+    iteration counts after which the driver's test cadence ran its greedy
+    rollout, which carries the runner on."""
     cfg = exp.cfg
     ts = exp.init_train_state(cfg.seed)
     rollout, insert, train_iter = exp.jitted_programs()
@@ -81,6 +85,10 @@ def _three_program_loop(exp, n_iters, accumulated=0):
             key, k_sample = jax.random.split(key)
             ts, info = train_iter(ts, k_sample, jnp.asarray(t_env))
             infos.append(info)
+        if t_env // spr in test_after:
+            rs, _, _ = rollout(ts.learner.params["agent"], ts.runner,
+                               test_mode=True)
+            ts = ts.replace(runner=rs)
     return ts, infos
 
 
@@ -197,6 +205,108 @@ def test_run_sequential_issues_one_dispatch_per_k(tmp_path, monkeypatch):
     t_end = int(jax.device_get(ts.runner.t_env))
     assert t_end == 2 * 3 * 12                     # K-aligned boundary
     assert int(jax.device_get(ts.learner.train_steps)) == 5  # iters 2..6
+
+
+def _gated(cfg, capacity, t_env, k, accumulated):
+    """The driver's host mirror of the train gate for the K
+    sub-iterations of the dispatch that starts at ``t_env``."""
+    b = cfg.batch_size_run
+    it = t_env // (b * cfg.env_args.episode_limit)
+    return [min((it + j) * b, capacity) >= cfg.batch_size
+            and (it + j) * b >= accumulated for j in range(1, k + 1)]
+
+
+def test_run_sequential_unstacks_info_rows_in_one_program(tmp_path):
+    """The real driver at superstep=3, telemetry on: each fused dispatch's
+    ``driver.account`` span enqueues ONE program that unstacks the info
+    rows, whatever the gate pattern (the first dispatch is partly
+    ungated), so the run compiles it at most once, and keeps exactly the
+    gated sub-iterations' rows."""
+    jax.clear_caches()
+    # spr = 12; t_max=70 → dispatches at t_env 0 and 36; training
+    # opens at iteration 3 (episode 6)
+    cfg = tiny_cfg(tmp_path, t_max=70, superstep=3,
+                   accumulated_episodes=6,
+                   obs=ObsConfig(enabled=True, flush_every=1))
+    run(cfg, Logger())
+    (path,) = glob.glob(os.path.join(str(tmp_path), "*", "spans.jsonl"))
+    with open(path) as f:
+        spans = [e for e in map(json.loads, f)
+                 if e.get("phase") == "driver.account"]
+    assert [e["t_env"] for e in spans] == [0, 36]
+    assert sum(e.get("compile_n", 0) for e in spans) <= 1
+    capacity = cfg.replay.buffer_size
+    for e in spans:
+        assert e["programs"] <= 1
+        assert e["rows"] == sum(_gated(cfg, capacity, e["t_env"], 3, 6))
+    assert [e["rows"] for e in spans] == [1, 3]
+
+
+@pytest.mark.parametrize("population", [0, 2])
+def test_run_sequential_info_rows_match_program_outputs(tmp_path,
+                                                        monkeypatch,
+                                                        population):
+    """The rows the driver hands ``fetch.train_infos`` (the benchmark's
+    hook) are, key for key and bit for bit, the gated sub-iterations'
+    rows of the fused program's stacked outputs — ``x[i]`` solo,
+    ``x[:, i]`` under a population's leading (P,) member axis — and,
+    solo, the K=1 three-program loop's rows on the same seed (with the
+    test rollout the driver's cadence runs at its first boundary). The
+    first dispatch is partly ungated (ring not yet fillable)."""
+    stacked = []
+    name = ("population_superstep_program" if population
+            else "superstep_program")
+    orig = getattr(Experiment, name)
+
+    def recording(self, k, **kw):
+        prog = orig(self, k, **kw)
+
+        def wrapped(*a, **k2):
+            out = prog(*a, **k2)
+            stacked.append(jax.tree.map(np.asarray,
+                                        jax.device_get(out[2])))
+            return out
+        return wrapped
+
+    monkeypatch.setattr(Experiment, name, recording)
+    fetched = []
+    resilience.register_fault(
+        "fetch.train_infos",
+        lambda train_infos=None, **_: fetched.extend(
+            jax.tree.map(np.asarray, jax.device_get(list(train_infos)))))
+    # spr = 12: dispatches at t_env 0 and 36, each flushed at the log
+    # cadence; gates [F, F, T] then [T, T, T]
+    kw = dict(t_max=70, superstep=3, accumulated_episodes=6,
+              log_interval=36, runner_log_interval=36)
+    if population:
+        kw["population"] = PopulationConfig(size=population)
+    cfg = tiny_cfg(tmp_path, **kw)
+    run(cfg, Logger())
+
+    axis = 1 if population else 0
+    capacity = cfg.replay.buffer_size
+    expected = [jax.tree.map(lambda x, i=i: np.take(x, i, axis=axis), s)
+                for d, s in enumerate(stacked)
+                for i, g in enumerate(_gated(cfg, capacity, d * 36, 3, 6))
+                if g]
+    assert len(stacked) == 2 and len(expected) == 4
+    if not population:
+        _, infos1 = _three_program_loop(Experiment.build(cfg), 6,
+                                        accumulated=6, test_after=(3,))
+        expected_k1 = [jax.tree.map(np.asarray, jax.device_get(i))
+                       for i in infos1]
+    refs = [expected] + ([] if population else [expected_k1])
+    for ref in refs:
+        assert len(fetched) == len(ref)
+        for n, (got, want) in enumerate(zip(fetched, ref)):
+            assert (jax.tree.structure(got)
+                    == jax.tree.structure(want)), f"row {n}"
+            for (kp, x), (_, y) in zip(
+                    jax.tree_util.tree_leaves_with_path(got),
+                    jax.tree_util.tree_leaves_with_path(want)):
+                assert x.shape == y.shape and x.dtype == y.dtype
+                np.testing.assert_array_equal(
+                    x, y, err_msg=f"row {n} {jax.tree_util.keystr(kp)}")
 
 
 def test_superstep_ineligible_on_host_buffer(tmp_path):
